@@ -1,22 +1,22 @@
-"""Flash-attention block-size sweep on the live TPU (VERDICT r3 #5 tooling).
+"""Flash-attention block-size sweep on the TPU.
 
-Probes the backend once (bench.py's subprocess-probing machinery), then
-runs the kernel microbench (fwd + fwd/bwd vs XLA) for each block-size
-combination in a FRESH subprocess — the env knobs
-(PADDLE_TPU_FLASH_BLOCK_Q/K, PADDLE_TPU_FLASH_BWD_BLOCK_Q/K) are read at
-trace time, so per-config process isolation is what makes the sweep honest.
-Results append to FLASH_SWEEP.json (seq -> config -> timings); the best
-bwd config found should then be baked into ops/pallas/flash_attention.py
-defaults and re-proven by a full bench.py run.
+Runs bench.py's kernel microbench (fwd + fwd/bwd vs XLA) for each
+block-size combination in a FRESH subprocess, one after another — the env
+knobs (PADDLE_TPU_FLASH_BLOCK_Q/K, PADDLE_TPU_FLASH_BWD_BLOCK_Q/K) are read
+at trace time, so per-config process isolation is what makes the sweep
+honest. This parent never touches JAX, so each child gets the chip; a child
+that finds no TPU fails, and so does the sweep. Results append to
+FLASH_SWEEP.json (seq -> config -> timings); the best bwd config found
+should then be baked into ops/pallas/flash_attention.py defaults and
+re-proven by a full bench.py run.
 
 Usage: python bench_flash_sweep.py [seq ...]   (default: 1024 2048)
 """
 import itertools
 import json
 import os
+import subprocess
 import sys
-
-import bench  # the bench.py module next to this file
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(REPO, "FLASH_SWEEP.json")
@@ -35,29 +35,30 @@ GRID = [
 
 def main():
     seqs = [int(a) for a in sys.argv[1:]] or [1024, 2048]
-    env, platform, err = bench._select_backend()
-    if env is None or platform == "cpu":
-        print(json.dumps({"error": f"no TPU backend: {err}"}))
-        return
     try:
         with open(OUT) as f:
             results = json.load(f)
     except (OSError, ValueError):
         results = {}
     for seq, cfg in itertools.product(seqs, GRID):
-        child = dict(env)
+        child = dict(os.environ)
         child["PADDLE_TPU_FLASH_BLOCK_Q"] = str(cfg["fq"])
         child["PADDLE_TPU_FLASH_BLOCK_K"] = str(cfg["fk"])
         child["PADDLE_TPU_FLASH_BWD_BLOCK_Q"] = str(cfg["bq"])
         child["PADDLE_TPU_FLASH_BWD_BLOCK_K"] = str(cfg["bk"])
-        r = bench._run_phase(child, platform, f"micro:{seq}", timeout=900)
+        phase = f"micro:{seq}"
+        out = subprocess.run(
+            [sys.executable, os.path.join(REPO, "bench.py"), phase],
+            env=child, check=True, capture_output=True, text=True,
+            timeout=900).stdout
+        r = json.loads(out.strip().splitlines()[-1])[phase]
         key = f"seq{seq}"
         name = f"f{cfg['fq']}x{cfg['fk']}_b{cfg['bq']}x{cfg['bk']}"
         results.setdefault(key, {})[name] = r
         print(json.dumps({"seq": seq, "config": name,
-                          "pallas_fwdbwd_ms": r.get("pallas_fwdbwd_ms"),
-                          "speedup_fwdbwd": r.get("speedup_fwdbwd"),
-                          "error": r.get("error")}), flush=True)
+                          "pallas_fwdbwd_ms": r["pallas_fwdbwd_ms"],
+                          "speedup_fwdbwd": r["speedup_fwdbwd"]}),
+              flush=True)
         with open(OUT, "w") as f:
             json.dump(results, f, indent=1, sort_keys=True)
     # summary: best bwd config per seq

@@ -14,15 +14,12 @@ from ..framework.core import CPUPlace, Place, TPUPlace
 _current = None
 
 
-def _platform_devices(kind: str):
-    try:
-        return jax.devices("cpu" if kind == "cpu" else None)
-    except RuntimeError:
-        return jax.devices()
-
-
 def set_device(device: str):
-    """paddle.set_device parity: 'tpu', 'tpu:0', 'cpu', 'gpu:0'→tpu."""
+    """paddle.set_device parity: 'tpu', 'tpu:0', 'cpu', 'gpu:0'→tpu.
+
+    Asking for an accelerator that is not there, or an index past the last
+    device, raises: a script must not believe it trains on a chip while it
+    runs on the host."""
     global _current
     kind, _, idx = device.partition(":")
     idx = int(idx) if idx else 0
@@ -31,8 +28,18 @@ def set_device(device: str):
     if kind == "cpu":
         devs = jax.devices("cpu")
     else:
-        devs = [d for d in jax.devices() if d.platform != "cpu"] or jax.devices()
-    dev = devs[min(idx, len(devs) - 1)]
+        devs = [d for d in jax.devices() if d.platform != "cpu"]
+        if not devs:
+            raise RuntimeError(
+                f"set_device({device!r}): no accelerator is attached "
+                f"(jax.devices() reports platform "
+                f"{jax.devices()[0].platform!r}); use set_device('cpu') to "
+                "run on the host")
+    if not 0 <= idx < len(devs):
+        raise ValueError(
+            f"set_device({device!r}): index {idx} out of range, "
+            f"{len(devs)} {kind} device(s) present")
+    dev = devs[idx]
     jax.config.update("jax_default_device", dev)
     _current = f"{kind}:{idx}"
     return Place(kind, idx)
@@ -83,7 +90,7 @@ class _Event:
     def record(self, stream=None):
         import time
 
-        jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+        jax.effects_barrier()
         self._t = time.perf_counter()
 
     def synchronize(self):
@@ -117,7 +124,7 @@ class _Stream:
 
 def synchronize(device=None):
     """Block until all queued device work completes."""
-    for d in jax.live_arrays() if hasattr(jax, "live_arrays") else []:
+    for d in jax.live_arrays():
         try:
             d.block_until_ready()
         except Exception:

@@ -83,10 +83,11 @@ def get_group(gid=None):
 # `shard_map` convenience re-export: the explicit-SPMD escape hatch
 # (reference analogue: writing custom collective ops).
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-    from .._jax_compat import shard_map as _shard_map
+    import jax
+
     from .mesh import require_global_mesh
 
-    return _shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh or require_global_mesh(),
         in_specs=in_specs,

@@ -1111,11 +1111,9 @@ def _pipeline_forward(x, *stacked_vals, pipe: SpmdPipeline, n_extra: int = 0):
             return inner_fn(
                 region, xm_all.astype(x.dtype)).astype(jnp.float32)
 
-    from ...._jax_compat import shard_map as _shard_map
-
     region_axes = frozenset({"pp"}) | frozenset(bs_axes) | (
         frozenset({"sharding"}) if z_layout is not None else frozenset())
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         spmd_fn,
         mesh=m,
         in_specs=(tuple(region_specs), data_spec),

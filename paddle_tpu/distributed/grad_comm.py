@@ -11,7 +11,7 @@ closest reference analogue of the wire-quantized collectives here.
 
 TPU-native design: there is no eager NCCL loop to fuse — every collective
 is compiled into the step program. On this jax the building block is the
-*fully-manual* `shard_map` region (`_jax_compat.shard_map`), whose boundary
+*fully-manual* `jax.shard_map` region, whose boundary
 autodiff gives exactly the mechanics we need (all verified empirically on
 the CPU mesh backend):
 
@@ -678,8 +678,6 @@ def build_explicit_dp_step(cfg: GradCommConfig, plan: DpPlan, mesh, *,
     Returns a ``step(p_vals, b_vals, opt_states, batch_vals, lr, rng_key)``
     with the same signature/state-layout contract as TrainStep._build_step
     (opt_states may carry a trailing {RESIDUAL_KEY: ...} entry)."""
-    from .._jax_compat import shard_map as _shard_map
-
     axes = plan.axes
     S = plan.nshards
     have_sh = S > 1 and "sharding" in axes
@@ -841,7 +839,7 @@ def build_explicit_dp_step(cfg: GradCommConfig, plan: DpPlan, mesh, *,
             batch_spec_fn(tuple(v.shape)) for v in batch_vals)
         res_spec = P(axes if len(axes) > 1 else axes[0])
         res_specs = {k: res_spec for k in residuals}
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(tuple(p_specs), tuple(b_specs), state_specs_tree,
                       res_specs, tuple(batch_specs), P(), P()),

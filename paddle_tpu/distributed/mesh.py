@@ -260,9 +260,7 @@ def sharding_constraint(value, spec: PartitionSpec, mesh: Optional[Mesh] = None)
     # trace: data is already rank-local along them, so a GSPMD hint naming
     # them is moot — and rejected at LOWERING time (too late for a
     # try/except here). Strip them from the spec up front.
-    from .._jax_compat import bound_axis_names
-
-    manual = bound_axis_names()
+    manual = manual_axis_names()
     if manual:
         entries = [
             None
@@ -281,6 +279,14 @@ def sharding_constraint(value, spec: PartitionSpec, mesh: Optional[Mesh] = None)
         return lax.with_sharding_constraint(value, NamedSharding(m, spec))
     except Exception:
         return jax.device_put(value, NamedSharding(m, spec))
+
+
+def manual_axis_names() -> frozenset:
+    """Axis names bound in the CURRENT trace (shard_map/pmap/vmap regions):
+    manual here, so not GSPMD's to partition."""
+    from jax._src import core as _core
+
+    return frozenset(_core.unsafe_get_axis_names())
 
 
 def mesh_axis_size(name: str, mesh: Optional[Mesh] = None) -> int:

@@ -81,6 +81,12 @@ __all__ = [
 
 KV_DTYPES = ("f32", "bf16", "int8")
 
+#: PRNG of the request sample streams, pinned so that sampled tokens do not
+#: depend on the process-wide default: on a TPU that default is ``rbg``
+#: (framework/rng.py), whose bits change under ``vmap`` and so with the
+#: slot a request happens to decode in
+_KEY_IMPL = "threefry2x32"
+
 #: the reserved all-garbage page every unallocated page-table entry (and
 #: every masked scatter) points at; never handed out by the allocator
 TRASH_PAGE = 0
@@ -153,8 +159,9 @@ class EngineConfig:
     #: pins the fused Pallas kernel (page gather + online softmax + int8
     #: dequant in one pass, docs/SERVING.md §kernel plane), "einsum" pins
     #: the XLA reference oracle, "auto" picks pallas on TPU. An mp-
-    #: sharded pool always serves einsum (the GSPMD annotations live
-    #: there) and counts an attn_kernel_fallback_total.
+    #: sharded pool serves einsum under "auto" (the GSPMD annotations
+    #: live there; counted in attn_kernel_fallback_total) and rejects an
+    #: explicit "pallas".
     attn_kernel: Optional[str] = None
     #: jax.sharding.Mesh to run the compiled programs on. An ``mp`` axis
     #: with degree > 1 shards the KV pools (and int8 scales) over kv
@@ -496,7 +503,7 @@ def _replicate_out(x):
 
 def _sample_tokens(logits, keys, temperature, top_k, top_p, greedy,
                    exact_argmax=None):
-    """On-device sampling for N rows: logits [N, V] f32, keys [N, ks],
+    """On-device sampling for N rows: logits [N, V] f32, keys [N] typed,
     temperature/top_p f32 [N], top_k i32 [N], greedy bool [N]. Per-row
     keys keep every request's sample stream independent of co-scheduling.
     top_k <= 0 and top_p >= 1.0 disable their filters. ``exact_argmax``
@@ -596,15 +603,19 @@ class DecodeEngine:
         self._logit_wire = lw
         # resolve the paged-attention kernel once — it shapes every
         # compiled program (and so belongs in the AOT cache key). The
-        # fused Pallas kernel cannot express the mp GSPMD sharding, so a
-        # sharded pool falls back to the einsum oracle and says so.
-        self._attn_kernel = F.resolve_attn_kernel(cfg.attn_kernel)
-        if self._attn_kernel == "pallas":
-            from ..ops.pallas import paged_attention as _pa_kernel
-
-            if self._mp_degree > 1 or not _pa_kernel.available():
-                self._attn_kernel = "einsum"
-                _obs.inc("attn_kernel_fallback_total")
+        # fused Pallas kernel cannot express the mp GSPMD sharding: under
+        # mp "auto" serves the einsum oracle and counts it, while a
+        # kernel that was asked for by name and cannot run is an error,
+        # never a quiet switch.
+        asked = F.asked_attn_kernel(cfg.attn_kernel)
+        self._attn_kernel = F.resolve_attn_kernel(asked)
+        if self._attn_kernel == "pallas" and self._mp_degree > 1:
+            if asked == "pallas":
+                raise ValueError(
+                    f"attn_kernel='pallas' cannot serve an mp-sharded KV "
+                    f"pool (mp={self._mp_degree}); use 'auto' or 'einsum'")
+            self._attn_kernel = "einsum"
+            _obs.inc("attn_kernel_fallback_total")
         _obs.set_gauge("attn_kernel_active",
                        1.0 if self._attn_kernel == "pallas" else 0.0)
         # einsum + int8 materializes both dequantized [N, Hkv, P, D] f32
@@ -693,6 +704,8 @@ class DecodeEngine:
         self._decode_jit = None
         self._verify_jit = None
         self._compiled = set()
+        #: name -> (jitted fn, abstract args) of every program that ran
+        self._programs: Dict[str, tuple] = {}
         self._aot: Dict[str, object] = {}  # persistent-cache Compiled objects
         self.aot_cache_hits = 0
         self.compile_count = 0
@@ -719,8 +732,8 @@ class DecodeEngine:
         self._acct = None
         self._pg_meter = None
         self._backoff_s = 0.0
-        self._base_key = jax.random.PRNGKey(cfg.seed)
-        self._zero_key = np.asarray(self._base_key)
+        self._base_key = jax.random.key(cfg.seed, impl=_KEY_IMPL)
+        self._zero_key = np.asarray(jax.random.key_data(self._base_key))
         self._waiting: deque = deque()
         self._running: Dict[int, Request] = {}
         self._free = list(range(cfg.num_slots))[::-1]  # pop() -> slot 0
@@ -800,12 +813,8 @@ class DecodeEngine:
                 f"has {self._num_pages - 1}")
         rid = self._next_id
         self._next_id += 1
-        if params.seed is not None:
-            key = jax.random.PRNGKey(params.seed)
-        else:
-            key = jax.random.fold_in(self._base_key, rid)
         req = Request(req_id=rid, prompt=ids, params=params,
-                      key_np=np.asarray(key),
+                      key_np=self._request_key(params, rid),
                       submit_time=time.perf_counter())
         if trace:
             req.trace_id = trace.get("trace_id")
@@ -1330,17 +1339,13 @@ class DecodeEngine:
             return None
         rid = self._next_id
         self._next_id += 1
-        if params.seed is not None:
-            key = jax.random.PRNGKey(params.seed)
-        else:
-            key = jax.random.fold_in(self._base_key, rid)
         cached_len = len(shared) * p
         row = np.zeros(self._mp, np.int32)
         row[:len(shared)] = shared
         row[len(shared):content_pages] = pages
         self._tables[slot] = row
         req = Request(req_id=rid, prompt=ids, params=params,
-                      key_np=np.asarray(key),
+                      key_np=self._request_key(params, rid),
                       submit_time=time.perf_counter())
         if trace:
             req.trace_id = trace.get("trace_id")
@@ -1490,13 +1495,9 @@ class DecodeEngine:
                 jnp.asarray(v_in, self._vc.dtype))
         rid = self._next_id
         self._next_id += 1
-        if params.seed is not None:
-            key = jax.random.PRNGKey(params.seed)
-        else:
-            key = jax.random.fold_in(self._base_key, rid)
         now = time.perf_counter()
         req = Request(req_id=rid, prompt=ids, params=params,
-                      key_np=np.asarray(key), submit_time=now,
+                      key_np=self._request_key(params, rid), submit_time=now,
                       status="running", slot=slot, epoch=self._epoch)
         req.page_ids = list(pages)
         req.prefill_t0 = now
@@ -1530,6 +1531,15 @@ class DecodeEngine:
         return rid
 
     # -- internals ----------------------------------------------------------
+
+    def _request_key(self, params: SamplingParams, rid: int) -> np.ndarray:
+        """Host-side key data of one request's sample stream: its own seed
+        when it carries one, else the engine's base key folded with the
+        request id."""
+        key = (jax.random.key(params.seed, impl=_KEY_IMPL)
+               if params.seed is not None
+               else jax.random.fold_in(self._base_key, rid))
+        return np.asarray(jax.random.key_data(key))
 
     def _bucket_for(self, n: int) -> int:
         for b in self.buckets:
@@ -1844,9 +1854,23 @@ class DecodeEngine:
         stack.enter_context(_mp_comm.activation_wire_disabled())
         return stack
 
+    def program_text(self, name: str) -> str:
+        """Optimised HLO of one program that has run ("decode",
+        "verify_k4", "prefill_b16", ...): what the compiler kept of it — a
+        kernel's custom call, the collectives of a sharded engine."""
+        fn, args = self._programs[name]
+        with self._mesh_ctx():
+            return fn.lower(*args).compile().as_text()
+
     def _run_counted(self, name, fn, *args):
         first = name not in self._compiled
         t0 = time.perf_counter() if first else 0.0
+        if first:
+            # an uncommitted array follows the committed ones, as in the call
+            self._programs[name] = (fn, jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=a.sharding
+                    if getattr(a, "committed", False) else None), args))
         cached = self._aot.get(name)
         if cached is not None:
             fn = cached
@@ -1997,7 +2021,8 @@ class DecodeEngine:
             # sample stream keyed by DESTINATION position: token landing at
             # position true_len uses fold_in(key, true_len), matching what
             # the decode step would use — scheduling-invariant
-            step_key = jax.random.fold_in(key, true_len)
+            step_key = jax.random.fold_in(
+                jax.random.wrap_key_data(key, impl=_KEY_IMPL), true_len)
             s_logits, exact_arg, wired = self._wire_logits(logits)
             nxt = _sample_tokens(s_logits, step_key[None], temp[None],
                                  top_k[None], top_p[None], greedy[None],
@@ -2042,7 +2067,9 @@ class DecodeEngine:
             finally:
                 for t_, v_ in zip(state, originals):
                     t_._value = v_
-            step_keys = jax.vmap(jax.random.fold_in)(keys, positions + 1)
+            step_keys = jax.vmap(jax.random.fold_in)(
+                jax.random.wrap_key_data(keys, impl=_KEY_IMPL),
+                positions + 1)
             s_logits, exact_arg, wired = self._wire_logits(logits)
             nxt = _sample_tokens(s_logits, step_keys, temp, top_k, top_p,
                                  greedy, exact_argmax=exact_arg)
@@ -2091,12 +2118,13 @@ class DecodeEngine:
                 for t_, v_ in zip(state, originals):
                     t_._value = v_
             step_keys = jax.vmap(jax.vmap(
-                jax.random.fold_in, in_axes=(None, 0)))(keys, pos2 + 1)
+                jax.random.fold_in, in_axes=(None, 0)))(
+                jax.random.wrap_key_data(keys, impl=_KEY_IMPL), pos2 + 1)
             s_logits, exact_arg, wired = self._wire_logits(logits)
             flat = s_logits.reshape(s * k1, -1)
             rep = lambda a: jnp.repeat(a, k1, axis=0)
             targets = _sample_tokens(
-                flat, step_keys.reshape(s * k1, -1), rep(temp), rep(top_k),
+                flat, step_keys.reshape(s * k1), rep(temp), rep(top_k),
                 rep(top_p), rep(greedy),
                 exact_argmax=(None if exact_arg is None
                               else exact_arg.reshape(s * k1))).reshape(s, k1)

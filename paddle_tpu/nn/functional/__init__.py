@@ -6,6 +6,7 @@ from .activation import *  # noqa: F401,F403
 # breaking `F.flash_attention(q, k, v)` callers
 from . import flash_attention as _flash_attention_module  # noqa: F401
 from .attention import (  # noqa: F401
+    asked_attn_kernel,
     decode_attention,
     flash_attention,
     paged_attention,

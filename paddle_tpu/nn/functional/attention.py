@@ -26,52 +26,38 @@ _MASK_FILL = mask_fill_value(jnp.float32)
 ATTN_KERNELS = ("auto", "pallas", "einsum")
 
 _USE_PALLAS = True
-_PALLAS_PROBE: dict = {}  # backend name -> bool (Mosaic compile probe result)
+
+
+def _auto_partitioned() -> bool:
+    """Is this trace part of a program GSPMD will partition — a global mesh
+    of several devices is active and some axis of it is not manual
+    (shard_map) here?"""
+    from ...distributed import mesh as _mesh
+
+    m = _mesh.get_global_mesh()
+    if m is None or m.empty or m.size == 1:
+        return False
+    manual = _mesh.manual_axis_names()
+    return any(n > 1 and a not in manual for a, n in m.shape.items())
 
 
 def _pallas_backend_ok() -> bool:
-    """One-time probe: does the Pallas flash kernel actually COMPILE on this
-    backend? (Mosaic failures surface at XLA-compile time, after tracing, so
-    the per-call try/except in `_sdpa` cannot catch them.) On failure the
-    public attention API silently serves the XLA-native reference path —
-    the runtime fallback the reference gets from its flashattn-or-math
-    dispatch (python/paddle/nn/functional/flash_attention.py).
-
-    CPU/GPU backends return False outright: there the kernel would run in
-    Pallas interpret mode, which is orders of magnitude slower than the
-    fused XLA softmax-attention. Set PADDLE_TPU_PALLAS_INTERPRET=1 to force
-    the routed kernel in interpret mode (kernel-routing tests).
+    """Can the flash kernel serve long sequences in the program being
+    traced? Yes on a TPU, in a program for one device: a Mosaic refusal
+    then surfaces as the compile error of the program that holds the
+    kernel — never as a quiet switch to the XLA path. Not in a program
+    GSPMD partitions: the TPU compiler refuses it at lowering ("Mosaic
+    kernels cannot be automatically partitioned"), so Fleet hybrid steps
+    route XLA attention until the kernel is wrapped in a shard_map over the
+    batch and head axes (ROADMAP.md S5). Off-TPU the kernel would run in
+    Pallas interpret mode, orders of magnitude slower than the fused XLA
+    softmax-attention, so it is not routed; set
+    PADDLE_TPU_PALLAS_INTERPRET=1 to force the routed kernel in interpret
+    mode (kernel-routing tests).
     """
-    import os
-
-    backend = jax.default_backend()
     if os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1":
         return True
-    if backend != "tpu":
-        return False
-    got = _PALLAS_PROBE.get(backend)
-    if got is None:
-        try:
-            from ...ops.pallas.flash_attention import flash_attention as _fa
-
-            # AOT lower+compile, never execute: Mosaic failures surface at
-            # compile time, and (unlike calling the jitted fn) this works
-            # even when the first attention call happens inside an ambient
-            # trace — executing there would return a tracer and poison the
-            # cache with False.
-            x = jnp.zeros((1, 128, 1, 64), jnp.bfloat16)
-            jax.jit(lambda a: _fa(a, a, a, causal=True)).lower(x).compile()
-            got = True
-        except Exception as e:
-            import warnings
-
-            warnings.warn(
-                f"Pallas flash-attention kernel failed to compile on "
-                f"backend {backend!r} ({type(e).__name__}: {e}); attention "
-                "falls back to the XLA-native path", stacklevel=2)
-            got = False
-        _PALLAS_PROBE[backend] = got
-    return got
+    return jax.default_backend() == "tpu" and not _auto_partitioned()
 
 
 def _sdpa_reference(q, k, v, mask, dropout_p, causal, scale, key=None):
@@ -119,19 +105,16 @@ def _sdpa(q, k, v, mask, key, dropout_p, causal, scale, use_pallas):
         mask is None or getattr(mask, "ndim", 0) == 4
     ) and _pallas_backend_ok()
     if pallas_ok:
-        try:
-            from ...ops.pallas.flash_attention import flash_attention as _fa
+        from ...ops.pallas.flash_attention import flash_attention as _fa
 
-            if mask is None:
-                return _fa(q, k, v, causal=causal, scale=scale)
-            if mask.dtype == jnp.bool_:
-                return _fa(q, k, v, causal=causal, scale=scale, mask=mask)
-            # paddle attn_mask semantics: an additive mask, not a trained
-            # bias — skip the O(B*H*T^2) dbias pass in backward
-            return _fa(q, k, v, causal=causal, scale=scale, bias=mask,
-                       bias_needs_grad=False)
-        except Exception:
-            pass
+        if mask is None:
+            return _fa(q, k, v, causal=causal, scale=scale)
+        if mask.dtype == jnp.bool_:
+            return _fa(q, k, v, causal=causal, scale=scale, mask=mask)
+        # paddle attn_mask semantics: an additive mask, not a trained
+        # bias — skip the O(B*H*T^2) dbias pass in backward
+        return _fa(q, k, v, causal=causal, scale=scale, bias=mask,
+                   bias_needs_grad=False)
     return _sdpa_reference(q, k, v, mask, dropout_p, causal, scale, key)
 
 
@@ -256,24 +239,31 @@ def decode_attention(query, cache_k, cache_v, cache_position, scale=None,
                                 scale)
 
 
-def resolve_attn_kernel(kernel=None) -> str:
-    """Resolve the paged-attention kernel knob to ``'pallas'`` or
-    ``'einsum'``.
-
-    Precedence: explicit ``kernel`` arg (engine config) >
-    ``PADDLE_TPU_ATTN_KERNEL`` env > ``'auto'``. ``auto`` routes to the
-    fused Pallas kernel on a real TPU backend and to the einsum oracle
-    everywhere else — off-TPU the kernel runs in Pallas interpret mode,
-    orders of magnitude slower than the fused XLA einsum path.
-    ``PADDLE_TPU_PALLAS_INTERPRET=1`` (the kernel-routing test hook)
-    makes ``auto`` pick the kernel in interpret mode.
-    """
+def asked_attn_kernel(kernel=None) -> str:
+    """What was asked of the paged-attention kernel knob, unresolved: the
+    explicit ``kernel`` arg (engine config) > ``PADDLE_TPU_ATTN_KERNEL``
+    env > ``'auto'``."""
     mode = str(kernel or os.environ.get("PADDLE_TPU_ATTN_KERNEL")
                or "auto").lower()
     if mode not in ATTN_KERNELS:
         raise ValueError(
             f"unknown attention kernel {mode!r}; expected one of "
             f"{ATTN_KERNELS} (PADDLE_TPU_ATTN_KERNEL / engine attn_kernel)")
+    return mode
+
+
+def resolve_attn_kernel(kernel=None) -> str:
+    """Resolve the paged-attention kernel knob to ``'pallas'`` or
+    ``'einsum'``.
+
+    Precedence as in :func:`asked_attn_kernel`. ``auto`` routes to the
+    fused Pallas kernel on a real TPU backend and to the einsum oracle
+    everywhere else — off-TPU the kernel runs in Pallas interpret mode,
+    orders of magnitude slower than the fused XLA einsum path.
+    ``PADDLE_TPU_PALLAS_INTERPRET=1`` (the kernel-routing test hook)
+    makes ``auto`` pick the kernel in interpret mode.
+    """
+    mode = asked_attn_kernel(kernel)
     if mode != "auto":
         return mode
     if os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1":

@@ -36,9 +36,7 @@ def ring_flash_attention(
     sharded contiguously in rank order over `axis_name`).
     Returns the rank-local [B, T_local, H, D] output block.
     """
-    from ..._jax_compat import axis_size
-
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     b, tl, h, d = q.shape
     if scale is None:
@@ -96,9 +94,7 @@ def context_parallel_attention(q, k, v, causal: bool = False, scale=None, axis_n
         return _sdpa_reference(q, k, v, None, 0.0, causal, scale)
 
     spec = P(None, axis_name, None, None)
-    from ..._jax_compat import shard_map as _shard_map
-
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         lambda a, b_, c: ring_flash_attention(a, b_, c, axis_name, causal, scale),
         mesh=m,
         in_specs=(spec, spec, spec),

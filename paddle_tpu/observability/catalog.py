@@ -168,8 +168,8 @@ METRICS = {
                    "per decode/verify step)"),
     "attn_kernel_fallback_total": (
         "counter", "Engine resolutions that asked for the Pallas kernel "
-                   "but fell back to the einsum oracle (mp-sharded pool, "
-                   "or pallas TPU support missing)"),
+                   "by 'auto' and served the einsum oracle instead "
+                   "(mp-sharded pool)"),
     # -- serving router (serving/router.py) ---------------------------------
     "serving_router_requests_total": (
         "counter", "Requests submitted to the multi-engine router"),

@@ -34,11 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU module imports fine on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_M = 128
 # N auto-pads to a block multiple inside grouped_matmul, so a wide default
@@ -100,14 +96,6 @@ def _build_schedule(group_sizes, m, block_m, num_groups):
     )
 
 
-def _require_pltpu():
-    if pltpu is None:  # pragma: no cover
-        raise NotImplementedError(
-            "grouped_matmul needs jax.experimental.pallas.tpu (scalar "
-            "prefetch grid spec)"
-        )
-
-
 def _mask_rows(x, rs, re):
     rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
     return jnp.where((rows >= rs) & (rows < re), x, jnp.zeros_like(x))
@@ -132,7 +120,6 @@ def _fwd_kernel(sched_ref, lhs_ref, rhs_ref, out_ref, acc):
 
 
 def _gmm_forward(lhs, rhs, sched, block_m, block_n, interpret):
-    _require_pltpu()
     m, k = lhs.shape
     _, k2, n = rhs.shape
     assert k == k2, (lhs.shape, rhs.shape)
@@ -179,11 +166,25 @@ def _drhs_kernel(sched_ref, lhs_ref, dout_ref, drhs_ref, acc):
         drhs_ref[0] = acc[...].astype(drhs_ref.dtype)
 
 
+# VMEM the drhs kernel plans its f32 blocks in: Mosaic's scoped limit on a
+# v5e is 16 MiB, and the bf16 lhs/dout input blocks need their share of it
+_DRHS_VMEM_BUDGET = 12 << 20
+
+
+def _drhs_block_n(k, n, block_n):
+    """Widest column block, from ``block_n`` down by halves, whose (k, bn)
+    f32 accumulator plus double-buffered (k, bn) f32 output block fit the
+    budget. At K=2048 the forward's 1024-wide block would need 24 MiB."""
+    bn = min(block_n, n)
+    while 3 * k * bn * 4 > _DRHS_VMEM_BUDGET and bn % 256 == 0:
+        bn //= 2
+    return bn
+
+
 def _gmm_drhs(lhs, dout, sched, num_groups, block_m, block_n, interpret):
-    _require_pltpu()
     m, k = lhs.shape
     n = dout.shape[1]
-    block_n = min(block_n, n)
+    block_n = _drhs_block_n(k, n, block_n)
     grid = (n // block_n, sched.shape[0])
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

@@ -31,9 +31,9 @@ The einsum op remains the bit-equality reference oracle: greedy argmax
 must agree everywhere (tests/test_pallas_attention.py), raw outputs agree
 to f32 tolerance (online vs dense softmax differ in ulps only).
 
-Runs everywhere via ``interpret=True`` (default off-TPU), per the repo's
-robustness rule that every Pallas call site declares an interpret-mode
-fallback (scripts/check_robustness.py).
+Runs off-TPU via ``interpret=True`` (the default there), per the repo's
+robustness rule that every Pallas call site declares its interpret mode
+(scripts/check_robustness.py); on a TPU it compiles for real or fails.
 """
 from __future__ import annotations
 
@@ -43,11 +43,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is optional at import time (CPU test runs)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def mask_fill_value(dtype=jnp.float32) -> float:
@@ -63,19 +59,12 @@ def mask_fill_value(dtype=jnp.float32) -> float:
     return float(jnp.finfo(jnp.dtype(dtype)).min) * 0.5
 
 
-def available() -> bool:
-    """True when the pallas TPU grid-spec machinery imported (it is also
-    what drives interpret mode, so this gates CPU fallback too)."""
-    return pltpu is not None
-
-
 def _ceil8(n):
     return max(8, (n + 7) // 8 * 8)
 
 
 def _scratch(shape):
-    vmem = pltpu.VMEM if pltpu is not None else pl.ANY
-    return vmem(shape, jnp.float32)
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _paged_kernel(
@@ -183,10 +172,6 @@ def paged_attention(
     Returns:
         ``[S, T, H, D]`` f32 attention output.
     """
-    if pltpu is None:  # pragma: no cover - pltpu ships with jax
-        raise RuntimeError(
-            "pallas TPU grid specs unavailable; use the einsum path "
-            "(PADDLE_TPU_ATTN_KERNEL=einsum)")
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
     s, t, h, d = q.shape

@@ -351,7 +351,9 @@ class AdamW(Adam):
     def _rule(self, p, g, st, lr):
         decay = getattr(self, "_current_decay", self._wd)
         if decay:
-            p = p * (1.0 - lr * decay)
+            # lr is an f32 array: without the cast a bf16 parameter comes
+            # back f32, doubling its bytes and recompiling the next step
+            p = (p * (1.0 - lr * decay)).astype(p.dtype)
         return super()._rule(p, g, st, lr)
 
     @no_grad()

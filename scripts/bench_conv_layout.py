@@ -94,6 +94,13 @@ def main():
     if jax.default_backend() != "tpu":
         print(json.dumps({"error": "not on tpu"}))
         return 1
+    import os
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from paddle_tpu.runtime import jax_cache
+
+    jax_cache.configure()
     tot = {"NCHW": 0.0, "NHWC": 0.0}
     for label, cin, cout, k, s, hw, count in SHAPES:
         row = {"shape": label, "count": count}

@@ -510,6 +510,10 @@ def _spawn_router_worker(args, master, namespace, extra_env=None,
     if extra_env:
         env.update(extra_env)
     env.update({
+        # the multi-worker scenarios are a CPU correctness harness: a
+        # worker has no way to choose its device, so on a chip host every
+        # child would contend for chip 0 (ROADMAP.md D7/R7a)
+        "JAX_PLATFORMS": "cpu",
         # one virtual device and ONE compute thread per worker: XLA's
         # eigen pool defaults to all cores, and n workers x all-core
         # executions oversubscribe the box into negative scaling

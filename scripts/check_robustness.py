@@ -43,13 +43,16 @@ rejects two classes of hang/mask bugs that code review keeps re-admitting:
      Convention: boundary channel objects are named ``*chan*``
      (``_chan``, ``up_chan``, ``server_chan``); nothing else may use
      those names.
-  7. Pallas call sites without an interpret-mode fallback — in
+  7. Pallas call sites without an interpret mode for off-TPU runs — in
      ``paddle_tpu/ops/pallas`` every ``pl.pallas_call(...)`` must pass an
      ``interpret=`` keyword: the kernel plane's contract is that tier-1
      runs everywhere (docs/SERVING.md §kernel plane), and a call site
      that hardcodes compiled mode silently breaks every CPU run the
-     moment it is reached. The keyword's VALUE is the author's choice
-     (typically ``backend != "tpu"``); declaring it is not.
+     moment it is reached. Interpret mode is for off-TPU only: on a TPU
+     the kernel compiles for real or the program fails, it never gives
+     way to interpret mode or to a reference path. The keyword's VALUE
+     is the author's choice (typically ``backend != "tpu"``); declaring
+     it is not.
   8. supervisor durability — in ``paddle_tpu/distributed/fleet/
      supervisor.py`` (a) every coordination-store op must sit inside a
      ``with deadline_guard(...)`` block (same contract as rule 4: the
@@ -374,7 +377,7 @@ def check_pallas_interpret(path: str):
     """Yield (line, message) for ``pallas_call`` sites that do not declare
     an ``interpret=`` keyword (rule 7). Matches bare ``pallas_call(...)``
     and any attribute form (``pl.pallas_call``); a ``**kwargs`` splat
-    does NOT count — the fallback must be visible at the call site."""
+    does NOT count — the choice must be visible at the call site."""
     with open(path, "rb") as f:
         src = f.read()
     tree = ast.parse(src, filename=path)
@@ -390,7 +393,7 @@ def check_pallas_interpret(path: str):
             yield (node.lineno,
                    "pallas_call without an explicit interpret= keyword — "
                    "every kernel-plane call site must declare its "
-                   "interpret-mode CPU fallback (rule 7)")
+                   "interpret mode, which is for off-TPU only (rule 7)")
 
 
 def _open_mode_is_write(node: ast.Call) -> bool:

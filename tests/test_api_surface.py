@@ -303,3 +303,34 @@ def test_incubate_fused_functionals():
         ln2_scale=paddle.ones([d]), ln2_bias=paddle.zeros([d]),
     )
     assert out2.shape == [2, 6, d]
+
+
+@pytest.mark.parametrize("device,error", [
+    ("tpu", RuntimeError),      # no accelerator on the CPU test backend:
+    ("gpu:0", RuntimeError),    # never quietly the host instead
+    ("cuda", RuntimeError),
+    ("cpu:99", ValueError),     # past the last device: never clamped
+    ("cpu:-1", ValueError),
+])
+def test_set_device_raises_instead_of_falling_back(device, error):
+    import jax
+
+    before = (paddle.get_device(), jax.config.jax_default_device)
+    with pytest.raises(error, match="set_device"):
+        paddle.set_device(device)
+    assert (paddle.get_device(), jax.config.jax_default_device) == before
+
+
+def test_set_device_selects_the_indexed_cpu_device():
+    import jax
+
+    import paddle_tpu.device as device_mod
+
+    before = (device_mod._current, jax.config.jax_default_device)
+    try:
+        place = paddle.set_device("cpu:1")
+        assert paddle.get_device() == "cpu:1" and place.device_id == 1
+        assert jax.config.jax_default_device == jax.devices("cpu")[1]
+    finally:
+        device_mod._current = before[0]
+        jax.config.update("jax_default_device", before[1])

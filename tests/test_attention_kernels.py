@@ -267,6 +267,39 @@ def test_sdpa_routes_to_flash_kernel(monkeypatch):
     np.testing.assert_allclose(out.numpy(), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+def test_flash_route_is_off_in_programs_gspmd_partitions(monkeypatch):
+    """The TPU compiler refuses a Mosaic kernel in a program GSPMD
+    partitions ("cannot be automatically partitioned", found when the Fleet
+    dp2 x mp2 step was compiled for four chips): the route is on for one
+    device and inside a fully manual shard_map region, off under a mesh of
+    several devices."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.distributed import mesh as _mesh
+    from paddle_tpu.nn.functional import attention as attn_mod
+
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with _mesh.global_mesh(None):
+        assert attn_mod._pallas_backend_ok()
+    m = _mesh.build_mesh((2, 2), ("dp", "mp"), devices=jax.devices()[:4])
+    with _mesh.global_mesh(m):
+        assert not attn_mod._pallas_backend_ok()
+        seen = []
+        jax.shard_map(
+            lambda x: (seen.append(attn_mod._pallas_backend_ok()), x)[1],
+            mesh=m, in_specs=P("dp", "mp"), out_specs=P("dp", "mp"),
+        )(jnp.zeros((2, 2)))
+        assert seen == [True]
+        seen.clear()
+        jax.jit(jax.shard_map(
+            lambda x: (seen.append(attn_mod._pallas_backend_ok()), x)[1],
+            mesh=m, in_specs=P("dp"), out_specs=P("dp"),
+            axis_names=frozenset({"dp"}), check_vma=False,
+        ))(jnp.zeros((2, 2)))
+        assert seen == [False]  # mp is still GSPMD's inside this region
+
+
 @pytest.mark.fast
 def test_ring_attention_exactness():
     s = fleet.DistributedStrategy()

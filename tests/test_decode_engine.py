@@ -170,23 +170,44 @@ def test_compile_count_gate(model):
     assert eng.stats()["compile_count"] == before
 
 
-def test_sampling_is_scheduling_invariant(model):
+@pytest.mark.parametrize("default_impl", ["threefry2x32", "rbg"])
+def test_sampling_is_scheduling_invariant(model, default_impl):
+    """Holds whatever the process-wide default PRNG is: on a TPU the
+    package switches it to rbg, whose bits change under vmap, so the
+    engine pins its request keys to threefry."""
+    import jax
+
     ids = _prompts(4, 6, seed=21)
     p = SamplingParams(max_new_tokens=8, do_sample=True, temperature=0.8,
-                      top_k=12, top_p=0.95, seed=123)
-    solo = DecodeEngine(model, EngineConfig(num_slots=1, max_length=64))
-    rid = solo.submit(ids[0], p)
-    solo.run()
-    alone = solo.result(rid)
+                       top_k=12, top_p=0.95, seed=123)
+    with jax.default_prng_impl(default_impl):
+        solo = DecodeEngine(model, EngineConfig(num_slots=1, max_length=64))
+        rid = solo.submit(ids[0], p)
+        solo.run()
+        alone = solo.result(rid)
 
-    # same request, different slot count, batched with other traffic
-    busy = DecodeEngine(model, EngineConfig(num_slots=4, max_length=64))
-    others = [busy.submit(ids[i], SamplingParams(max_new_tokens=5))
-              for i in (1, 2, 3)]
-    rid2 = busy.submit(ids[0], p)
-    busy.run()
+        # same request, different slot count, batched with other traffic
+        busy = DecodeEngine(model, EngineConfig(num_slots=4, max_length=64))
+        others = [busy.submit(ids[i], SamplingParams(max_new_tokens=5))
+                  for i in (1, 2, 3)]
+        rid2 = busy.submit(ids[0], p)
+        busy.run()
     np.testing.assert_array_equal(alone, busy.result(rid2))
     assert all(busy._requests[r].status == "done" for r in others)
+
+
+def test_program_text_of_programs_that_ran(model):
+    """What the compiler kept of a program, for asserting on its text
+    (chip_smoke.py looks for the Mosaic kernel's custom call there)."""
+    eng = DecodeEngine(model, EngineConfig(num_slots=2, max_length=64))
+    eng.submit(_prompts(1, 6, seed=4)[0], SamplingParams(max_new_tokens=3))
+    eng.run()
+    assert sorted(eng._programs) == eng.stats()["compiled"] == [
+        "decode", "prefill_b16"]
+    for name in ("decode", "prefill_b16"):
+        assert "HloModule" in eng.program_text(name)
+    with pytest.raises(KeyError):
+        eng.program_text("verify_k4")  # never ran
 
 
 def test_submit_validation(model):

@@ -15,7 +15,6 @@ import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu.distributed import fleet
 import paddle_tpu.distributed as dist
-from paddle_tpu._jax_compat import shard_map as _compat_shard_map
 
 pytestmark = pytest.mark.fast  # whole-module smoke: cheap on 1 core
 
@@ -186,7 +185,7 @@ def test_collectives_traced_semantics():
 
     m = dist.get_global_mesh()
     f = jax.jit(
-        _compat_shard_map(
+        jax.shard_map(
             lambda x: dist.collective.all_reduce(x, group=g)
             if False
             else jax.lax.psum(x, g.axis_names[0]),
@@ -216,7 +215,7 @@ def test_collective_api_traced():
         return summed, gathered, scattered
 
     f = jax.jit(
-        _compat_shard_map(
+        jax.shard_map(
             body, mesh=g.mesh, in_specs=P(ax), out_specs=(P(), P(), P(ax)),
             check_vma=False,
         )
@@ -327,7 +326,7 @@ def test_batch_isend_irecv_ring():
         ])
         return fwd._value, bwd._value
 
-    f = jax.jit(_compat_shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=g.mesh, in_specs=P(ax), out_specs=(P(ax), P(ax)),
         check_vma=False,
     ))
@@ -341,7 +340,7 @@ def test_batch_isend_irecv_ring():
                 [dist.P2POp(dist.irecv, Tensor(x), 1, group=g)]
             )
             return x
-        jax.jit(_compat_shard_map(
+        jax.jit(jax.shard_map(
             recv_only, mesh=g.mesh, in_specs=P(ax), out_specs=P(ax),
             check_vma=False,
         ))(jnp.arange(8.0))

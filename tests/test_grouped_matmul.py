@@ -65,9 +65,14 @@ def test_grouped_matmul_dynamic_sizes_under_jit():
 
 
 @pytest.mark.fast
-def test_grouped_matmul_grads():
+@pytest.mark.parametrize("k,n", [
+    (16, 32),
+    (2048, 1024),  # OLMoE widths: the drhs kernel narrows its column block
+])
+def test_grouped_matmul_grads(k, n):
     sizes = [50, 30, 48]
-    lhs, rhs = _mk(128, 16, 32, 3, seed=2)
+    lhs, rhs = _mk(128, k, n, 3, seed=2)
+    lhs, rhs = lhs / np.sqrt(k), rhs / np.sqrt(n)
     sz = jnp.asarray(sizes, jnp.int32)
 
     def f_pl(l, r):

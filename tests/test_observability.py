@@ -550,7 +550,11 @@ def test_summary_sorted_by_and_time_unit(capsys):
             with P.RecordEvent("aa_fast"):
                 pass
         with P.RecordEvent("bb_slow"):
-            time.sleep(0.02)
+            pass
+        # inject the durations: a sleep here races the other xdist workers
+        # (a descheduled "fast" event has outlasted a 20 ms sleep)
+        P._host_events["aa_fast"][1] = 3e-3
+        P._host_events["bb_slow"][1] = 2e-2
 
         prof = P.Profiler(timer_only=True)
         prof.start()

@@ -48,6 +48,22 @@ def test_adamw_decay():
     assert (w.numpy() < 1.0).all()
 
 
+def test_adamw_functional_step_keeps_bf16_params_bf16():
+    """The compiled train step feeds lr as an f32 array; the decoupled
+    decay must not promote a bf16 parameter to f32 (twice the bytes on the
+    chip, and a second compile when the step's signature changes)."""
+    import jax.numpy as jnp
+
+    w = nn.layer.Parameter(jnp.ones((4, 4), jnp.bfloat16))
+    opt = AdamW(learning_rate=0.1, parameters=[w], weight_decay=0.01)
+    (new_w,), (st,) = opt.functional_step(
+        [w._value], [jnp.full((4, 4), 0.5, jnp.bfloat16)],
+        opt.functional_states(), jnp.asarray(0.1, jnp.float32))
+    assert new_w.dtype == jnp.bfloat16
+    assert st["moment1"].dtype == st["moment2"].dtype == jnp.bfloat16
+    assert float(new_w[0, 0]) < 1.0
+
+
 def test_adam_matches_manual():
     a = rng.rand(4).astype(np.float32)
     g = rng.rand(4).astype(np.float32)

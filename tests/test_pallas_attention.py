@@ -209,10 +209,28 @@ def test_engine_config_and_env_routing(model, monkeypatch):
     assert eng.stats()["attn_kernel"] == "einsum"
 
 
-def test_engine_falls_back_when_kernel_unavailable(model, monkeypatch):
-    monkeypatch.setattr(pa_kernel, "available", lambda: False)
-    eng = DecodeEngine(model, EngineConfig(num_slots=2, max_length=64,
-                                           attn_kernel="pallas"))
+def test_engine_mp_sharded_pool_auto_serves_einsum_explicit_pallas_raises(
+        model, monkeypatch):
+    """A kernel asked for by name that cannot run is an error, never a
+    quiet switch to the einsum oracle."""
+    from paddle_tpu.distributed.mesh import build_mesh
+
+    mesh = build_mesh((1, 2), ("dp", "mp"), devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match="mp-sharded"):
+        DecodeEngine(model, EngineConfig(num_slots=2, max_length=64,
+                                         attn_kernel="pallas", mesh=mesh))
+    # auto resolving to the kernel (here through the routing test hook)
+    # may still give way under mp, and says so in stats()
+    monkeypatch.delenv("PADDLE_TPU_ATTN_KERNEL", raising=False)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    # an engine with a mesh commits its model's weights to that mesh: give
+    # it a model of its own, not the module's shared one
+    own = GPTForCausalLM(GPTConfig(
+        vocab_size=VOCAB, hidden_size=32, num_hidden_layers=1,
+        num_attention_heads=4, max_position_embeddings=128,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
+    eng = DecodeEngine(own, EngineConfig(num_slots=2, max_length=64,
+                                         mesh=mesh))
     assert eng.stats()["attn_kernel"] == "einsum"
 
 

@@ -1,0 +1,146 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode (every other kernel test here) cannot see what Mosaic
+refuses: a slice off the tiling, more VMEM than a kernel may hold. The
+TPU compiler is installed in the CPU sandbox and compiles for a chip that
+is described, not attached, so these cases guard the flash, paged and
+grouped-matmul kernels at the shapes `chip_smoke.py` and the roadmap's
+cells run them at — at no chip time. Nothing executes: a compile that
+passes says nothing about results or speed.
+
+The topology is described inside a fixture, after a test of this file has
+started, and compiled in the test's own process (libtpu allows one process
+at a time); keep every such case in THIS file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import flash_attention as flash_mod
+from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from paddle_tpu.ops.pallas.paged_attention import paged_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops the description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one would warn)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip; the kernel must be in it."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# -- flash attention (training path: ERNIE seq1024, GPT-1.3B seq2048) -------
+
+FLASH_SHAPES = {
+    "ernie_b32_t1024_h12_d64": (32, 1024, 12, 64),
+    "gpt1p3b_b4_t2048_h16_d128": (4, 2048, 16, 128),
+}
+
+
+@pytest.fixture
+def flash_on_tpu(monkeypatch):
+    """The wrapper picks interpret mode from jax.default_backend(), which
+    still says cpu here: steer it from the test, not with a new option."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES), ids=list(FLASH_SHAPES))
+def test_flash_attention_compiles(one_chip, flash_on_tpu, shape, backward):
+    causal = shape.startswith("gpt")
+
+    def fwd(q, k, v):
+        return flash_mod.flash_attention(q, k, v, causal=causal)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    qkv = (FLASH_SHAPES[shape], jnp.bfloat16)
+    _compile(fwd_bwd if backward else fwd, one_chip, qkv, qkv, qkv)
+
+
+def test_flash_attention_padding_mask_compiles(one_chip, flash_on_tpu):
+    """The (B,1,1,Tk) keep-mask of a padded ERNIE batch, forward+backward."""
+    b, t, h, d = FLASH_SHAPES["ernie_b32_t1024_h12_d64"]
+
+    def fwd_bwd(q, k, v, mask):
+        return jax.grad(
+            lambda *a: flash_mod.flash_attention(*a, mask=mask)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    qkv = ((b, t, h, d), jnp.bfloat16)
+    _compile(fwd_bwd, one_chip, qkv, qkv, qkv, ((b, 1, 1, t), jnp.bool_))
+
+
+# -- paged attention (serving path: GPT-1.3B, 8 slots, 2048 tokens, page 16) -
+
+@pytest.mark.parametrize("t", [1, 5], ids=["decode", "verify_k4"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_attention_compiles(one_chip, kv, t):
+    slots, heads, d, page, max_pages = 8, 16, 128, 16, 128
+    n = 1 + slots * max_pages
+    pool = ((n, heads, page, d), jnp.int8 if kv == "int8" else jnp.bfloat16)
+    shapes = [((slots, t, heads, d), jnp.bfloat16), pool, pool,
+              ((slots, max_pages), jnp.int32), ((slots,), jnp.int32)]
+    if kv == "int8":
+        scales = ((n, heads, page), jnp.float32)
+        shapes += [scales, scales]
+
+    def fn(q, kp, vp, table, start, ks=None, vs=None):
+        return paged_attention(q, kp, vp, table, start, k_scales=ks,
+                               v_scales=vs, interpret=False)
+
+    _compile(fn, one_chip, *shapes)
+
+
+# -- grouped matmul (MoE expert FFN at OLMoE widths, ROADMAP R1) ------------
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("n", [1024, 1408])
+def test_grouped_matmul_compiles(one_chip, n, backward):
+    m, k, groups = 8192, 2048, 64
+    gmm = functools.partial(grouped_matmul, interpret=False)
+
+    def fwd_bwd(lhs, rhs, sizes):
+        return jax.grad(
+            lambda a, b: gmm(a, b, sizes).astype(jnp.float32).sum(),
+            argnums=(0, 1))(lhs, rhs)
+
+    _compile(fwd_bwd if backward else gmm, one_chip,
+             ((m, k), jnp.bfloat16), ((groups, k, n), jnp.bfloat16),
+             ((groups,), jnp.int32))
