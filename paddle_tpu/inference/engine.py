@@ -70,6 +70,10 @@ from ..framework.core import Tensor, no_grad
 from ..framework.op import raw
 from ..nn import functional as F
 
+#: the parts of each compiled program carry a scope of these names: embed,
+#: qkv, kv_write, attend, attn_out, mlp, lm_head, sample
+_scope = jax.named_scope
+
 __all__ = [
     "DecodeEngine",
     "EngineConfig",
@@ -258,6 +262,20 @@ class Request:
     #: if the engine promotes a newer epoch mid-flight — the per-epoch
     #: greedy bit-equal contract rides on this
     epoch: int = 0
+
+
+@dataclass
+class StepReport:
+    """What one ``DecodeEngine.step()`` did, by request id
+    (``engine.last_step``): what the step already knows, not a
+    measurement, so it is kept whether or not anybody is tracing."""
+    #: requests whose prefill ran in this step, in admission order
+    admitted: List[int] = field(default_factory=list)
+    #: requests that finished in this step
+    finished: List[int] = field(default_factory=list)
+    #: the tokens each request emitted in this step (an admitted request's
+    #: first token, then what the decode or verify pass added)
+    tokens: Dict[int, List[int]] = field(default_factory=dict)
 
 
 # ---------------------------------------------------------------------------
@@ -712,6 +730,9 @@ class DecodeEngine:
         self.total_tokens = 0
         self.decode_steps = 0
         self.verify_steps = 0
+        #: slots advanced, summed over the decode and verify passes: over
+        #: ``decode_steps * num_slots`` it is how full the batch has been
+        self.slot_steps = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
         self._t_decode_ema = None
@@ -739,6 +760,11 @@ class DecodeEngine:
         self._free = list(range(cfg.num_slots))[::-1]  # pop() -> slot 0
         self._requests: Dict[int, Request] = {}
         self._next_id = 0
+        #: the newest ``step()``'s report; ``_report`` is the one being
+        #: written, None outside ``step()`` (prefill_export and
+        #: try_import_prefill emit tokens that belong to no step)
+        self.last_step = StepReport()
+        self._report: Optional[StepReport] = None
 
     # -- scheduler ----------------------------------------------------------
 
@@ -839,8 +865,38 @@ class DecodeEngine:
         decode step, or — when speculation is on and a prompt-lookup
         draft exists — ONE compiled verify step emitting up to
         ``speculate_k + 1`` tokens per slot. Returns False when the
-        engine is fully idle."""
+        engine is fully idle. Leaves ``last_step`` (what it did, always)
+        and, while somebody is tracing, one ``eng_step`` span tree."""
+        report = self._report = StepReport()
+        try:
+            if not (self._running or self._waiting):
+                # an idle poll (serving/worker.py makes 200 a second) is no
+                # step: it leaves no tree
+                return self._step(None)
+            with _obs.span("eng_step",
+                           num_slots=self.config.num_slots) as sp:
+                busy = self._step(sp)
+                if sp:
+                    sp.attrs.update(
+                        emitted={rid: len(t)
+                                 for rid, t in report.tokens.items()},
+                        # running totals since the engine was built
+                        slot_steps=self.slot_steps,
+                        slot_capacity=(self.decode_steps
+                                       * self.config.num_slots))
+        finally:
+            self._report = None
+            self.last_step = report
+        return busy
+
+    def _step(self, sp) -> bool:
         self._admit()
+        if sp:
+            # the batch that the decode pass below runs with
+            sp.attrs.update(
+                running=len(self._running), waiting=len(self._waiting),
+                context_tokens=sum(len(r.prompt) + len(r.tokens)
+                                   for r in self._running.values()))
         if not self._running:
             if self._waiting:
                 self._admission_backoff()
@@ -904,14 +960,57 @@ class DecodeEngine:
         return x if prev is None else (1 - alpha) * prev + alpha * x
 
     def _step_decode(self, epoch: Optional[int] = None):
-        cfg = self.config
         if self._acct is not None:
             self._acct_tick(time.perf_counter())
         if epoch is None:
             epoch = self._epoch
+        with _obs.span("eng_decode_prep"):
+            active, host = self._decode_inputs(epoch)
+            if self._decode_jit is None:
+                self._decode_jit = self._build_decode()
+        warm = "decode" in self._compiled
+        t0 = time.perf_counter()
+        with _obs.span("eng_decode_upload"):
+            dev = [jnp.asarray(a) for a in host]
+        with _obs.span("eng_decode_dispatch"):
+            out = self._run_counted(
+                "decode", self._decode_jit,
+                self._state_vals(epoch), self._kc, self._vc, self._ksc,
+                self._vsc, *dev)
+            self._kc, self._vc, self._ksc, self._vsc, nxt, logits = out
+        with _obs.span("eng_decode_readback"):
+            # the per-token host transfer, [S] int32: waits for the step
+            nxt_host = np.asarray(nxt)
+        dt = time.perf_counter() - t0
+        _obs.observe("serving_decode_step_seconds", dt)
+        if self._fused_dequant_bytes_step:
+            _obs.inc("attn_kernel_fused_dequant_bytes_total",
+                     self._fused_dequant_bytes_step)
+        if warm:  # a compile-laden first step would poison the estimate
+            self._t_decode_ema = self._ema(self._t_decode_ema, dt)
+        with _obs.span("eng_decode_append"):
+            self._steps_since_probe += 1
+            self.decode_steps += 1
+            self.slot_steps += len(active)
+            self._last_logits = logits
+            if self._acct is not None:
+                self._acct_wire_bytes(active, int(logits.shape[-1]), 1)
+            for slot, req in active:
+                if req.decode_t0 is None:
+                    req.decode_t0 = t0  # first batched step it joined
+                req.decode_steps_n += 1
+                self.total_tokens += 1
+                self._append_token(req, int(nxt_host[slot]))
+            _obs.inc("serving_tokens_total", len(active))
+            self._update_gauges()
+
+    def _decode_inputs(self, epoch: int):
+        """The decode program's host arrays for one epoch group:
+        (active [(slot, request)], (tokens, positions, tables, keys, temp,
+        top_k, top_p, greedy) in the program's argument order)."""
         active = [(slot, req) for slot, req in self._running.items()
                   if req.epoch == epoch]
-        s = cfg.num_slots
+        s = self.config.num_slots
         tokens = np.zeros(s, np.int32)
         positions = np.zeros(s, np.int32)
         temp = np.ones(s, np.float32)
@@ -936,39 +1035,8 @@ class DecodeEngine:
             # untouched and their tokens below are never applied
             tables = self._tables.copy()
             tables[excluded] = 0
-        if self._decode_jit is None:
-            self._decode_jit = self._build_decode()
-        warm = "decode" in self._compiled
-        t0 = time.perf_counter()
-        out = self._run_counted(
-            "decode", self._decode_jit,
-            self._state_vals(epoch), self._kc, self._vc, self._ksc,
-            self._vsc, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(keys),
-            jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-            jnp.asarray(greedy))
-        self._kc, self._vc, self._ksc, self._vsc, nxt, logits = out
-        nxt_host = np.asarray(nxt)  # the per-token host transfer: [S] int32
-        dt = time.perf_counter() - t0
-        _obs.observe("serving_decode_step_seconds", dt)
-        if self._fused_dequant_bytes_step:
-            _obs.inc("attn_kernel_fused_dequant_bytes_total",
-                     self._fused_dequant_bytes_step)
-        if warm:  # a compile-laden first step would poison the estimate
-            self._t_decode_ema = self._ema(self._t_decode_ema, dt)
-        self._steps_since_probe += 1
-        self.decode_steps += 1
-        self._last_logits = logits
-        if self._acct is not None:
-            self._acct_wire_bytes(active, int(logits.shape[-1]), 1)
-        for slot, req in active:
-            if req.decode_t0 is None:
-                req.decode_t0 = t0  # first batched step this request joined
-            req.decode_steps_n += 1
-            self.total_tokens += 1
-            self._append_token(req, int(nxt_host[slot]))
-        _obs.inc("serving_tokens_total", len(active))
-        self._update_gauges()
+        return active, (tokens, positions, tables, keys, temp, top_k, top_p,
+                        greedy)
 
     def _step_verify(self, drafts: Dict[int, np.ndarray], k: int,
                      epoch: Optional[int] = None):
@@ -984,34 +1052,39 @@ class DecodeEngine:
         if self._acct is not None:
             self._acct_tick(time.perf_counter())
         s, k1 = cfg.num_slots, k + 1
-        tokens = np.zeros((s, k1), np.int32)
-        positions = np.zeros(s, np.int32)
-        temp = np.ones(s, np.float32)
-        top_k = np.zeros(s, np.int32)
-        top_p = np.ones(s, np.float32)
-        greedy = np.ones(s, bool)
-        keys = np.array(np.broadcast_to(
-            self._zero_key, (s,) + self._zero_key.shape))
-        for slot, req in self._running.items():
-            tokens[slot, 0] = req.tokens[-1]
-            tokens[slot, 1:] = drafts[slot]
-            positions[slot] = len(req.prompt) + len(req.tokens) - 1
-            t_, k_, p_, g_ = req.params.fields()
-            temp[slot], top_k[slot], top_p[slot], greedy[slot] = t_, k_, p_, g_
-            keys[slot] = req.key_np
-        if self._verify_jit is None:
-            self._verify_jit = self._build_verify(k1)
+        with _obs.span("eng_verify_prep"):
+            tokens = np.zeros((s, k1), np.int32)
+            positions = np.zeros(s, np.int32)
+            temp = np.ones(s, np.float32)
+            top_k = np.zeros(s, np.int32)
+            top_p = np.ones(s, np.float32)
+            greedy = np.ones(s, bool)
+            keys = np.array(np.broadcast_to(
+                self._zero_key, (s,) + self._zero_key.shape))
+            for slot, req in self._running.items():
+                tokens[slot, 0] = req.tokens[-1]
+                tokens[slot, 1:] = drafts[slot]
+                positions[slot] = len(req.prompt) + len(req.tokens) - 1
+                t_, k_, p_, g_ = req.params.fields()
+                temp[slot], top_k[slot], top_p[slot], greedy[slot] = (
+                    t_, k_, p_, g_)
+                keys[slot] = req.key_np
+            if self._verify_jit is None:
+                self._verify_jit = self._build_verify(k1)
         warm = f"verify_k{k}" in self._compiled
         t0 = time.perf_counter()
-        out = self._run_counted(
-            f"verify_k{k}", self._verify_jit,
-            self._state_vals(epoch), self._kc, self._vc, self._ksc,
-            self._vsc, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(self._tables), jnp.asarray(keys),
-            jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-            jnp.asarray(greedy))
-        self._kc, self._vc, self._ksc, self._vsc, targets, logits = out
-        targets_host = np.asarray(targets)  # [S, k+1] int32
+        with _obs.span("eng_verify_upload"):
+            dev = [jnp.asarray(a) for a in (
+                tokens, positions, self._tables, keys, temp, top_k, top_p,
+                greedy)]
+        with _obs.span("eng_verify_dispatch"):
+            out = self._run_counted(
+                f"verify_k{k}", self._verify_jit,
+                self._state_vals(epoch), self._kc, self._vc, self._ksc,
+                self._vsc, *dev)
+            self._kc, self._vc, self._ksc, self._vsc, targets, logits = out
+        with _obs.span("eng_verify_readback"):
+            targets_host = np.asarray(targets)  # [S, k+1] int32
         dt = time.perf_counter() - t0
         _obs.observe("serving_decode_step_seconds", dt)
         if self._fused_dequant_bytes_step:
@@ -1019,40 +1092,42 @@ class DecodeEngine:
                      self._fused_dequant_bytes_step)
         if warm:
             self._t_verify_ema = self._ema(self._t_verify_ema, dt)
-        self._steps_since_probe = 0
-        self.decode_steps += 1
-        self.verify_steps += 1
-        self._last_logits = logits
-        emitted = 0
-        active_slots = len(self._running)
-        if self._acct is not None:
-            self._acct_wire_bytes(list(self._running.items()),
-                                  int(logits.shape[-1]), k1)
-        for slot, req in list(self._running.items()):
-            tgt = targets_host[slot]
-            m = 0
-            while m < k and int(drafts[slot][m]) == int(tgt[m]):
-                m += 1
-            self.spec_proposed += k
-            self.spec_accepted += m
-            if req.decode_t0 is None:
-                req.decode_t0 = t0
-            req.decode_steps_n += 1
-            req.verify_steps_n += 1
-            req.spec_accepted_n += m
-            for tok in tgt[:m + 1]:
-                if req.status != "running":
-                    break  # budget/eos hit mid-emission
-                self.total_tokens += 1
-                emitted += 1
-                self._append_token(req, int(tok))
-        if active_slots:
-            self._tok_verify_ema = self._ema(
-                self._tok_verify_ema, emitted / active_slots)
-        _obs.inc("serving_tokens_total", emitted)
-        _obs.set_gauge("serving_spec_accept_ratio",
-                       self.spec_accepted / max(self.spec_proposed, 1))
-        self._update_gauges()
+        with _obs.span("eng_verify_append"):
+            self._steps_since_probe = 0
+            self.decode_steps += 1
+            self.verify_steps += 1
+            self._last_logits = logits
+            emitted = 0
+            active_slots = len(self._running)
+            self.slot_steps += active_slots
+            if self._acct is not None:
+                self._acct_wire_bytes(list(self._running.items()),
+                                      int(logits.shape[-1]), k1)
+            for slot, req in list(self._running.items()):
+                tgt = targets_host[slot]
+                m = 0
+                while m < k and int(drafts[slot][m]) == int(tgt[m]):
+                    m += 1
+                self.spec_proposed += k
+                self.spec_accepted += m
+                if req.decode_t0 is None:
+                    req.decode_t0 = t0
+                req.decode_steps_n += 1
+                req.verify_steps_n += 1
+                req.spec_accepted_n += m
+                for tok in tgt[:m + 1]:
+                    if req.status != "running":
+                        break  # budget/eos hit mid-emission
+                    self.total_tokens += 1
+                    emitted += 1
+                    self._append_token(req, int(tok))
+            if active_slots:
+                self._tok_verify_ema = self._ema(
+                    self._tok_verify_ema, emitted / active_slots)
+            _obs.inc("serving_tokens_total", emitted)
+            _obs.set_gauge("serving_spec_accept_ratio",
+                           self.spec_accepted / max(self.spec_proposed, 1))
+            self._update_gauges()
 
     def _collect_drafts(self, k: int):
         """Prompt-lookup drafts per running slot; slots with no n-gram
@@ -1229,6 +1304,7 @@ class DecodeEngine:
             "buckets": list(self.buckets),
             "decode_steps": self.decode_steps,
             "verify_steps": self.verify_steps,
+            "slot_steps": self.slot_steps,
             "total_tokens": self.total_tokens,
             "prompt_tokens_total": self.prompt_tokens_total,
             "running": len(self._running),
@@ -1646,7 +1722,21 @@ class DecodeEngine:
 
     def _admit(self):
         while self._free and self._waiting:
-            if not self._try_prefill(self._waiting[0], self._free[-1]):
+            req = self._waiting[0]
+            with _obs.span("eng_admit", rid=req.req_id,
+                           prompt_len=int(req.prompt.shape[0])) as sp:
+                admitted = self._try_prefill(req, self._free[-1])
+                if sp:
+                    sp.attrs["admitted"] = admitted
+                    if admitted:
+                        sp.attrs.update(
+                            cached_len=req.cached_len,
+                            bucket=self._bucket_for(
+                                len(req.prompt) - req.cached_len),
+                            queue_s=req.prefill_t0 - req.submit_time)
+                    if req.trace_id is not None:
+                        sp.attrs["request_trace_id"] = req.trace_id
+            if not admitted:
                 break  # head request can't get pages yet; keep FIFO order
             self._waiting.popleft()
             self._free.pop()
@@ -1703,24 +1793,30 @@ class DecodeEngine:
     def _prefill(self, req: Request, slot: int, row: np.ndarray,
                  cached_len: int):
         t0 = int(req.prompt.shape[0])
-        tail = req.prompt[cached_len:]
-        tb = self._bucket_for(len(tail))
-        fn = self._prefill_jit.get(tb)
-        if fn is None:
-            fn = self._build_prefill(tb)
-            self._prefill_jit[tb] = fn
-        ids = np.zeros((1, tb), np.int32)
-        ids[0, :len(tail)] = tail
-        t_, k_, p_, g_ = req.params.fields()
-        tp0 = time.perf_counter()
-        out = self._run_counted(
-            f"prefill_b{tb}", fn,
-            self._state_vals(), self._kc, self._vc, self._ksc, self._vsc,
-            jnp.asarray(ids), np.int32(cached_len), np.int32(t0),
-            jnp.asarray(row), jnp.asarray(req.key_np), np.float32(t_),
-            np.int32(k_), np.float32(p_), np.asarray(g_))
-        self._kc, self._vc, self._ksc, self._vsc, nxt, logits = out
-        token = int(nxt)
+        rid = req.req_id
+        with _obs.span("eng_prefill_prep", rid=rid):
+            tail = req.prompt[cached_len:]
+            tb = self._bucket_for(len(tail))
+            fn = self._prefill_jit.get(tb)
+            if fn is None:
+                fn = self._build_prefill(tb)
+                self._prefill_jit[tb] = fn
+            ids = np.zeros((1, tb), np.int32)
+            ids[0, :len(tail)] = tail
+            t_, k_, p_, g_ = req.params.fields()
+            tp0 = time.perf_counter()  # the uploads count as prefill
+            args = (jnp.asarray(ids), np.int32(cached_len), np.int32(t0),
+                    jnp.asarray(row), jnp.asarray(req.key_np),
+                    np.float32(t_), np.int32(k_), np.float32(p_),
+                    np.asarray(g_))
+        with _obs.span("eng_prefill_dispatch", rid=rid, bucket=int(tb)):
+            out = self._run_counted(
+                f"prefill_b{tb}", fn,
+                self._state_vals(), self._kc, self._vc, self._ksc,
+                self._vsc, *args)
+            self._kc, self._vc, self._ksc, self._vsc, nxt, logits = out
+        with _obs.span("eng_prefill_readback", rid=rid):
+            token = int(nxt)  # waits for the prefill
         now = time.perf_counter()
         req.first_token_time = now
         req.prefill_t0 = tp0
@@ -1736,6 +1832,8 @@ class DecodeEngine:
         req.status = "running"
         req.epoch = self._epoch  # admission pins the epoch it prefilled on
         self._running[slot] = req
+        if self._report is not None:
+            self._report.admitted.append(rid)
         self.total_tokens += 1
         self.prompt_tokens_total += t0
         _obs.inc("serving_tokens_total")
@@ -1743,6 +1841,8 @@ class DecodeEngine:
 
     def _append_token(self, req: Request, token: int):
         req.tokens.append(token)
+        if self._report is not None:
+            self._report.tokens.setdefault(req.req_id, []).append(token)
         p = req.params
         if len(req.tokens) >= p.max_new_tokens or (
                 p.eos_token_id is not None and token == p.eos_token_id):
@@ -1750,6 +1850,8 @@ class DecodeEngine:
 
     def _finish(self, req: Request):
         req.status = "done"
+        if self._report is not None:
+            self._report.finished.append(req.req_id)
         if self._acct is not None:
             # charge the page-occupancy tail while the request still
             # holds its pages, then attribute its totals to the ledger
@@ -1978,6 +2080,28 @@ class DecodeEngine:
             _layer_kv(vc, vsc, l, self._int8), tables, positions,
             kernel="einsum")
 
+    def _token_layer(self, l, x, kc, vc, ksc, vsc, tables, positions, pos2):
+        """One layer of the decode and verify programs (traced): the new
+        tokens' K/V go to their pages, then attention over the pages. Each
+        part under its ``named_scope``, which the compiled text keeps
+        (``profiler.op_scopes``)."""
+        ad, int8, psz = self.adapter, self._int8, self.config.page_size
+        with _scope("qkv"):
+            h = ad.pre_attn(l, x)
+            q, k, v = ad.qkv(l, h, pos2)
+        with _scope("kv_write"):
+            kc, ksc = _token_page_write(
+                kc, ksc, l, _shard_kv_heads(raw(k)), tables, pos2, int8, psz)
+            vc, vsc = _token_page_write(
+                vc, vsc, l, _shard_kv_heads(raw(v)), tables, pos2, int8, psz)
+        with _scope("attend"):
+            o = self._attend(q, kc, vc, ksc, vsc, l, tables, positions)
+        with _scope("attn_out"):
+            x = x + ad.attn_out(l, o)
+        with _scope("mlp"):
+            x = x + ad.mlp(l, x)
+        return x, kc, vc, ksc, vsc
+
     def _build_prefill(self, tb: int):
         ad, state, int8 = self.adapter, self._state, self._int8
         layers = ad.num_layers
@@ -1993,40 +2117,48 @@ class DecodeEngine:
                     positions = cached_len + jnp.arange(tb, dtype=jnp.int32)
                     start = jnp.reshape(cached_len, (1,)).astype(jnp.int32)
                     table = row[None]  # [1, MP]
-                    x = ad.embed(Tensor(ids), positions)
+                    with _scope("embed"):
+                        x = ad.embed(Tensor(ids), positions)
                     for l in range(layers):
-                        h = ad.pre_attn(l, x)
-                        q, k, v = ad.qkv(l, h, positions)
-                        kc, ksc = _block_page_write(
-                            kc, ksc, l, _shard_kv_heads(raw(k)), row,
-                            cached_len, true_len, int8, psz)
-                        vc, vsc = _block_page_write(
-                            vc, vsc, l, _shard_kv_heads(raw(v)), row,
-                            cached_len, true_len, int8, psz)
-                        o = self._attend(q, kc, vc, ksc, vsc, l, table,
-                                         start)
-                        x = x + ad.attn_out(l, o)
-                        x = x + ad.mlp(l, x)
-                    x = ad.final_norm(x)
-                    # right-pad positions >= true_len are inert under the
-                    # position mask; the real last-token logits sit at
-                    # tail offset true_len - 1 - cached_len
-                    last = jax.lax.dynamic_slice_in_dim(
-                        raw(x), true_len - 1 - cached_len, 1, 1)
-                    logits = raw(ad.logits(Tensor(last)))[:, 0].astype(
-                        jnp.float32)
+                        with _scope("qkv"):
+                            h = ad.pre_attn(l, x)
+                            q, k, v = ad.qkv(l, h, positions)
+                        with _scope("kv_write"):
+                            kc, ksc = _block_page_write(
+                                kc, ksc, l, _shard_kv_heads(raw(k)), row,
+                                cached_len, true_len, int8, psz)
+                            vc, vsc = _block_page_write(
+                                vc, vsc, l, _shard_kv_heads(raw(v)), row,
+                                cached_len, true_len, int8, psz)
+                        with _scope("attend"):
+                            o = self._attend(q, kc, vc, ksc, vsc, l, table,
+                                             start)
+                        with _scope("attn_out"):
+                            x = x + ad.attn_out(l, o)
+                        with _scope("mlp"):
+                            x = x + ad.mlp(l, x)
+                    with _scope("lm_head"):
+                        x = ad.final_norm(x)
+                        # right-pad positions >= true_len are inert under
+                        # the position mask; the real last-token logits sit
+                        # at tail offset true_len - 1 - cached_len
+                        last = jax.lax.dynamic_slice_in_dim(
+                            raw(x), true_len - 1 - cached_len, 1, 1)
+                        logits = raw(ad.logits(Tensor(last)))[:, 0].astype(
+                            jnp.float32)
             finally:
                 for t_, v_ in zip(state, originals):
                     t_._value = v_
             # sample stream keyed by DESTINATION position: token landing at
             # position true_len uses fold_in(key, true_len), matching what
             # the decode step would use — scheduling-invariant
-            step_key = jax.random.fold_in(
-                jax.random.wrap_key_data(key, impl=_KEY_IMPL), true_len)
-            s_logits, exact_arg, wired = self._wire_logits(logits)
-            nxt = _sample_tokens(s_logits, step_key[None], temp[None],
-                                 top_k[None], top_p[None], greedy[None],
-                                 exact_argmax=exact_arg)
+            with _scope("sample"):
+                step_key = jax.random.fold_in(
+                    jax.random.wrap_key_data(key, impl=_KEY_IMPL), true_len)
+                s_logits, exact_arg, wired = self._wire_logits(logits)
+                nxt = _sample_tokens(s_logits, step_key[None], temp[None],
+                                     top_k[None], top_p[None], greedy[None],
+                                     exact_argmax=exact_arg)
             kc, vc, ksc, vsc = _pin_pool_shardings(kc, vc, ksc, vsc)
             out_logits = (_replicate_out(logits[0]) if wired is None
                           else wired[0])
@@ -2036,9 +2168,8 @@ class DecodeEngine:
         return jax.jit(pure, donate_argnums=donate)
 
     def _build_decode(self):
-        ad, state, int8 = self.adapter, self._state, self._int8
+        ad, state = self.adapter, self._state
         layers = ad.num_layers
-        psz = self.config.page_size
 
         def pure(state_vals, kc, vc, ksc, vsc, tokens, positions, tables,
                  keys, temp, top_k, top_p, greedy):
@@ -2048,31 +2179,24 @@ class DecodeEngine:
                     t_._value = v_
                 with no_grad():
                     pos2 = positions[:, None]  # [S, 1]
-                    x = ad.embed(Tensor(tokens[:, None]), pos2)
+                    with _scope("embed"):
+                        x = ad.embed(Tensor(tokens[:, None]), pos2)
                     for l in range(layers):
-                        h = ad.pre_attn(l, x)
-                        q, k, v = ad.qkv(l, h, pos2)
-                        kc, ksc = _token_page_write(
-                            kc, ksc, l, _shard_kv_heads(raw(k)), tables,
-                            pos2, int8, psz)
-                        vc, vsc = _token_page_write(
-                            vc, vsc, l, _shard_kv_heads(raw(v)), tables,
-                            pos2, int8, psz)
-                        o = self._attend(q, kc, vc, ksc, vsc, l, tables,
-                                         positions)
-                        x = x + ad.attn_out(l, o)
-                        x = x + ad.mlp(l, x)
-                    x = ad.final_norm(x)
-                    logits = raw(ad.logits(x))[:, 0].astype(jnp.float32)
+                        x, kc, vc, ksc, vsc = self._token_layer(
+                            l, x, kc, vc, ksc, vsc, tables, positions, pos2)
+                    with _scope("lm_head"):
+                        x = ad.final_norm(x)
+                        logits = raw(ad.logits(x))[:, 0].astype(jnp.float32)
             finally:
                 for t_, v_ in zip(state, originals):
                     t_._value = v_
-            step_keys = jax.vmap(jax.random.fold_in)(
-                jax.random.wrap_key_data(keys, impl=_KEY_IMPL),
-                positions + 1)
-            s_logits, exact_arg, wired = self._wire_logits(logits)
-            nxt = _sample_tokens(s_logits, step_keys, temp, top_k, top_p,
-                                 greedy, exact_argmax=exact_arg)
+            with _scope("sample"):
+                step_keys = jax.vmap(jax.random.fold_in)(
+                    jax.random.wrap_key_data(keys, impl=_KEY_IMPL),
+                    positions + 1)
+                s_logits, exact_arg, wired = self._wire_logits(logits)
+                nxt = _sample_tokens(s_logits, step_keys, temp, top_k,
+                                     top_p, greedy, exact_argmax=exact_arg)
             kc, vc, ksc, vsc = _pin_pool_shardings(kc, vc, ksc, vsc)
             out_logits = _replicate_out(logits) if wired is None else wired
             return (kc, vc, ksc, vsc, _replicate_out(nxt), out_logits)
@@ -2084,9 +2208,8 @@ class DecodeEngine:
         """The speculative companion of the decode program: k1 = k + 1
         tokens per slot in one pass, per-position sampling on the SAME
         position-keyed streams."""
-        ad, state, int8 = self.adapter, self._state, self._int8
+        ad, state = self.adapter, self._state
         layers = ad.num_layers
-        psz = self.config.page_size
 
         def pure(state_vals, kc, vc, ksc, vsc, tokens, positions, tables,
                  keys, temp, top_k, top_p, greedy):
@@ -2098,36 +2221,31 @@ class DecodeEngine:
                 with no_grad():
                     pos2 = positions[:, None] + jnp.arange(
                         k1, dtype=jnp.int32)[None, :]  # [S, k1]
-                    x = ad.embed(Tensor(tokens), pos2)
+                    with _scope("embed"):
+                        x = ad.embed(Tensor(tokens), pos2)
                     for l in range(layers):
-                        h = ad.pre_attn(l, x)
-                        q, k, v = ad.qkv(l, h, pos2)
-                        kc, ksc = _token_page_write(
-                            kc, ksc, l, _shard_kv_heads(raw(k)), tables,
-                            pos2, int8, psz)
-                        vc, vsc = _token_page_write(
-                            vc, vsc, l, _shard_kv_heads(raw(v)), tables,
-                            pos2, int8, psz)
-                        o = self._attend(q, kc, vc, ksc, vsc, l, tables,
-                                         positions)
-                        x = x + ad.attn_out(l, o)
-                        x = x + ad.mlp(l, x)
-                    x = ad.final_norm(x)
-                    logits = raw(ad.logits(x)).astype(jnp.float32)  # [S,k1,V]
+                        x, kc, vc, ksc, vsc = self._token_layer(
+                            l, x, kc, vc, ksc, vsc, tables, positions, pos2)
+                    with _scope("lm_head"):
+                        x = ad.final_norm(x)
+                        logits = raw(ad.logits(x)).astype(
+                            jnp.float32)  # [S,k1,V]
             finally:
                 for t_, v_ in zip(state, originals):
                     t_._value = v_
-            step_keys = jax.vmap(jax.vmap(
-                jax.random.fold_in, in_axes=(None, 0)))(
-                jax.random.wrap_key_data(keys, impl=_KEY_IMPL), pos2 + 1)
-            s_logits, exact_arg, wired = self._wire_logits(logits)
-            flat = s_logits.reshape(s * k1, -1)
-            rep = lambda a: jnp.repeat(a, k1, axis=0)
-            targets = _sample_tokens(
-                flat, step_keys.reshape(s * k1), rep(temp), rep(top_k),
-                rep(top_p), rep(greedy),
-                exact_argmax=(None if exact_arg is None
-                              else exact_arg.reshape(s * k1))).reshape(s, k1)
+            with _scope("sample"):
+                step_keys = jax.vmap(jax.vmap(
+                    jax.random.fold_in, in_axes=(None, 0)))(
+                    jax.random.wrap_key_data(keys, impl=_KEY_IMPL), pos2 + 1)
+                s_logits, exact_arg, wired = self._wire_logits(logits)
+                flat = s_logits.reshape(s * k1, -1)
+                rep = lambda a: jnp.repeat(a, k1, axis=0)
+                targets = _sample_tokens(
+                    flat, step_keys.reshape(s * k1), rep(temp), rep(top_k),
+                    rep(top_p), rep(greedy),
+                    exact_argmax=(None if exact_arg is None
+                                  else exact_arg.reshape(s * k1))
+                ).reshape(s, k1)
             kc, vc, ksc, vsc = _pin_pool_shardings(kc, vc, ksc, vsc)
             out_logits = _replicate_out(logits) if wired is None else wired
             return (kc, vc, ksc, vsc, _replicate_out(targets), out_logits)

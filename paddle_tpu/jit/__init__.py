@@ -344,52 +344,65 @@ class TrainStep:
         """Shared plumbing for the single-step and multi-step paths: state
         extraction, cache get-or-compile, rng draw, and the write-back of
         params/buffers/optimizer states. Returns the jitted fn's first
-        output (loss scalar or per-step losses)."""
+        output (loss scalar or per-step losses). A warm call is one
+        ``train_step`` span over its three parts: the HOST's time to
+        dispatch the step, not the step's (nothing here waits for the
+        device)."""
         t0 = time.perf_counter()
-        params = self._params
-        buffers = self._buffers + self._extra_params
-        p_vals = [p._value for p in params]
-        b_vals = [b._value for b in buffers]
-        opt_states = self._opt.functional_states()
-        lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
-        rng_key = _rng.next_key()
         jitted = self._cache.get(key)
-        miss = jitted is None
-        aot_hit = None
-        if miss:
-            jitted = build()
-            aot = _compile_cache.resolve()
-            if aot is not None:
-                try:
-                    lowered = jitted.lower(
-                        p_vals, b_vals, opt_states, batch_vals, lr, rng_key)
-                    ckey = aot.key_for(lowered, config=self._aot_key_parts(),
-                                       mesh=self._aot_mesh())
-                    jitted, aot_hit = aot.load_or_compile(
-                        lowered, ckey, where="train_step")
-                except Exception:  # noqa: BLE001
-                    # the cache must never break training — fall back to
-                    # the plain jit path (first call compiles normally)
-                    jitted, aot_hit = build(), None
-            self._cache[key] = jitted
-        out, new_p, new_b, new_st = jitted(
-            p_vals, b_vals, opt_states, batch_vals, lr, rng_key)
-        for p, v in zip(params, new_p):
+        if jitted is None:
+            return self._dispatch_miss(key, build, batch_vals, t0)
+        with _obs.span("train_step"):
+            with _obs.span("train_gather"):
+                args = self._gather(batch_vals)
+            with _obs.span("train_dispatch"):
+                out, new_p, new_b, new_st = jitted(*args)
+            with _obs.span("train_writeback"):
+                self._write_back(new_p, new_b, new_st)
+        _obs.observe("train_step_seconds", time.perf_counter() - t0)
+        return out
+
+    def _gather(self, batch_vals):
+        """The compiled step's arguments from the live state."""
+        return (
+            [p._value for p in self._params],
+            [b._value for b in self._buffers + self._extra_params],
+            self._opt.functional_states(), batch_vals,
+            jnp.asarray(self._opt.get_lr(), jnp.float32), _rng.next_key())
+
+    def _write_back(self, new_p, new_b, new_st):
+        for p, v in zip(self._params, new_p):
             p._value = v
-        for b, v in zip(buffers, new_b):
+        for b, v in zip(self._buffers + self._extra_params, new_b):
             b._value = v
         self._opt.load_functional_states(new_st)
-        dt = time.perf_counter() - t0
-        if miss:
-            # compile steps are tracked separately so they don't pollute
-            # the steady-state step-time distribution (record_compile also
-            # emits the 'compile' span)
-            _obs.record_compile("train_step", dt,
-                                signature=f"{type(self).__name__} {key!r}",
-                                cache_hit=aot_hit)
-        else:
-            _obs.observe("train_step_seconds", dt)
-            _obs.record_span("train_step", dur_s=dt)
+
+    def _dispatch_miss(self, key, build, batch_vals, t0):
+        """The first call of a signature: build, load or compile, run.
+        Tracked apart from the warm steps so that it does not pollute the
+        steady-state step-time distribution (record_compile also emits the
+        'compile' span)."""
+        args = self._gather(batch_vals)
+        jitted = build()
+        aot_hit = None
+        aot = _compile_cache.resolve()
+        if aot is not None:
+            try:
+                lowered = jitted.lower(*args)
+                ckey = aot.key_for(lowered, config=self._aot_key_parts(),
+                                   mesh=self._aot_mesh())
+                jitted, aot_hit = aot.load_or_compile(
+                    lowered, ckey, where="train_step")
+            except Exception:  # noqa: BLE001
+                # the cache must never break training — fall back to
+                # the plain jit path (first call compiles normally)
+                jitted, aot_hit = build(), None
+        self._cache[key] = jitted
+        out, new_p, new_b, new_st = jitted(*args)
+        self._write_back(new_p, new_b, new_st)
+        _obs.record_compile("train_step", time.perf_counter() - t0,
+                            signature=f"{type(self).__name__} {key!r}",
+                            cache_hit=aot_hit)
         return out
 
     def _place_batch(self, batch_vals):
@@ -569,7 +582,8 @@ class TrainStep:
                     for b, v in zip(buffers, b_vals):
                         b._value = v
                     batch_t = [Tensor(v) for v in batch_vals]
-                    loss = loss_fn(model, *batch_t)
+                    with jax.named_scope("forward_loss"):
+                        loss = loss_fn(model, *batch_t)
                     loss_val = raw(loss)
                     new_b = [b._value for b in buffers]
                 finally:
@@ -594,7 +608,9 @@ class TrainStep:
                 p_vals, (b_vals, batch_vals, rng_key)
             )
             grads = [g if t else None for g, t in zip(grads, trainable)]
-            new_p, new_st = opt.functional_step(p_vals, grads, opt_states, lr)
+            with jax.named_scope("optimizer_update"):
+                new_p, new_st = opt.functional_step(
+                    p_vals, grads, opt_states, lr)
             return loss_val, new_p, new_b, new_st
 
         return step

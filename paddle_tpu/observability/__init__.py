@@ -15,13 +15,16 @@ Three layers (docs/OBSERVABILITY.md):
    context propagation over per-rank ``spans_rank{R}.jsonl`` sinks —
    ``span``/``start_span``/``end_span``/``record_span`` re-exported here;
    ``scripts/trace_report.py`` merges the files into a Perfetto timeline
-   and a per-SLO-class latency attribution table.
+   and a per-SLO-class latency attribution table. Spans are also on while
+   a profiler session records (``tracing.active()``): they then lie in an
+   in-process buffer (``tracing.recorded()``) and, as ``TraceAnnotation``s,
+   on the profiler's own timeline beside the device's operations.
 
-Everything is env-gated on ``PADDLE_TPU_TELEMETRY_DIR``: with it unset, the
-module-level helpers below return before touching the registry or the
-filesystem, so instrumented hot paths (train step dispatch, store RPCs,
-heartbeat loops) pay one dict lookup in ``os.environ`` and nothing else —
-guarded by
+Metrics and events are env-gated on ``PADDLE_TPU_TELEMETRY_DIR``: with it
+unset, the module-level helpers below return before touching the registry
+or the filesystem, so instrumented hot paths (train step dispatch, store
+RPCs, heartbeat loops) pay one dict lookup in ``os.environ`` and nothing
+else; a span pays that and one static call into the profiler — guarded by
 ``tests/test_observability.py::test_disabled_adds_no_measurable_overhead``.
 
 Hot-path call convention (enforced by ``scripts/check_observability.py``
@@ -44,6 +47,8 @@ import os
 import threading
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from . import catalog
 from . import tracing
@@ -80,6 +85,9 @@ _io_lock = threading.Lock()
 # stays stdlib-standalone (trace_report.py loads it without this package)
 tracing._counter_hook = (
     lambda name: _registry.counter("trace_spans_total").inc(1, name=name))
+# ... and asks the profiler whether a session records, and lies on its
+# timeline, through the one class it is handed here
+tracing._annotation = _TraceAnnotation
 
 
 # ---------------------------------------------------------------------------

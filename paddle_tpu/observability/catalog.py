@@ -32,7 +32,8 @@ METRICS = {
         "Wall time of each cache-miss step: trace + compile + first run"),
     # -- training loop ------------------------------------------------------
     "train_step_seconds": (
-        "histogram", "Per-step wall time measured at the train-step dispatch"),
+        "histogram", "Host time of one warm train-step dispatch (nothing "
+                     "is fenced: not the step's device time)"),
     "train_tokens_per_second": (
         "gauge", "Input elements consumed per second (last step)"),
     "train_flops_per_second": (
@@ -521,6 +522,68 @@ SPANS = {
         "Speculative share of the decode window, child of srv_decode "
         "(attrs: steps, accepted); emitted only when the request ran "
         "draft/verify steps"),
+    # -- the engine's step tree (one a DecodeEngine.step(), whoever drives
+    # it; docs/OBSERVABILITY.md section 8) -----------------------------------
+    "eng_step": (
+        "paddle_tpu/inference/engine.py",
+        "Root of one DecodeEngine.step() that had something running or "
+        "waiting (an idle poll leaves none): admissions, then one decode or "
+        "verify pass (attrs: num_slots, running and waiting after the "
+        "admissions, context_tokens live in the running slots, emitted = "
+        "{rid: new tokens}; slot_steps and slot_capacity, the engine's "
+        "running totals of slots advanced and of decode passes x num_slots)"),
+    "eng_admit": (
+        "paddle_tpu/inference/engine.py",
+        "One admission attempt, child of eng_step: page reservation, "
+        "prefix lookup and the inline prefill (attrs: rid, prompt_len, "
+        "admitted; when admitted cached_len, bucket, queue_s = prefill "
+        "start less submit; request_trace_id where a router gave one)"),
+    "eng_prefill_prep": (
+        "paddle_tpu/inference/engine.py",
+        "Prefill, host: bucket choice, padded ids, argument uploads "
+        "(attrs: rid; child of eng_admit, a root under prefill_export)"),
+    "eng_prefill_dispatch": (
+        "paddle_tpu/inference/engine.py",
+        "Prefill, host: the call that enqueues the compiled prefill "
+        "(attrs: rid, bucket; includes compile on a cold bucket)"),
+    "eng_prefill_readback": (
+        "paddle_tpu/inference/engine.py",
+        "Prefill: the blocking read of the first token, i.e. the wait "
+        "for the device (attrs: rid)"),
+    "eng_decode_prep": (
+        "paddle_tpu/inference/engine.py",
+        "Decode, host: the eight per-slot numpy arrays of one step"),
+    "eng_decode_upload": (
+        "paddle_tpu/inference/engine.py",
+        "Decode, host: those arrays to the device (eight jnp.asarray)"),
+    "eng_decode_dispatch": (
+        "paddle_tpu/inference/engine.py",
+        "Decode, host: the call that enqueues the compiled decode step"),
+    "eng_decode_readback": (
+        "paddle_tpu/inference/engine.py",
+        "Decode: the blocking read of the [num_slots] next tokens, i.e. "
+        "the wait for the device"),
+    "eng_decode_append": (
+        "paddle_tpu/inference/engine.py",
+        "Decode, host: tokens appended, requests finished, pages freed, "
+        "gauges"),
+    "eng_verify_prep": (
+        "paddle_tpu/inference/engine.py",
+        "Speculative verify step, host: as eng_decode_prep, k+1 tokens a "
+        "slot"),
+    "eng_verify_upload": (
+        "paddle_tpu/inference/engine.py",
+        "Speculative verify step, host: argument uploads"),
+    "eng_verify_dispatch": (
+        "paddle_tpu/inference/engine.py",
+        "Speculative verify step, host: the enqueueing call"),
+    "eng_verify_readback": (
+        "paddle_tpu/inference/engine.py",
+        "Speculative verify step: the blocking read of the [num_slots, "
+        "k+1] targets"),
+    "eng_verify_append": (
+        "paddle_tpu/inference/engine.py",
+        "Speculative verify step, host: acceptance and token bookkeeping"),
     # -- training side ------------------------------------------------------
     "compile": (
         "paddle_tpu/observability/__init__.py",
@@ -529,8 +592,20 @@ SPANS = {
         "signature)"),
     "train_step": (
         "paddle_tpu/jit/__init__.py",
-        "One warm train-step dispatch (cache hits only; misses are "
-        "'compile' spans)"),
+        "One warm TrainStep dispatch on the HOST (cache hits only; misses "
+        "are 'compile' spans): gather, enqueue, write-back. Nothing is "
+        "fenced, so this is the host's dispatch time, not the step's"),
+    "train_gather": (
+        "paddle_tpu/jit/__init__.py",
+        "Child of train_step: parameter, buffer and optimizer-state "
+        "values, the lr array and the step's rng key"),
+    "train_dispatch": (
+        "paddle_tpu/jit/__init__.py",
+        "Child of train_step: the call that enqueues the compiled step"),
+    "train_writeback": (
+        "paddle_tpu/jit/__init__.py",
+        "Child of train_step: new values back into parameters, buffers "
+        "and optimizer states"),
     "pp_tick_window": (
         "paddle_tpu/distributed/fleet/meta_parallel/pipeline_parallel.py",
         "Host-side pipeline schedule build for one micro-batched step "

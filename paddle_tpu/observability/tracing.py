@@ -17,9 +17,13 @@ three processes::
       │                                             disaggregated prefill)
       └─ srv_prefill / srv_decode ── srv_verify    (engine)
 
-and the training side emits single-span trees per compile miss, train
-step, checkpoint commit, reshard, pipeline-schedule build and gradient-
-exchange build — all through the same three entry points:
+and the training side emits single-span trees per compile miss,
+checkpoint commit, reshard, pipeline-schedule build and gradient-exchange
+build. What the host does INSIDE a step is a tree a step: ``eng_step``
+over ``eng_admit`` (``eng_prefill_*``) and ``eng_decode_*`` /
+``eng_verify_*`` in ``DecodeEngine.step``, ``train_step`` over
+``train_gather`` / ``train_dispatch`` / ``train_writeback`` in
+``TrainStep`` — all through the same three entry points:
 
 * ``span(name, **attrs)`` — context manager; nested spans chain through a
   thread-local stack (child inherits trace_id, parent_id);
@@ -34,16 +38,30 @@ Cross-process propagation is a plain dict (``{"trace_id", "parent_id",
 (serving/protocol.py) next to the router-assigned seed; the worker and
 engine continue the trace from it.
 
-Discipline matches the PR 3 event log exactly: everything is env-gated on
-``PADDLE_TPU_TELEMETRY_DIR`` (re-read per call; the disabled path is one
-dict lookup), and each finished span is ONE ``json.dumps`` line appended
-open/append/close under a lock to ``spans_rank{R}.jsonl`` — O_APPEND
-atomicity means concurrent writers interleave whole lines and a SIGKILL
-never tears a flushed span (an *unfinished* span is simply lost, which is
-the correct account of a killed process).
+Spans are on while somebody is tracing (``active()``): a profiler session
+records (``jax.profiler.start_trace`` / ``paddle_tpu.profiler.Profiler``) or
+``PADDLE_TPU_TELEMETRY_DIR`` is set (re-read per call). Off, an entry point
+costs one static call into the profiler and one dict lookup. On, every
+finished span goes to two places:
+
+* one bounded in-process buffer, ``(name, t0, t1, trace_id, span_id,
+  parent_id, attrs)`` on the ``time.perf_counter`` clock, read back with
+  ``recorded(t_from, t_to)`` — what a benchmark's traced run lays against
+  the device's idle gaps;
+* under the telemetry directory, ONE ``json.dumps`` line a span, appended
+  open/append/close under a lock to ``spans_rank{R}.jsonl`` — O_APPEND
+  atomicity means concurrent writers interleave whole lines and a SIGKILL
+  never tears a flushed span (an *unfinished* span is simply lost, which is
+  the correct account of a killed process). Spans that finish inside a
+  ``with span(...)`` are appended with their root, in one write: a step's
+  tree of a dozen spans costs one append (at most ``_HELD_MAX`` lines wait).
+
+The context-manager form also enters a ``jax.profiler.TraceAnnotation`` of
+the span's name, so the span lies on ``/host:CPU`` of the same ``.xplane.pb``
+as the device's ``XLA Ops`` line (xprof / Perfetto show both).
 
 Timing: durations come from the monotonic ``time.perf_counter`` clock;
-each record also carries a wall-clock start (``ts``) so per-process span
+each JSONL record also carries a wall-clock start (``ts``) so per-process span
 streams can be merged onto one Perfetto timeline (scripts/trace_report.py).
 Cross-host wall skew shifts tracks, never durations. The cross-process
 spans — ``srv_store_transit``/``srv_net_transit`` (dispatch transit) and
@@ -63,10 +81,12 @@ import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from collections import deque
+from typing import Dict, List, NamedTuple, Optional
 
 __all__ = [
     "span", "start_span", "end_span", "record_span", "new_trace_id",
+    "active", "recorded", "Recorded",
     "load_spans", "summarize_spans", "summarize_dir", "validate_trees",
     "SpanTailer", "compute_burn",
 ]
@@ -77,6 +97,34 @@ _local = threading.local()
 #: set by observability/__init__ to count recorded spans into the
 #: registry (trace_spans_total); None keeps this module stdlib-standalone
 _counter_hook = None
+
+#: set by observability/__init__ to ``jax.profiler.TraceAnnotation``: says
+#: whether a profiler session records, and puts ``span(...)`` on its
+#: timeline; None (this file loaded by its path) leaves only the directory
+_annotation = None
+
+
+class Recorded(NamedTuple):
+    """One finished span in the in-process buffer; ``t0``/``t1`` are
+    ``time.perf_counter`` seconds."""
+    name: str
+    t0: float
+    t1: float
+    trace_id: str
+    span_id: str
+    parent_id: Optional[str]
+    attrs: dict
+
+
+#: every finished span of this process, oldest dropped first: a traced
+#: window of a few seconds holds a few hundred, a day of telemetry does
+#: not grow the process
+_buffer: deque = deque(maxlen=1 << 16)
+
+#: lines that may wait in memory for their root's append: a tree that
+#: grows past it is written as it goes, so a long ``with span(...)`` loses
+#: at most this many finished spans to a SIGKILL
+_HELD_MAX = 64
 
 #: span name -> report phase for per-request latency attribution.
 #: store_transit and net_transit are mutually exclusive per attempt (the
@@ -98,6 +146,23 @@ PHASES = ("queue", "store_transit", "net_transit", "prefill", "kv_stream",
 def _dir() -> Optional[str]:
     d = os.environ.get("PADDLE_TPU_TELEMETRY_DIR")
     return d if d else None
+
+
+def active() -> bool:
+    """Is somebody tracing: a profiler session records, or the telemetry
+    directory is set. Every entry point asks this first."""
+    ann = _annotation
+    return (ann is not None and ann.is_enabled()) or _dir() is not None
+
+
+def recorded(t_from: Optional[float] = None,
+             t_to: Optional[float] = None) -> List[Recorded]:
+    """The buffer's spans that lie inside ``[t_from, t_to]`` on the
+    ``time.perf_counter`` clock (either end open when None), oldest
+    first."""
+    return [r for r in list(_buffer)
+            if (t_from is None or r.t0 >= t_from)
+            and (t_to is None or r.t1 <= t_to)]
 
 
 def _rank() -> int:
@@ -156,12 +221,17 @@ class SpanHandle:
         return True
 
 
-def _write(name: str, trace_id: str, span_id: str,
-           parent_id: Optional[str], wall_start: float, dur_s: float,
-           attrs: dict) -> None:
+def _emit(name: str, trace_id: str, span_id: str,
+          parent_id: Optional[str], wall_start: float, t0: float, t1: float,
+          attrs: dict) -> None:
+    """A finished span: into the buffer, and to the JSONL sink where the
+    telemetry directory is set."""
+    _buffer.append(Recorded(name, t0, t1, trace_id, span_id, parent_id,
+                            attrs))
     d = _dir()
     if d is None:
-        return  # flipped off between start and end: drop, never block
+        return
+    dur_s = t1 - t0
     rec = {
         "kind": "span",
         "name": name,
@@ -175,27 +245,38 @@ def _write(name: str, trace_id: str, span_id: str,
     }
     if attrs:
         rec["attrs"] = attrs
-    line = json.dumps(rec, default=str) + "\n"
+    if _counter_hook is not None:
+        _counter_hook(name)
+    held = getattr(_local, "held", None)
+    if held is None:
+        held = _local.held = []
+    held.append(json.dumps(rec, default=str) + "\n")
+    if _stack() and len(held) < _HELD_MAX:
+        # inside a ``with span(...)``: the lines wait for their root, and a
+        # step's tree costs one append, not one a span (on the chip's host a
+        # dozen appends a decode step cost 2% of the step: PERF.md)
+        return
+    lines = "".join(held)
+    del held[:]
     path = os.path.join(d, f"spans_rank{_rank()}.jsonl")
     with _io_lock:
         os.makedirs(d, exist_ok=True)
-        # open/append/close per span: one O_APPEND write per line is
+        # open/append/close per tree: one O_APPEND write of whole lines is
         # atomic across the router/worker processes sharing a rank file,
-        # and nothing sits in a buffer when a SIGKILL lands
+        # and nothing of a finished tree sits in a buffer when a SIGKILL
+        # lands (an unfinished tree is lost with its root)
         with open(path, "a") as f:
-            f.write(line)
-    if _counter_hook is not None:
-        _counter_hook(name)
+            f.write(lines)
 
 
 def start_span(name: str, *, trace_id: Optional[str] = None,
                parent_id: Optional[str] = None, **attrs):
-    """Open a span and return its handle (``_NOOP`` when telemetry is
-    off). With no explicit ``trace_id`` the innermost enclosing
+    """Open a span and return its handle (``_NOOP`` when nobody is
+    tracing). With no explicit ``trace_id`` the innermost enclosing
     ``span(...)`` context supplies trace and parent; with neither, a
     fresh trace is minted (this span is a root). The caller owns the
     handle — nothing is written until ``end_span``."""
-    if _dir() is None:
+    if not active():
         return _NOOP
     if trace_id is None:
         st = _stack()
@@ -216,9 +297,9 @@ def end_span(handle, **attrs) -> Optional[str]:
         return None
     if attrs:
         handle.attrs.update(attrs)
-    _write(handle.name, handle.trace_id, handle.span_id,
-           handle.parent_id, handle._wall0,
-           time.perf_counter() - handle._t0, handle.attrs)
+    _emit(handle.name, handle.trace_id, handle.span_id,
+          handle.parent_id, handle._wall0, handle._t0,
+          time.perf_counter(), handle.attrs)
     return handle.span_id
 
 
@@ -231,11 +312,12 @@ def record_span(name: str, *, trace_id: Optional[str] = None,
     ``dur_s`` (wall start is derived from ``end_ts`` minus it; default
     end is now) or an explicit ``start_ts`` wall clock (the
     cross-process ``srv_store_transit`` case). Returns the new span id
-    so later spans can parent to it, or None when telemetry is off."""
-    if _dir() is None:
+    so later spans can parent to it, or None when nobody is tracing."""
+    if not active():
         return None
+    now_wall, now = time.time(), time.perf_counter()
     if end_ts is None:
-        end_ts = time.time()
+        end_ts = now_wall
     if dur_s is None:
         dur_s = 0.0 if start_ts is None else max(end_ts - start_ts, 0.0)
     if start_ts is None:
@@ -243,7 +325,9 @@ def record_span(name: str, *, trace_id: Optional[str] = None,
     if trace_id is None:
         trace_id = new_trace_id()
     sid = _new_span_id()
-    _write(name, trace_id, sid, parent_id, start_ts, dur_s, attrs)
+    t1 = now - (now_wall - end_ts)  # the wall clock's end on the buffer's
+    _emit(name, trace_id, sid, parent_id, start_ts,
+          t1 - max(float(dur_s), 0.0), t1, attrs)
     return sid
 
 
@@ -255,36 +339,50 @@ class span:
 
     ``trace_id``/``parent_id`` keyword arguments join an existing trace
     (they are reserved and never become attrs); all other keywords are
-    span attributes. Disabled cost is one env lookup."""
+    span attributes; more can be put into the handle's ``attrs`` before
+    the exit (``if h: h.attrs[...] = ...``: the no-op handle is falsy).
+    While a profiler session records, the span is also a
+    ``TraceAnnotation`` of its name on the profiler's timeline."""
 
-    __slots__ = ("_name", "_kw", "_handle")
+    __slots__ = ("_name", "_kw", "_handle", "_ann")
 
     def __init__(self, name: str, **kw):
         self._name = name
         self._kw = kw
         self._handle = None
+        self._ann = None
 
     def __enter__(self):
-        if _dir() is None:
+        if not active():
             return _NOOP
         kw = self._kw
+        if _annotation is not None:
+            # no keyword arguments: they would change the event's name
+            self._ann = _annotation(self._name)
+            self._ann.__enter__()
         self._handle = start_span(
             self._name, trace_id=kw.pop("trace_id", None),
             parent_id=kw.pop("parent_id", None), **kw)
-        _stack().append(self._handle)
+        if self._handle:
+            _stack().append(self._handle)
         return self._handle
 
     def __exit__(self, exc_type, exc, tb):
         h = self._handle
-        if h is not None:
+        if h:
             st = _stack()
             if st and st[-1] is h:
                 st.pop()
+            elif h in st:  # exits out of order: never leave a stale parent
+                st.remove(h)
             if exc_type is not None:
                 end_span(h, error=repr(exc))
             else:
                 end_span(h)
-            self._handle = None
+        self._handle = None
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         return False
 
 

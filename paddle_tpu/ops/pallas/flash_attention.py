@@ -303,6 +303,7 @@ def _fa_forward(q, k, v, bias, causal, scale, n_heads, n_kv_heads,
             _scratch((block_q, d)),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
     return (o[:, :tq] if pad_q else o), lse[:, :, 0]
 
@@ -500,6 +501,7 @@ def _fa_backward(q, k, v, bias, o, lse, do, causal, scale, n_heads,
         out_specs=out_specs,
         scratch_shapes=[_scratch((block_q, d))],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*args)
     if want_dbias:
         dq, ds_full = dq_out
@@ -577,6 +579,7 @@ def _fa_backward(q, k, v, bias, o, lse, do, causal, scale, n_heads,
         out_specs=[kv_out_spec, kv_out_spec],
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*args2)
     dk, dv = dk[:, :tk], dv[:, :tk]
     group = n_heads // n_kv_heads

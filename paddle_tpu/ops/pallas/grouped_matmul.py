@@ -145,6 +145,7 @@ def _gmm_forward(lhs, rhs, sched, block_m, block_n, interpret):
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         interpret=interpret,
+        name="grouped_matmul_fwd",
     )(sched, lhs, rhs)
 
 
@@ -204,6 +205,7 @@ def _gmm_drhs(lhs, dout, sched, num_groups, block_m, block_n, interpret):
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((num_groups, k, n), jnp.float32),
         interpret=interpret,
+        name="grouped_matmul_drhs",
     )(sched, lhs, dout)
 
 

@@ -238,6 +238,7 @@ def paged_attention(
         out_shape=jax.ShapeDtypeStruct((s, hkv, rows8, d), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), start_position.astype(jnp.int32), *args)
     out = out[:, :, :rows]
     return out.reshape(s, hkv, t, groups, d).transpose(

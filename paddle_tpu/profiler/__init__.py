@@ -18,6 +18,7 @@ import collections
 import contextlib
 import json
 import os
+import re
 import time
 from enum import Enum
 from typing import Callable, Iterable, List, Optional, Union
@@ -25,6 +26,7 @@ from typing import Callable, Iterable, List, Optional, Union
 import jax
 
 from .. import runtime as _runtime
+from ..observability import tracing as _tracing
 
 
 class ProfilerTarget(Enum):
@@ -120,15 +122,23 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
 class RecordEvent:
     """Scoped host annotation (reference: platform::RecordEvent).
 
-    Shows up in the XLA trace timeline and in Profiler.summary().
+    Shows up in the XLA trace timeline and in Profiler.summary(). It is a
+    span of ``observability.tracing``: a user's annotation and the
+    engine's own land in one buffer (``tracing.recorded()``) and on one
+    timeline. ``begin``/``end`` may be far apart, out of order or on two
+    threads, so the event is an explicit handle and never a parent on the
+    nesting stack: spans that finish under it stay roots of their own and
+    reach the JSONL sink as they finish.
     """
 
     def __init__(self, name: str, event_type=None):
         self.name = name
         self._ann = None
+        self._span = None
         self._t0 = None
 
     def begin(self):
+        self._span = _tracing.start_span(self.name)
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
@@ -141,6 +151,7 @@ class RecordEvent:
     def end(self):
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
+            _tracing.end_span(self._span)
             _host_events[self.name][1] += time.perf_counter() - self._t0
             if self._t0_ns is not None and _runtime.trace_enabled():
                 import threading as _threading
@@ -326,6 +337,33 @@ class Profiler:
         out = "\n".join(lines)
         print(out)
         return out
+
+
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*\bop_name="([^"]*)"', re.M)
+_JIT_WRAPPER = re.compile(r"^p?jit\(.*\)$")
+
+
+def op_scopes(hlo_text: str) -> dict:
+    """{instruction name: innermost scope} over a compiled program's text
+    (``compiled.as_text()``: ``TrainStep._compiled_for(...)``,
+    ``DecodeEngine.program_text(...)``).
+
+    A device trace names an operation by its HLO line and carries no
+    ``op_name``, so the ``jax.named_scope`` a fusion came from is found
+    here: ``%multiply_add_fusion.3 = ... metadata={op_name=
+    "jit(step)/optimizer_update/add"}`` gives ``{"multiply_add_fusion.3":
+    "optimizer_update"}``. The scope is the last part of the path before
+    the primitive, ``jit(...)`` wrappers left out; jax's own transforms
+    stay (``transpose(jvp(forward_loss))`` is the backward pass). An
+    instruction outside every scope is left out."""
+    out = {}
+    for name, op_name in _HLO_OP_NAME.findall(hlo_text):
+        scopes = [p for p in op_name.split("/")[:-1]
+                  if not _JIT_WRAPPER.match(p)]
+        if scopes:
+            out[name] = scopes[-1]
+    return out
 
 
 @contextlib.contextmanager

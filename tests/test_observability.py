@@ -199,22 +199,40 @@ def test_disabled_is_inert(tmp_path, monkeypatch):
     assert not any(tmp_path.iterdir())
 
 
-def test_disabled_adds_no_measurable_overhead(monkeypatch):
+def _record_metrics():
+    obs.observe("train_step_seconds", 0.01)
+    obs.inc("xla_compile_total")
+
+
+def _record_spans():
+    with obs.span("train_step"):
+        pass
+    obs.record_span("train_step", dur_s=0.01)
+
+
+@pytest.mark.parametrize("record", [_record_metrics, _record_spans],
+                         ids=["metrics", "spans"])
+def test_disabled_adds_no_measurable_overhead(monkeypatch, record):
     """Acceptance guard: with telemetry off, a recording call must stay a
-    single env lookup — no locks, registry writes, or file I/O. 20us/call
-    is ~40x the observed cost, loose enough for a loaded CI box while still
-    catching any accidental I/O on the disabled path."""
+    single env lookup (a span: that and one static call that asks the
+    profiler whether a session records) — no locks, registry writes, or
+    file I/O. 20us/call is ~40x the observed cost, loose enough for a
+    loaded CI box while still catching any accidental I/O on the disabled
+    path."""
+    from paddle_tpu.observability import tracing
+
     monkeypatch.delenv("PADDLE_TPU_TELEMETRY_DIR", raising=False)
     obs.reset()
+    before = len(tracing.recorded())
     n = 20_000
     t0 = time.perf_counter()
     for _ in range(n):
-        obs.observe("train_step_seconds", 0.01)
-        obs.inc("xla_compile_total")
+        record()
     per_call = (time.perf_counter() - t0) / (2 * n)
     assert per_call < 20e-6, \
         f"disabled telemetry costs {per_call * 1e6:.2f}us per call"
     assert obs.registry().get("train_step_seconds") is None
+    assert len(tracing.recorded()) == before
 
 
 # ---------------------------------------------------------------------------

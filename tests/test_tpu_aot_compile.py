@@ -13,6 +13,7 @@ started, and compiled in the test's own process (libtpu allows one process
 at a time); keep every such case in THIS file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,3 +145,71 @@ def test_grouped_matmul_compiles(one_chip, n, backward):
     _compile(fwd_bwd if backward else gmm, one_chip,
              ((m, k), jnp.bfloat16), ((groups, k, n), jnp.bfloat16),
              ((groups,), jnp.int32))
+
+
+# -- every kernel carries its name in the compiled program -------------------
+
+KERNEL_NAMES = {
+    "paged_attention": "paged",
+    "flash_attention_fwd": "flash",
+    "flash_attention_bwd_dq": "flash",
+    "flash_attention_bwd_dkv": "flash",
+    "grouped_matmul_fwd": "gmm",
+    "grouped_matmul_drhs": "gmm",
+}
+
+
+@pytest.fixture(scope="module")
+def named_programs(one_chip):
+    """The compiled text of one forward+backward flash program, one paged
+    decode program and one forward+backward grouped matmul, compiled when
+    the first case asks."""
+    texts = {}
+
+    def flash(q, k, v):
+        return jax.grad(
+            lambda *a: flash_mod.flash_attention(*a)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    def paged(q, kp, vp, table, start):
+        return paged_attention(q, kp, vp, table, start, interpret=False)
+
+    def gmm(lhs, rhs, sizes):
+        return jax.grad(
+            lambda a, b: grouped_matmul(a, b, sizes, interpret=False)
+            .astype(jnp.float32).sum(), argnums=(0, 1))(lhs, rhs)
+
+    qkv = (FLASH_SHAPES["ernie_b32_t1024_h12_d64"], jnp.bfloat16)
+    pool = ((1 + 8 * 128, 16, 16, 128), jnp.bfloat16)
+    programs = {
+        "flash": (flash, (qkv, qkv, qkv)),
+        "paged": (paged, (((8, 1, 16, 128), jnp.bfloat16), pool, pool,
+                          ((8, 128), jnp.int32), ((8,), jnp.int32))),
+        "gmm": (gmm, (((8192, 2048), jnp.bfloat16),
+                      ((64, 2048, 1024), jnp.bfloat16),
+                      ((64,), jnp.int32))),
+    }
+
+    def text_of(which):
+        if which not in texts:
+            fn, shapes = programs[which]
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                texts[which] = _compile(fn, one_chip, *shapes).as_text()
+        return texts[which]
+
+    return text_of
+
+
+@pytest.mark.parametrize("kernel", list(KERNEL_NAMES))
+def test_kernel_is_named_in_the_compiled_program(named_programs, kernel):
+    """XLA:TPU names a Mosaic custom call after ``pallas_call(name=...)``:
+    what a device trace's event carries, so that kernels are told apart by
+    name and not by the shape of their result. Under jax's transforms the
+    name comes wrapped (``%jvp_flash_attention_fwd_.1``,
+    ``%transpose_jvp_flash_attention_bwd_dq__.1``); alone it is
+    ``%paged_attention.1``."""
+    text = named_programs(KERNEL_NAMES[kernel])
+    assert re.search(rf"%\w*{kernel}_*(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), (
+        re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
