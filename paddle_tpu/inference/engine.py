@@ -733,6 +733,11 @@ class DecodeEngine:
         #: slots advanced, summed over the decode and verify passes: over
         #: ``decode_steps * num_slots`` it is how full the batch has been
         self.slot_steps = 0
+        #: page slots that the decode and verify passes' attention had to
+        #: read, ``(position + T - 1) // page_size + 1`` a slot (an idle
+        #: slot reads one): over ``kv_pages_capacity`` it is how far the
+        #: paged kernel's work is below what the tables hold
+        self.kv_pages_live = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
         self._t_decode_ema = None
@@ -883,7 +888,9 @@ class DecodeEngine:
                         # running totals since the engine was built
                         slot_steps=self.slot_steps,
                         slot_capacity=(self.decode_steps
-                                       * self.config.num_slots))
+                                       * self.config.num_slots),
+                        kv_pages_live=self.kv_pages_live,
+                        kv_pages_capacity=self.kv_pages_capacity)
         finally:
             self._report = None
             self.last_step = report
@@ -992,6 +999,7 @@ class DecodeEngine:
             self._steps_since_probe += 1
             self.decode_steps += 1
             self.slot_steps += len(active)
+            self._count_kv_pages(host[1], 1)
             self._last_logits = logits
             if self._acct is not None:
                 self._acct_wire_bytes(active, int(logits.shape[-1]), 1)
@@ -1003,6 +1011,19 @@ class DecodeEngine:
                 self._append_token(req, int(nxt_host[slot]))
             _obs.inc("serving_tokens_total", len(active))
             self._update_gauges()
+
+    def _count_kv_pages(self, positions, t: int):
+        """One pass's live page slots, from the positions that the pass
+        was called with (0 for a slot that sat it out)."""
+        live = np.minimum(
+            (positions + (t - 1)) // self.config.page_size + 1, self._mp)
+        self.kv_pages_live += int(live.sum())
+
+    @property
+    def kv_pages_capacity(self) -> int:
+        """Page slots that the tables of all decode and verify passes
+        held: passes x ``num_slots`` x the table's width."""
+        return self.decode_steps * self.config.num_slots * self._mp
 
     def _decode_inputs(self, epoch: int):
         """The decode program's host arrays for one epoch group:
@@ -1100,6 +1121,7 @@ class DecodeEngine:
             emitted = 0
             active_slots = len(self._running)
             self.slot_steps += active_slots
+            self._count_kv_pages(positions, k1)
             if self._acct is not None:
                 self._acct_wire_bytes(list(self._running.items()),
                                       int(logits.shape[-1]), k1)
@@ -1305,6 +1327,8 @@ class DecodeEngine:
             "decode_steps": self.decode_steps,
             "verify_steps": self.verify_steps,
             "slot_steps": self.slot_steps,
+            "kv_pages_live": self.kv_pages_live,
+            "kv_pages_capacity": self.kv_pages_capacity,
             "total_tokens": self.total_tokens,
             "prompt_tokens_total": self.prompt_tokens_total,
             "running": len(self._running),

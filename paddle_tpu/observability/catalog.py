@@ -531,7 +531,10 @@ SPANS = {
         "verify pass (attrs: num_slots, running and waiting after the "
         "admissions, context_tokens live in the running slots, emitted = "
         "{rid: new tokens}; slot_steps and slot_capacity, the engine's "
-        "running totals of slots advanced and of decode passes x num_slots)"),
+        "running totals of slots advanced and of decode passes x num_slots; "
+        "kv_pages_live and kv_pages_capacity, its running totals of the "
+        "page slots those passes' attention had to read and of the page "
+        "slots their tables held)"),
     "eng_admit": (
         "paddle_tpu/inference/engine.py",
         "One admission attempt, child of eng_step: page reservation, "

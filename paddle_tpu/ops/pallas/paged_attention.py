@@ -7,8 +7,8 @@ that by materializing the gathered K/V pages as f32 ``[S, Hkv, MP*P, D]``
 tensors plus a dense ``[S, Hkv, G, T, MP*P]`` logits tensor in HBM every
 step, and — under int8 KV — by a separate whole-pool dequant pass.
 
-This kernel fuses the whole per-(slot, kv-head) pipeline into one Pallas
-program:
+This kernel fuses the whole per-slot pipeline into one Pallas program
+whose grid follows the KV that is live, not the page table's capacity:
 
   * page-table-aware gather: the K/V pool blocks are addressed through a
     scalar-prefetched page table (``pltpu.PrefetchScalarGridSpec``), so
@@ -23,9 +23,19 @@ program:
   * fused int8 dequant: when per-[page, head] absmax scales are passed,
     ``int8 * scale`` happens on the VMEM-resident page right before the
     QK / PV dots — the f32 pool is never materialized;
-  * decode (T=1) and speculative verify (T=k+1) are the SAME kernel: all
-    k+1 draft positions score in one pass, each row masked at its own
-    causal horizon ``start_position + t``.
+  * decode (T=1), speculative verify (T=k+1) and the tail prefill (S=1,
+    T=bucket) are the SAME kernel: all T positions score in one pass,
+    each row masked at its own causal horizon ``start_position + t``;
+  * few, full grid steps: a page of the ``[N, Hkv, P, D]`` pool is
+    contiguous over its kv heads, so one step fetches a block of heads of
+    a page in one DMA and batches its two products over them. The block
+    is as many heads as the call's shapes leave room for in VMEM
+    (``_heads_per_step``): all of them in decode and verify, a few in a
+    prefill;
+  * no work past a slot's last live page ``(start_position + T - 1) //
+    P``: the index maps stand still there (a block whose index does not
+    change is not fetched again) and the body is skipped, so an idle slot
+    costs one page and not the table's width.
 
 The einsum op remains the bit-equality reference oracle: greedy argmax
 must agree everywhere (tests/test_pallas_attention.py), raw outputs agree
@@ -67,17 +77,71 @@ def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _paged_kernel(
-    *refs, scale, page_size, num_page_slots, groups, rows, fill, has_scales,
-):
-    """One grid step = one (slot, kv_head, page_slot) triple.
+#: What one call's grid step may take of the 16 MiB of VMEM that a kernel
+#: may hold on a v5e; the margin is for what ``_bytes_per_head`` leaves out.
+_VMEM_BUDGET = 14 * 2 ** 20
 
-    Grid is (S, Hkv, MP) with the page dimension innermost; m/l/acc
-    scratch carries the online softmax across page slots. Row r of the
-    folded query block is (draft position t = r // groups, query head
-    h_kv * groups + r % groups); kv positions on page slot j are
-    ``j * page_size + offset`` in the sequence's virtual key order —
-    exactly the gathered-layout positions the einsum oracle masks.
+
+def _bytes_per_head(rows8, d, p, kv_itemsize, has_scales):
+    """VMEM that one kv head adds to a grid step: the query and result
+    blocks (float32, double-buffered), the m / l / acc scratch, the body's
+    temporaries (logits, probabilities and their selects: about four
+    ``[rows8, 128]`` float32 arrays), and the double-buffered K and V pages
+    with their scale columns (a ``[P, 1]`` column pads out to 128 lanes).
+    Inside the engine's bucket-512 prefill program Mosaic counted 21.47 MiB
+    for 8 heads of 512 rows (PR 27, on the chip); this gives 22.1. The
+    kernel compiled ALONE needs less, because XLA then keeps the small
+    query and result arrays in VMEM and nothing double-buffers them: a
+    lone compile that passes proves nothing about the budget."""
+    per_head = 4 * rows8 * (4 * d + 2 * 128 + d + 4 * 128)
+    per_head += 2 * 2 * p * d * kv_itemsize
+    if has_scales:
+        per_head += 2 * 2 * p * 128 * 4
+    return per_head
+
+
+def _heads_per_step(hkv, rows8, d, p, kv_itemsize, has_scales):
+    """How many kv heads one grid step takes: the most that divides
+    ``hkv`` and keeps what grows with it under ``_VMEM_BUDGET``. Decode
+    and verify (8-16 rows) take every head; a prefill, whose row block is
+    the whole bucket, takes a few or one."""
+    hb = max(1, min(hkv, _VMEM_BUDGET // _bytes_per_head(
+        rows8, d, p, kv_itemsize, has_scales)))
+    while hkv % hb:
+        hb -= 1
+    return hb
+
+
+def _last_live_page(sp_ref, s_i, t, page_size, num_page_slots):
+    """The last page slot that any query row of slot ``s_i`` can see: row
+    ``t - 1`` sits at position ``start_position + t - 1``. Everything past
+    it is masked for every row, so the grid neither fetches nor multiplies
+    it. An idle slot (position 0) has one live page slot."""
+    return jnp.minimum(
+        jax.lax.div(sp_ref[s_i] + (t - 1), page_size), num_page_slots - 1)
+
+
+def _paged_kernel(
+    *refs, scale, page_size, num_page_slots, groups, rows, t, fill,
+    has_scales,
+):
+    """One grid step = one (slot, block of kv heads, page_slot) triple.
+
+    Grid is (S, Hkv // hb, MP) with the page dimension innermost; m/l/acc
+    scratch carries the online softmax across page slots, one row block a
+    kv head. A page of the pool is contiguous over its kv heads, so one
+    step fetches ``hb`` heads of a page in one DMA and the two products
+    are batched over the head axis. Row r of a head's folded query block
+    is (draft position t = r // groups, query head h_kv * groups +
+    r % groups); kv positions on page slot j are ``j * page_size +
+    offset`` in the sequence's virtual key order — exactly the
+    gathered-layout positions the einsum oracle masks.
+
+    Page slots past the slot's last live one (``_last_live_page``) do no
+    work: the index maps hold the last live page's block, which is
+    therefore not fetched again, and the body's products and softmax
+    update are skipped. Such a page would have contributed ``p = 0`` and
+    ``alpha = 1`` exactly, so leaving it out changes no bit of a row.
     """
     if has_scales:
         (pt_ref, sp_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
@@ -96,48 +160,54 @@ def _paged_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]  # [rows8, d] f32
-    k = k_ref[0, 0].astype(jnp.float32)  # [page_size, d]
-    v = v_ref[0, 0].astype(jnp.float32)
-    if has_scales:
-        # fused absmax dequant: int8 page * per-[page, head] scale, on the
-        # VMEM-resident block — the f32 pool never exists in HBM
-        k = k * ks_ref[0, 0]  # scale block [page_size, 1]
-        v = v * vs_ref[0, 0]
-    s_log = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [rows8, page_size]
+    @pl.when(j <= _last_live_page(sp_ref, s_idx, t, page_size,
+                                  num_page_slots))
+    def _page():
+        q = q_ref[0]  # [hb, rows8, d] f32
+        k = k_ref[0].astype(jnp.float32)  # [hb, page_size, d]
+        v = v_ref[0].astype(jnp.float32)
+        if has_scales:
+            # fused absmax dequant: int8 page * per-[page, head] scale, on
+            # the VMEM-resident block — the f32 pool never exists in HBM
+            k = k * ks_ref[0]  # scale block [hb, page_size, 1]
+            v = v * vs_ref[0]
+        s_log = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [hb, rows8, page_size]
 
-    row = jax.lax.broadcasted_iota(jnp.int32, s_log.shape, 0)
-    qpos = sp_ref[s_idx] + row // groups
-    kpos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s_log.shape, 1)
-    # causal at each row's own horizon; padding rows (row >= rows) are
-    # fully masked and sliced off by the wrapper. Trash/unallocated page
-    # slots mask themselves: their virtual positions exceed the horizon.
-    mask = jnp.logical_and(kpos <= qpos, row < rows)
-    s_log = jnp.where(mask, s_log, fill)
+        shape = s_log.shape[1:]
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        qpos = sp_ref[s_idx] + row // groups
+        kpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        # causal at each row's own horizon; padding rows (row >= rows) are
+        # fully masked and sliced off by the wrapper. Trash/unallocated
+        # page slots up to the last live one mask themselves: their
+        # virtual positions exceed the horizon.
+        mask = jnp.logical_and(kpos <= qpos, row < rows)
+        s_log = jnp.where(mask[None], s_log, fill)
 
-    m_prev = m_scr[:, :1]  # [rows8, 1]
-    l_prev = l_scr[:, :1]
-    m_cur = jnp.max(s_log, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    # dead rows (still all-masked) would get p = exp(fill - fill) = 1 per
-    # key; gate on the raw logit so they contribute l = 0 and emit zeros
-    p = jnp.where(s_log > fill * 0.5, jnp.exp(s_log - m_new), 0.0)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_prev = m_scr[:, :, :1]  # [hb, rows8, 1]
+        l_prev = l_scr[:, :, :1]
+        m_cur = jnp.max(s_log, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        # dead rows (still all-masked) would get p = exp(fill - fill) = 1
+        # per key; gate on the raw logit so they contribute l = 0 and emit
+        # zeros
+        p = jnp.where(s_log > fill * 0.5, jnp.exp(s_log - m_new), 0.0)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(j == num_page_slots - 1)
     def _emit():
-        safe = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / safe).astype(o_ref.dtype)
+        safe = jnp.maximum(l_scr[:, :, :1], 1e-30)
+        o_ref[0] = (acc_scr[:] / safe).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -156,7 +226,8 @@ def paged_attention(
 
     Args:
         q: ``[S, T, H, D]`` queries — T=1 for plain decode, T=k+1 for
-            speculative verify (all draft positions scored in one pass).
+            speculative verify (all draft positions scored in one pass),
+            T=bucket with S=1 for the prefix-cached tail prefill.
         k_pool, v_pool: ``[N, Hkv, P, D]`` page pools in their STORED
             dtype (f32, bf16, or int8 when scales are passed).
         page_table: ``[S, MP]`` int32 — page slot j of sequence s lives
@@ -171,6 +242,11 @@ def paged_attention(
 
     Returns:
         ``[S, T, H, D]`` f32 attention output.
+
+    The work follows the live KV, not the table's width: a slot's page
+    slots past ``(start_position + T - 1) // P`` are neither fetched nor
+    multiplied, and a grid step takes as many kv heads of a page as fit
+    (``_heads_per_step``, from the call's shapes alone).
     """
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
@@ -186,6 +262,8 @@ def paged_attention(
         interpret = jax.default_backend() != "tpu"
     sc = float(scale) if scale is not None else 1.0 / math.sqrt(d)
     fill = mask_fill_value(jnp.float32)
+    has_scales = k_scales is not None
+    hb = _heads_per_step(hkv, rows8, d, p, k_pool.dtype.itemsize, has_scales)
 
     # GQA-native folding: [S, T, H, D] -> [S, Hkv, T*G, D]; the G query
     # heads of a kv head travel as kernel rows, so kv pages are read once
@@ -200,37 +278,39 @@ def paged_attention(
 
     def pool_index(s_i, h_i, j, pt_ref, sp_ref):
         # the page-table gather: grid step (s, h, j) streams physical
-        # page pt[s, j] for kv head h straight from the pool
-        return (pt_ref[s_i, j], h_i, 0, 0)
+        # page pt[s, j] for head block h straight from the pool; past the
+        # last live page slot the index stands still, and a block whose
+        # index does not change is not fetched again
+        live = jnp.minimum(j, _last_live_page(sp_ref, s_i, t, p, mp))
+        return (pt_ref[s_i, live], h_i, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, rows8, d), q_index),
-        pl.BlockSpec((1, 1, p, d), pool_index),
-        pl.BlockSpec((1, 1, p, d), pool_index),
+        pl.BlockSpec((1, hb, rows8, d), q_index),
+        pl.BlockSpec((1, hb, p, d), pool_index),
+        pl.BlockSpec((1, hb, p, d), pool_index),
     ]
     args = [qg, k_pool, v_pool]
-    has_scales = k_scales is not None
     if has_scales:
         # trailing singleton dim: per-row stats blocks must keep their
         # last two dims equal to the array dims for Mosaic tiling
-        in_specs.append(pl.BlockSpec((1, 1, p, 1), pool_index))
-        in_specs.append(pl.BlockSpec((1, 1, p, 1), pool_index))
+        in_specs.append(pl.BlockSpec((1, hb, p, 1), pool_index))
+        in_specs.append(pl.BlockSpec((1, hb, p, 1), pool_index))
         args.append(k_scales.astype(jnp.float32).reshape(n, hkv, p, 1))
         args.append(v_scales.astype(jnp.float32).reshape(n, hkv, p, 1))
 
     kernel = functools.partial(
         _paged_kernel, scale=sc, page_size=p, num_page_slots=mp,
-        groups=groups, rows=rows, fill=fill, has_scales=has_scales,
+        groups=groups, rows=rows, t=t, fill=fill, has_scales=has_scales,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, hkv, mp),
+        grid=(s, hkv // hb, mp),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows8, d), q_index),
+        out_specs=pl.BlockSpec((1, hb, rows8, d), q_index),
         scratch_shapes=[
-            _scratch((rows8, 128)),
-            _scratch((rows8, 128)),
-            _scratch((rows8, d)),
+            _scratch((hb, rows8, 128)),
+            _scratch((hb, rows8, 128)),
+            _scratch((hb, rows8, d)),
         ],
     )
     out = pl.pallas_call(

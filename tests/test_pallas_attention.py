@@ -36,21 +36,26 @@ VOCAB = 61
 
 
 def _case(rng, *, t, hkv, group, page_size, max_pages=3, int8=False, d=16,
-          s=2):
+          s=2, ctx=None):
     """Random paged-cache case: q [S,T,H,D], pools [N,Hkv,P,D], page
     table with per-slot context lengths (tail pages left on the trash
     page 0), start positions placing the T query rows at the context
-    tail — the decode (T=1) and speculative verify (T=k+1) layouts."""
+    tail — the decode (T=1), speculative verify (T=k+1) and tail prefill
+    (S=1, T=bucket) layouts. ``ctx`` fixes the contexts (one a slot, the
+    T rows included); ``None`` in it is an idle slot: position 0, every
+    table entry the trash page."""
     h = hkv * group
+    if ctx is None:
+        ctx = rng.integers(t, max_pages * page_size + 1, size=s)
+    s = len(ctx)
     n = 1 + s * max_pages  # page 0 is the reserved trash page
     q = rng.standard_normal((s, t, h, d)).astype(np.float32)
-    ctx = rng.integers(t, max_pages * page_size + 1, size=s)
-    start = (ctx - t).astype(np.int32)
+    start = np.array([0 if c is None else c - t for c in ctx], np.int32)
     table = np.zeros((s, max_pages), np.int32)
     perm = rng.permutation(np.arange(1, n))
     nxt = 0
     for i in range(s):
-        used = -(-int(ctx[i]) // page_size)
+        used = 0 if ctx[i] is None else -(-int(ctx[i]) // page_size)
         table[i, :used] = perm[nxt:nxt + used]
         nxt += used
     if int8:
@@ -76,19 +81,109 @@ def _run(kernel, q, kp, vp, ks, vs, table, start):
     return np.asarray(raw(out))
 
 
+def _assert_matches_oracle(case):
+    got = _run("pallas", *case)
+    ref = _run("einsum", *case)
+    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=1e-4)
+    # greedy contract: the fused path must not flip an argmax
+    np.testing.assert_array_equal(got.argmax(-1), ref.argmax(-1))
+    return got
+
+
 @pytest.mark.parametrize("page_size", [8, 16])
 @pytest.mark.parametrize("group", [1, 4])
 @pytest.mark.parametrize("int8", [False, True])
 @pytest.mark.parametrize("t", [1, 3])
 def test_kernel_matches_einsum_oracle(page_size, group, int8, t):
     rng = np.random.default_rng(page_size * 100 + group * 10 + int8 * 5 + t)
-    case = _case(rng, t=t, hkv=2, group=group, page_size=page_size,
-                 int8=int8)
-    got = _run("pallas", *case)
-    ref = _run("einsum", *case)
-    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=1e-4)
-    # greedy contract: the fused path must not flip an argmax
-    np.testing.assert_array_equal(got.argmax(-1), ref.argmax(-1))
+    _assert_matches_oracle(_case(rng, t=t, hkv=2, group=group,
+                                 page_size=page_size, int8=int8))
+
+
+#: What a grid that follows the live KV can get wrong: where the last live
+#: page slot lies, a table far wider than it, a slot with nothing live, the
+#: prefill's one slot of many rows, and a head block smaller than the heads
+#: (``heads``: kv heads a grid step, forced through the VMEM budget).
+LIVE_KV_CASES = {
+    "wide_table_contexts_of_1_to_3_pages": dict(
+        t=1, ctx=[3, 20, 9], max_pages=16),
+    "wide_table_verify": dict(t=3, ctx=[9, 24], max_pages=16, group=2),
+    "idle_slot_beside_a_full_one": dict(t=1, ctx=[None, 64], max_pages=8),
+    "idle_slot_beside_a_full_one_verify": dict(
+        t=3, ctx=[64, None], max_pages=8),
+    "every_slot_idle": dict(t=1, ctx=[None, None], max_pages=4),
+    "context_ends_on_a_page_boundary": dict(t=1, ctx=[16, 8], max_pages=6),
+    "context_one_token_past_a_page_boundary": dict(
+        t=1, ctx=[17, 9], max_pages=6),
+    "verify_rows_straddle_a_page_boundary": dict(
+        t=3, ctx=[17, 18], max_pages=6),
+    "prefill_nothing_cached": dict(t=32, ctx=[32], max_pages=8),
+    "prefill_two_cached_pages": dict(t=32, ctx=[48], max_pages=8),
+    "prefill_short_of_its_bucket_gqa_int8": dict(
+        t=32, ctx=[40], max_pages=12, group=2, int8=True),
+    "four_heads_in_blocks_of_two": dict(
+        t=1, ctx=[21, 5], max_pages=4, hkv=4, heads=2),
+    "six_heads_budget_for_four_takes_three": dict(
+        t=3, ctx=[30, 12], max_pages=4, hkv=6, heads=4, expect_heads=3),
+    "four_heads_one_a_step": dict(
+        t=1, ctx=[11, 32], max_pages=4, hkv=4, heads=1),
+    "int8_scales_four_heads_a_step": dict(
+        t=1, ctx=[21, 5], max_pages=4, hkv=4, int8=True),
+    "int8_scales_in_blocks_of_two": dict(
+        t=3, ctx=[21, 5], max_pages=4, hkv=4, group=2, int8=True, heads=2),
+}
+
+
+@pytest.mark.parametrize("name", list(LIVE_KV_CASES))
+def test_kernel_matches_einsum_oracle_where_the_grid_follows_live_kv(
+        name, monkeypatch):
+    spec = dict(LIVE_KV_CASES[name])
+    heads = spec.pop("heads", None)
+    expect = spec.pop("expect_heads", heads)
+    spec = dict(dict(hkv=2, group=1, page_size=8, int8=False), **spec)
+    case = _case(np.random.default_rng(len(name)), **spec)
+    whole = _assert_matches_oracle(case)
+    if heads is None:
+        return
+    # a smaller head block is the same arithmetic a head: bit-equal
+    shapes = (pa_kernel._ceil8(spec["t"] * spec["group"]), 16, 8,
+              1 if spec["int8"] else 4, spec["int8"])
+    assert pa_kernel._heads_per_step(spec["hkv"], *shapes) == spec["hkv"]
+    monkeypatch.setattr(pa_kernel, "_VMEM_BUDGET",
+                        heads * pa_kernel._bytes_per_head(*shapes))
+    assert pa_kernel._heads_per_step(spec["hkv"], *shapes) == expect
+    np.testing.assert_array_equal(_assert_matches_oracle(case), whole)
+
+
+@pytest.mark.parametrize("shape,heads", [
+    # (hkv, rows8, d, p, kv_itemsize, has_scales) at the serving cell's
+    # widths: decode and verify take every head, a prefill what fits
+    ((16, 8, 128, 16, 2, False), 16),
+    ((16, 8, 128, 16, 1, True), 16),
+    ((8, 24, 128, 16, 2, False), 8),     # GQA 32/8, verify k=4
+    ((16, 128, 128, 16, 2, False), 16),  # bucket 128
+    ((16, 512, 128, 16, 2, False), 4),
+    ((16, 1024, 128, 16, 2, False), 2),
+    ((16, 1024, 128, 16, 1, True), 2),
+    ((8, 2048, 128, 16, 2, False), 1),   # GQA 32/8 at bucket 512
+    ((16, 8192, 128, 16, 2, False), 1),  # never none, whatever the rows
+])
+def test_heads_per_step_follows_the_calls_shapes(shape, heads):
+    got = pa_kernel._heads_per_step(*shape)
+    assert got == heads and shape[0] % got == 0
+    assert (got == 1 or got * pa_kernel._bytes_per_head(*shape[1:])
+            <= pa_kernel._VMEM_BUDGET)
+
+
+def test_bytes_per_head_covers_what_mosaic_counted_on_the_chip():
+    """Inside the engine's bucket-512 prefill program, 8 heads a step were
+    refused at run time: "Scoped allocation with size 21.47M and limit
+    16.00M" (PR 27, my chip run), though the kernel compiled alone passes
+    (XLA keeps the lone call's query and result in VMEM). The estimate has
+    to stay above that reading, and the budget under the limit."""
+    assert 8 * pa_kernel._bytes_per_head(512, 128, 16, 2, False) >= int(
+        21.47 * 2 ** 20)
+    assert pa_kernel._VMEM_BUDGET < 16 * 2 ** 20
 
 
 def test_kernel_under_jit_matches_eager():

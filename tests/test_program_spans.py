@@ -162,6 +162,13 @@ def test_each_engine_step_is_one_tree_on_the_profilers_timeline(
             == 2 * (len(roots) - 1))
     assert (first.attrs["slot_steps"], first.attrs["slot_capacity"]) == (
         engine.slot_steps - 2, 2 * (engine.decode_steps - 2))
+    # and of page slots: the running slot sits at positions 11, 12, 13 of
+    # pages of 8 (two live page slots), the idle one reads one, of 2 x 8
+    assert (roots[-1].attrs["kv_pages_live"] - first.attrs["kv_pages_live"]
+            == 3 * (len(roots) - 1))
+    assert (roots[-1].attrs["kv_pages_capacity"]
+            - first.attrs["kv_pages_capacity"] == 16 * (len(roots) - 1))
+    assert roots[-1].attrs["kv_pages_live"] == engine.kv_pages_live
     # the same spans lie in the .xplane.pb, on a host plane
     files = glob.glob(os.path.join(
         str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
@@ -338,6 +345,31 @@ def test_last_step_agrees_with_the_request_table_on_every_step(engine):
     assert [len(seen[r]) for r in rids] == [3, 6, 4]
     assert engine.last_step.tokens == {}  # the idle call did nothing
     assert not tracing.recorded()
+
+
+def test_kv_page_counts_follow_the_positions_of_each_pass(engine):
+    """``kv_pages_live`` is what the paged kernel has to read: a slot's
+    page slots up to its last query row's, one for a slot that sits the
+    pass out; ``kv_pages_capacity`` is what the tables hold."""
+    before = engine.stats()
+    passes0 = engine.decode_steps
+    engine.submit(_prompt(9, 4), max_new_tokens=6)  # positions 9 .. 13
+    engine.submit(_prompt(15, 5), max_new_tokens=3)  # positions 15, 16
+    while engine.step():
+        pass
+    after = engine.stats()
+    live = sum(p // 8 + 1 for p in (9, 10, 11, 12, 13, 15, 16))
+    idle = 3  # the second slot, once the shorter answer has left
+    assert after["kv_pages_live"] - before["kv_pages_live"] == live + idle
+    assert (after["kv_pages_capacity"] - before["kv_pages_capacity"]
+            == (engine.decode_steps - passes0) * 2 * 8)
+    assert engine.decode_steps - passes0 == 5
+    # a verify pass of k + 1 rows reaches k tokens further, never past the
+    # table's width
+    live0 = engine.kv_pages_live
+    engine._count_kv_pages(np.array([0, 13, 63]), 4)
+    assert engine.kv_pages_live - live0 == 1 + 3 + 8
+    engine.kv_pages_live = live0
 
 
 # -- names inside the compiled programs --------------------------------------------

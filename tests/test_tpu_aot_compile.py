@@ -110,23 +110,48 @@ def test_flash_attention_padding_mask_compiles(one_chip, flash_on_tpu):
 
 # -- paged attention (serving path: GPT-1.3B, 8 slots, 2048 tokens, page 16) -
 
-@pytest.mark.parametrize("t", [1, 5], ids=["decode", "verify_k4"])
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_attention_compiles(one_chip, kv, t):
-    slots, heads, d, page, max_pages = 8, 16, 128, 16, 128
-    n = 1 + slots * max_pages
-    pool = ((n, heads, page, d), jnp.int8 if kv == "int8" else jnp.bfloat16)
+def _paged_shapes(kv, slots, t, heads=16, kv_heads=16):
+    d, page, max_pages = 128, 16, 128
+    n = 1 + 8 * max_pages
+    pool = ((n, kv_heads, page, d),
+            jnp.int8 if kv == "int8" else jnp.bfloat16)
     shapes = [((slots, t, heads, d), jnp.bfloat16), pool, pool,
               ((slots, max_pages), jnp.int32), ((slots,), jnp.int32)]
     if kv == "int8":
-        scales = ((n, heads, page), jnp.float32)
+        scales = ((n, kv_heads, page), jnp.float32)
         shapes += [scales, scales]
+    return shapes
 
-    def fn(q, kp, vp, table, start, ks=None, vs=None):
-        return paged_attention(q, kp, vp, table, start, k_scales=ks,
-                               v_scales=vs, interpret=False)
 
-    _compile(fn, one_chip, *shapes)
+def _paged(q, kp, vp, table, start, ks=None, vs=None):
+    return paged_attention(q, kp, vp, table, start, k_scales=ks,
+                           v_scales=vs, interpret=False)
+
+
+#: (slots, T): the decode and verify passes over 8 slots, and the tail
+#: prefill's one slot at each bucket, where a grid step's head block is
+#: what the VMEM budget leaves (16, 4 and 2 heads of the 16). A lone
+#: kernel's compile is LENIENT on VMEM: XLA keeps its small query and
+#: result in VMEM, where the engine's program double-buffers them from HBM
+#: (tests/test_pallas_attention.py pins the estimate on the chip's count)
+PAGED_CALLS = {"decode": (8, 1), "verify_k4": (8, 5),
+               "prefill_128": (1, 128), "prefill_512": (1, 512),
+               "prefill_1024": (1, 1024)}
+
+
+@pytest.mark.parametrize("call", list(PAGED_CALLS))
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_attention_compiles(one_chip, kv, call):
+    _compile(_paged, one_chip, *_paged_shapes(kv, *PAGED_CALLS[call]))
+
+
+@pytest.mark.parametrize("call", ["decode", "prefill_512"])
+def test_paged_attention_gqa_compiles(one_chip, call):
+    """Llama's layout: 32 query heads on 8 kv heads, four a kernel row
+    group (at bucket 512 a head's row block is 2048 rows: one head a
+    step)."""
+    _compile(_paged, one_chip, *_paged_shapes(
+        "bf16", *PAGED_CALLS[call], heads=32, kv_heads=8))
 
 
 # -- grouped matmul (MoE expert FFN at OLMoE widths, ROADMAP R1) ------------
@@ -171,20 +196,15 @@ def named_programs(one_chip):
             lambda *a: flash_mod.flash_attention(*a)
             .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    def paged(q, kp, vp, table, start):
-        return paged_attention(q, kp, vp, table, start, interpret=False)
-
     def gmm(lhs, rhs, sizes):
         return jax.grad(
             lambda a, b: grouped_matmul(a, b, sizes, interpret=False)
             .astype(jnp.float32).sum(), argnums=(0, 1))(lhs, rhs)
 
     qkv = (FLASH_SHAPES["ernie_b32_t1024_h12_d64"], jnp.bfloat16)
-    pool = ((1 + 8 * 128, 16, 16, 128), jnp.bfloat16)
     programs = {
         "flash": (flash, (qkv, qkv, qkv)),
-        "paged": (paged, (((8, 1, 16, 128), jnp.bfloat16), pool, pool,
-                          ((8, 128), jnp.int32), ((8,), jnp.int32))),
+        "paged": (_paged, _paged_shapes("bf16", *PAGED_CALLS["decode"])),
         "gmm": (gmm, (((8192, 2048), jnp.bfloat16),
                       ((64, 2048, 1024), jnp.bfloat16),
                       ((64,), jnp.int32))),
@@ -213,3 +233,26 @@ def test_kernel_is_named_in_the_compiled_program(named_programs, kernel):
     assert re.search(rf"%\w*{kernel}_*(\.\d+)? = [^\n]*custom-call\([^\n]*"
                      r'custom_call_target="tpu_custom_call"', text), (
         re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+
+
+def test_the_decode_kernels_line_is_what_the_benchmark_looks_for(
+        named_programs):
+    """``paged_attn_roofline`` and ``paged_attn_time_pct`` find the decode
+    kernel's device events by their HLO line: ONE custom call whose single
+    result is float32 with the slot axis first. A tuple result, a bf16
+    result or another leading axis silences both. The pattern is read from
+    the benchmark's own file, ``$num_slots`` filled in as its reader does."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "paged_attn_roofline.json")) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    assert "$num_slots" in pattern
+    rx = re.compile(pattern.replace("$num_slots", "8"))
+    lines = [ln for ln in named_programs("paged").splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(lines) == 1, lines
+    assert rx.search(lines[0]), lines[0]
+    assert re.search(r"%paged_attention(\.\d+)? = f32\[8,", lines[0])
