@@ -451,15 +451,30 @@ def _token_page_write(cache, scales, layer, kv, tables, positions, int8,
                       page_size):
     """Write kv [S, T, Hkv, D] at absolute positions [S, T] through the
     page tables [S, MP] (decode T=1, verify T=k+1). Inactive slots carry
-    zeroed table rows, so their writes land on the trash page."""
+    zeroed table rows, so their writes land on the trash page.
+
+    One in-place ``dynamic_update_slice`` of ``[1, 1, Hkv, 1, D]`` a
+    (slot, token), unrolled. NOT one scatter: its ``[Hkv, D]`` update
+    window makes XLA:TPU keep the pool with heads beside the lane axis
+    (``{4,2,3,1,0}``), which is neither the layout the pool arrives in nor
+    the one the paged kernel reads, so a pass then copies the whole pool
+    in and out and re-lays a layer of it before each kernel call (30 of
+    a 37 ms pass: PERF.md, PR 30). And NOT a ``fori_loop``, whose carry
+    takes that layout too. tests/test_tpu_aot_compile.py holds the
+    engine's compiled programs to it."""
     pg = jnp.take_along_axis(tables, positions // page_size, axis=1)
     off = positions % page_size
     if int8:
-        q, scale = quantize_absmax(kv, axis=-1)  # scale [S, T, Hkv, 1]
-        cache = cache.at[layer, pg, :, off, :].set(q.astype(cache.dtype))
-        scales = scales.at[layer, pg, :, off].set(scale[..., 0])
-        return cache, scales
-    cache = cache.at[layer, pg, :, off, :].set(kv.astype(cache.dtype))
+        kv, scale = quantize_absmax(kv, axis=-1)  # scale [S, T, Hkv, 1]
+    rows = kv.astype(cache.dtype)[:, :, None, None, :, None, :]
+    for s_i in range(pg.shape[0]):
+        for t_i in range(pg.shape[1]):
+            at = (layer, pg[s_i, t_i], 0, off[s_i, t_i])
+            cache = jax.lax.dynamic_update_slice(
+                cache, rows[s_i, t_i], at + (0,))
+            if int8:
+                scales = jax.lax.dynamic_update_slice(
+                    scales, scale[s_i, t_i, None, None], at)
     return cache, scales
 
 
@@ -2086,16 +2101,19 @@ class DecodeEngine:
 
     def _attend(self, q, kc, vc, ksc, vsc, l, tables, positions):
         """One layer of paged attention on the resolved kernel. The fused
-        Pallas path hands the kernel the STORED pool slices — plus the
-        absmax scale slabs when int8, so dequant happens against the
-        VMEM-resident page inside the kernel; the einsum oracle
+        Pallas path hands the kernel the whole STORED pool and the layer's
+        index, which its index maps read, so the pool is never sliced —
+        plus the layer's absmax scale slabs when int8 (1 MB, sliced: the
+        kernel wants them with a trailing 1, which the stacked slab could
+        not take without padding every lane), so dequant happens against
+        the VMEM-resident page inside the kernel; the einsum oracle
         dequantizes the layer's pool up front (``_layer_kv``). The
         kernel is pinned explicitly so an ambient PADDLE_TPU_ATTN_KERNEL
         cannot diverge a program from the engine's resolved (and
         AOT-cache-keyed) choice."""
         if self._attn_kernel == "pallas":
             return F.paged_attention(
-                q, kc[l], vc[l], tables, positions,
+                q, kc, vc, tables, positions, layer=l,
                 k_scales=None if ksc is None else ksc[l],
                 v_scales=None if vsc is None else vsc[l],
                 kernel="pallas")

@@ -273,7 +273,7 @@ def resolve_attn_kernel(kernel=None) -> str:
 
 @defop(amp="white", name="paged_attention_pallas_op")
 def _paged_attention_pallas_op(q, pk, pv, k_scales, v_scales, page_table,
-                               start_position, scale):
+                               start_position, scale, layer=None):
     """Fused-kernel twin of :func:`_paged_attention_op`: the pool streams
     HBM→VMEM at its stored dtype (int8 dequant fused against the absmax
     scales inside the kernel) and the softmax runs online — no gathered
@@ -283,7 +283,7 @@ def _paged_attention_pallas_op(q, pk, pv, k_scales, v_scales, page_table,
     from ...ops.pallas import paged_attention as _pa
 
     out = _pa.paged_attention(
-        q, pk, pv, page_table, start_position, scale=scale,
+        q, pk, pv, page_table, start_position, layer=layer, scale=scale,
         k_scales=k_scales, v_scales=v_scales)
     return out.astype(q.dtype)
 
@@ -341,7 +341,7 @@ def _paged_attention_op(q, pk, pv, k_scales, v_scales, page_table,
 
 def paged_attention(query, pool_k, pool_v, page_table, start_position,
                     scale=None, k_scales=None, v_scales=None, kernel=None,
-                    name=None):
+                    layer=None, name=None):
     """Multi-token KV-cached attention against a paged cache (the
     page-granular companion of :func:`decode_attention`; see
     docs/SERVING.md §paged cache). ``query`` [S, T, H, D]; ``pool_k/v``
@@ -350,6 +350,12 @@ def paged_attention(query, pool_k, pool_v, page_table, start_position,
     query row). Serves the decode step (T=1), the speculative verify
     step (T=k+1), and the prefix-cached tail prefill (S=1, T=bucket)
     with ONE op.
+
+    With ``layer`` (an int or int32 scalar) ``pool_k/v`` are the engine's
+    stacked [L, N, Hkv, page_size, D] pools: the fused kernel reads that
+    layer in place through its index maps, so a serving program never
+    slices its pool (docs/SERVING.md §paged cache); the einsum oracle
+    slices it. The scales stay ONE layer's.
 
     ``k_scales``/``v_scales`` ([N, Hkv, page_size] f32, both or neither)
     mark the pools as int8 absmax-quantized. ``kernel`` picks the
@@ -361,11 +367,13 @@ def paged_attention(query, pool_k, pool_v, page_table, start_position,
         raise ValueError("k_scales and v_scales must be passed together")
     choice = resolve_attn_kernel(kernel)
     if choice == "pallas":
-        _, mp_deg = _mp_degree_for(pool_k.shape[1])
+        _, mp_deg = _mp_degree_for(pool_k.shape[-3])
         if mp_deg == 1:
             return _paged_attention_pallas_op(
                 query, pool_k, pool_v, k_scales, v_scales, page_table,
-                start_position, scale)
+                start_position, scale, layer)
+    if layer is not None:
+        pool_k, pool_v = pool_k[layer], pool_v[layer]
     return _paged_attention_op(query, pool_k, pool_v, k_scales, v_scales,
                                page_table, start_position, scale)
 

@@ -32,6 +32,10 @@ whose grid follows the KV that is live, not the page table's capacity:
     is as many heads as the call's shapes leave room for in VMEM
     (``_heads_per_step``): all of them in decode and verify, a few in a
     prefill;
+  * the engine's stacked ``[L, N, Hkv, P, D]`` pool is read in place: the
+    layer index travels as a scalar-prefetch operand into the index maps,
+    so no caller slices a layer out (a slice is a copy of 1/L of the pool
+    before every call);
   * no work past a slot's last live page ``(start_position + T - 1) //
     P``: the index maps stand still there (a block whose index does not
     change is not fetched again) and the body is skipped, so an idle slot
@@ -144,13 +148,13 @@ def _paged_kernel(
     ``alpha = 1`` exactly, so leaving it out changes no bit of a row.
     """
     if has_scales:
-        (pt_ref, sp_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
+        (pt_ref, sp_ref, ly_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
     else:
-        (pt_ref, sp_ref, q_ref, k_ref, v_ref, o_ref,
+        (pt_ref, sp_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
         ks_ref = vs_ref = None
-    del pt_ref  # consumed by the BlockSpec index maps, not the body
+    del pt_ref, ly_ref  # consumed by the BlockSpec index maps, not the body
     s_idx = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -217,6 +221,7 @@ def paged_attention(
     page_table,
     start_position,
     *,
+    layer=None,
     scale=None,
     k_scales=None,
     v_scales=None,
@@ -229,14 +234,22 @@ def paged_attention(
             speculative verify (all draft positions scored in one pass),
             T=bucket with S=1 for the prefix-cached tail prefill.
         k_pool, v_pool: ``[N, Hkv, P, D]`` page pools in their STORED
-            dtype (f32, bf16, or int8 when scales are passed).
+            dtype (f32, bf16, or int8 when scales are passed), or the
+            engine's stacked ``[L, N, Hkv, P, D]`` pools with ``layer``.
         page_table: ``[S, MP]`` int32 — page slot j of sequence s lives
             in physical page ``page_table[s, j]`` (0 = trash page).
         start_position: ``[S]`` int32 — tokens already cached per slot;
             draft position t attends keys ``<= start_position + t``.
+        layer: which layer of a stacked pool to attend over (an int or
+            an int32 scalar, traced or not); required with 5-D pools and
+            refused with 4-D ones. It reaches the index maps as a third
+            scalar-prefetch operand and the pool's block squeezes the
+            layer axis, so the pool is never sliced.
         scale: logit scale; defaults to ``1/sqrt(D)``.
         k_scales, v_scales: optional ``[N, Hkv, P]`` f32 absmax scales —
             passing them turns on fused int8 dequant (both or neither).
+            ONE layer's slab also beside a stacked pool: stacked, its
+            trailing-1 reshape below would pad every lane to 128.
         interpret: force pallas interpret mode; default: interpret
             everywhere except on a real TPU backend.
 
@@ -250,8 +263,14 @@ def paged_attention(
     """
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
+    if (k_pool.ndim == 5) != (layer is not None):
+        raise ValueError(
+            "a stacked [L, N, Hkv, P, D] pool takes a layer index, and "
+            f"only it does: pool rank {k_pool.ndim}, layer {layer!r}")
+    if layer is None:  # one layer's pool is a stack of one: a bitcast
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     s, t, h, d = q.shape
-    n, hkv, p, _ = k_pool.shape
+    _, n, hkv, p, _ = k_pool.shape
     mp = page_table.shape[1]
     if h % hkv:
         raise ValueError(f"num heads {h} not divisible by kv heads {hkv}")
@@ -273,10 +292,10 @@ def paged_attention(
     if rows8 != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows8 - rows), (0, 0)))
 
-    def q_index(s_i, h_i, j, pt_ref, sp_ref):
+    def q_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref):
         return (s_i, h_i, 0, 0)
 
-    def pool_index(s_i, h_i, j, pt_ref, sp_ref):
+    def page_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref):
         # the page-table gather: grid step (s, h, j) streams physical
         # page pt[s, j] for head block h straight from the pool; past the
         # last live page slot the index stands still, and a block whose
@@ -284,17 +303,21 @@ def paged_attention(
         live = jnp.minimum(j, _last_live_page(sp_ref, s_i, t, p, mp))
         return (pt_ref[s_i, live], h_i, 0, 0)
 
+    def pool_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref):
+        return (ly_ref[0],) + page_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref)
+
     in_specs = [
         pl.BlockSpec((1, hb, rows8, d), q_index),
-        pl.BlockSpec((1, hb, p, d), pool_index),
-        pl.BlockSpec((1, hb, p, d), pool_index),
+        # the layer axis is squeezed: the body sees [1, hb, P, D] as ever
+        pl.BlockSpec((None, 1, hb, p, d), pool_index),
+        pl.BlockSpec((None, 1, hb, p, d), pool_index),
     ]
     args = [qg, k_pool, v_pool]
     if has_scales:
         # trailing singleton dim: per-row stats blocks must keep their
         # last two dims equal to the array dims for Mosaic tiling
-        in_specs.append(pl.BlockSpec((1, hb, p, 1), pool_index))
-        in_specs.append(pl.BlockSpec((1, hb, p, 1), pool_index))
+        in_specs.append(pl.BlockSpec((1, hb, p, 1), page_index))
+        in_specs.append(pl.BlockSpec((1, hb, p, 1), page_index))
         args.append(k_scales.astype(jnp.float32).reshape(n, hkv, p, 1))
         args.append(v_scales.astype(jnp.float32).reshape(n, hkv, p, 1))
 
@@ -303,7 +326,7 @@ def paged_attention(
         groups=groups, rows=rows, t=t, fill=fill, has_scales=has_scales,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(s, hkv // hb, mp),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hb, rows8, d), q_index),
@@ -319,7 +342,8 @@ def paged_attention(
         grid_spec=grid_spec,
         interpret=interpret,
         name="paged_attention",
-    )(page_table.astype(jnp.int32), start_position.astype(jnp.int32), *args)
+    )(page_table.astype(jnp.int32), start_position.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *args)
     out = out[:, :, :rows]
     return out.reshape(s, hkv, t, groups, d).transpose(
         0, 2, 1, 3, 4).reshape(s, t, h, d)

@@ -7,7 +7,11 @@ duration of its ``paged_attention`` event on the device's ``XLA Ops`` line,
 never the host's clock. With ``--parent DIR`` (an unpacked ``git archive`` of
 another commit) that tree's kernel runs the same inputs, the two outputs are
 compared bit for bit on the rows of live slots, and each is compared with
-the einsum oracle of THIS tree.
+the einsum oracle of THIS tree. Beside the 4-D call on one layer's pool
+(``change``) this tree's kernel also runs as the engine calls it since PR
+30 (``stacked``): the same pool as layer 1 of a stacked ``[3, N, Hkv, P, D]``
+array, the layer index a traced argument; its rows are compared bit for bit
+with the 4-D call's.
 
     python3 scripts/paged_attention_trace.py [--parent _tree/parent] \
         [--out chiprun_out/paged_attention_trace.json]
@@ -30,6 +34,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 H, D, PAGE, MAX_PAGES, SLOTS = 16, 128, 16, 128, 8
+#: the stacked call's pool: the case's pool at LAYER, other pages elsewhere
+LAYERS, LAYER = 3, 1
 
 #: name -> (slots, T, tokens cached in each slot before the T rows, pool)
 CASES = {
@@ -82,6 +88,15 @@ def make_case(rng, s, t, cached, pool):
             jnp.asarray(cached, jnp.int32)) + scales
 
 
+def stacked(pool):
+    """The case's pool as layer LAYER of a stacked one; the other layers
+    hold the same pages rolled, so a wrong layer index changes the rows."""
+    import jax.numpy as jnp
+
+    return jnp.stack([pool if i == LAYER else jnp.roll(pool, i + 1, axis=0)
+                      for i in range(LAYERS)])
+
+
 def kernel_event_ms(trace_dir):
     """Durations, in ms, of the kernel's events on chip 0, by the
     benchmark's own reduction of a trace."""
@@ -118,7 +133,8 @@ def main():
     change = load_kernel(ROOT, "change")
     if args.budget_mib:
         change._VMEM_BUDGET = int(args.budget_mib * 2 ** 20)
-    kernels = {"change": change.paged_attention}
+    kernels = {"change": change.paged_attention,
+               "stacked": change.paged_attention}
     if args.parent:
         kernels["parent"] = load_kernel(
             os.path.abspath(args.parent), "parent").paged_attention
@@ -136,12 +152,19 @@ def main():
                "pool": pool}
         outs = {}
         for tag, kernel in kernels.items():
-            def call(q, kp, vp, table, start, *sc, _k=kernel):
-                kw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
+            def call(q, kp, vp, table, start, *rest, _k=kernel, _tag=tag):
+                kw = {}
+                if _tag == "stacked":
+                    kw["layer"], rest = rest[0], rest[1:]
+                if rest:
+                    kw.update(k_scales=rest[0], v_scales=rest[1])
                 return _k(q, kp, vp, table, start, **kw)
 
-            fn = jax.jit(call)
-            outs[tag] = np.asarray(fn(*case))  # compiles, warms
+            fn, inputs = jax.jit(call), case
+            if tag == "stacked":
+                inputs = (case[0], stacked(case[1]), stacked(case[2]),
+                          *case[3:5], np.int32(LAYER), *case[5:])
+            outs[tag] = np.asarray(fn(*inputs))  # compiles, warms
             row[tag + "_oracle_maxdiff"] = float(
                 np.abs(outs[tag][live] - oracle[live]).max())
             if args.rehearse_on_cpu:
@@ -150,7 +173,7 @@ def main():
                     dir=os.path.dirname(args.out)) as d:
                 with jax.profiler.trace(d):
                     for _ in range(args.calls):
-                        fn(*case).block_until_ready()
+                        fn(*inputs).block_until_ready()
                 ms = kernel_event_ms(d)
             if len(ms) != args.calls:
                 sys.exit(f"{name}/{tag}: {len(ms)} kernel events in the "
@@ -158,6 +181,8 @@ def main():
             row[tag + "_ms_p50"] = statistics.median(ms)
             row[tag + "_ms_min"] = min(ms)
             row[tag + "_ms_max"] = max(ms)
+        row["stacked_bit_equal"] = bool(np.array_equal(
+            outs["stacked"], outs["change"]))
         if "parent" in outs:
             if not args.rehearse_on_cpu:
                 row["speedup_p50"] = (row["parent_ms_p50"]

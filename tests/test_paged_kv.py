@@ -8,13 +8,16 @@ shared blocks, greedy output is BIT-EQUAL with prefix caching and
 speculative decode on or off, and the compiled-program count stays O(1)
 in requests/lengths (prefill buckets + one decode + one verify).
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu.inference as inference
 from paddle_tpu.inference.engine import (DecodeEngine, EngineConfig,
                                          PagePool, PrefixRegistry,
-                                         SamplingParams)
+                                         SamplingParams, _token_page_write)
+from paddle_tpu.distributed.grad_comm import quantize_absmax
 from paddle_tpu.text.generation import prompt_lookup_draft
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
@@ -309,6 +312,87 @@ def test_quick_churn_no_leaked_pages(model):
     assert eng.pool.available() == eng.pool.num_pages - 1
     # freed slots must leave zeroed page-table rows (writes -> trash)
     assert (eng._tables == 0).all()
+
+
+def _scatter_token_write(cache, scales, layer, kv, tables, positions, int8,
+                         page_size):
+    """The token write as it was until PR 30: one scatter whose update
+    window is ``[Hkv, D]``. Kept here as the oracle of the in-place
+    writes that replaced it."""
+    pg = jnp.take_along_axis(tables, positions // page_size, axis=1)
+    off = positions % page_size
+    if int8:
+        q, scale = quantize_absmax(kv, axis=-1)
+        return (cache.at[layer, pg, :, off, :].set(q.astype(cache.dtype)),
+                scales.at[layer, pg, :, off].set(scale[..., 0]))
+    return cache.at[layer, pg, :, off, :].set(kv.astype(cache.dtype)), scales
+
+
+#: (T, position of each slot's first row; None = an idle slot, whose zeroed
+#: table row sends its write to the trash page)
+TOKEN_WRITES = {
+    "decode": (1, [5, 16, 40]),
+    "decode_idle_slots_write_the_trash_page": (1, [None, 23, None, None]),
+    "decode_every_slot_idle": (1, [None, None]),
+    "decode_first_and_last_offset_of_a_page": (1, [8, 15, 0]),
+    "verify_k4": (5, [3, 17]),
+    "verify_k4_rows_cross_a_page_boundary": (5, [6, 13, None]),
+    "verify_k4_ends_on_the_tables_last_page": (5, [43, None]),
+}
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("name", list(TOKEN_WRITES))
+def test_token_write_in_place_equals_the_scatter_it_replaced(
+        name, kv_dtype, jit):
+    t, first = TOKEN_WRITES[name]
+    rng = np.random.default_rng(len(name) + t)
+    layers, layer, hkv, p, d, mp = 3, 1, 2, 8, 16, 6
+    s, int8 = len(first), kv_dtype == "int8"
+    n = 1 + s * mp
+    tables = np.zeros((s, mp), np.int32)
+    free = iter(rng.permutation(np.arange(1, n)))
+    for i, pos in enumerate(first):
+        if pos is not None:
+            for j in range((pos + t - 1) // p + 1):
+                tables[i, j] = next(free)
+    positions = jnp.asarray(
+        [[0 if pos is None else pos + j for j in range(t)]
+         for pos in first], jnp.int32)
+    shape = (layers, n, hkv, p, d)
+    if int8:
+        cache = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        scales = jnp.asarray(rng.uniform(0.01, 0.03, shape[:-1]),
+                             jnp.float32)
+    else:
+        cache = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        scales = None
+    kv = jnp.asarray(rng.standard_normal((s, t, hkv, d)), jnp.float32)
+    args = (cache, scales, layer, kv, jnp.asarray(tables), positions, int8, p)
+    new, old = _token_page_write, _scatter_token_write
+    if jit:  # both: a fused quantize rounds its scale in another order
+        new, old = (jax.jit(f, static_argnums=(2, 6, 7)) for f in (new, old))
+    got, want = new(*args), old(*args)
+    # several idle slots write the trash page's first row, in the scatter
+    # in no stated order: every other page has to agree, bit for bit
+    for g, w, before in zip(got, want, (cache, scales)):
+        if w is None:
+            assert g is None
+            continue
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        np.testing.assert_array_equal(g[:, 1:], w[:, 1:])
+        live = [pos is not None for pos in first]
+        if any(live):  # something was written, and only in this layer
+            assert not np.array_equal(g[layer], np.asarray(
+                before, np.float32)[layer])
+        np.testing.assert_array_equal(
+            np.delete(g, layer, 0),
+            np.delete(np.asarray(before, np.float32), layer, 0))
+        # the trash page took nothing but row 0 (an idle slot's position)
+        np.testing.assert_array_equal(
+            g[layer, 0, :, 1:], np.asarray(before, np.float32)[
+                layer, 0, :, 1:])
 
 
 def test_transformer_paged_cache_matches_static():

@@ -155,6 +155,96 @@ def test_kernel_matches_einsum_oracle_where_the_grid_follows_live_kv(
     np.testing.assert_array_equal(_assert_matches_oracle(case), whole)
 
 
+#: The engine's call since PR 30: the stacked ``[L, N, Hkv, P, D]`` pool and
+#: a layer index that the index maps read. The same blocks reach the same
+#: body, so it equals the 4-D call on ``pool[l]`` bit for bit.
+STACKED_CASES = {
+    "decode": dict(t=1, ctx=[3, 20, None, 9], max_pages=16),
+    "decode_first_layer": dict(t=1, ctx=[7, 12], max_pages=4, layer=0),
+    "decode_layer_traced_under_jit": dict(
+        t=1, ctx=[3, 20, None, 9], max_pages=16, traced=True),
+    "verify_k4_rows_cross_a_page": dict(
+        t=5, ctx=[17, 40, None], max_pages=8),
+    "prefill_nothing_cached": dict(t=32, ctx=[32], max_pages=8),
+    "prefill_128_cached": dict(t=32, ctx=[160], max_pages=24),
+    "int8_decode": dict(t=1, ctx=[21, None, 5], max_pages=4, int8=True),
+    "int8_verify_k4_traced": dict(
+        t=5, ctx=[21, 13], max_pages=4, int8=True, traced=True),
+    "gqa_decode": dict(t=1, ctx=[11, 30], max_pages=4, group=4),
+    "gqa_prefill_128_cached_int8": dict(
+        t=32, ctx=[160], max_pages=24, group=2, int8=True),
+    "four_heads_in_blocks_of_two": dict(
+        t=1, ctx=[21, 5], max_pages=4, hkv=4, heads=2),
+}
+
+
+def _stacked(rng, pool, layers, layer):
+    """``pool`` as layer ``layer`` of a stack whose other layers hold
+    other values, so a wrong layer index cannot pass."""
+    other = rng.permutation(pool.reshape(-1)).reshape(pool.shape)
+    return jnp.stack([jnp.asarray(pool if i == layer else np.roll(
+        other, i, axis=0)) for i in range(layers)])
+
+
+@pytest.mark.parametrize("name", list(STACKED_CASES))
+def test_stacked_pool_call_equals_the_4d_call_on_its_layer(
+        name, monkeypatch):
+    spec = dict(STACKED_CASES[name])
+    layer, traced = spec.pop("layer", 2), spec.pop("traced", False)
+    heads = spec.pop("heads", None)
+    spec = dict(dict(hkv=2, group=1, page_size=8, int8=False), **spec)
+    rng = np.random.default_rng(len(name))
+    q, kp, vp, ks, vs, table, start = _case(rng, **spec)
+    if heads is not None:
+        monkeypatch.setattr(
+            pa_kernel, "_VMEM_BUDGET", heads * pa_kernel._bytes_per_head(
+                pa_kernel._ceil8(spec["t"] * spec["group"]), 16, 8, 4, False))
+    kw = {} if ks is None else dict(k_scales=jnp.asarray(ks),
+                                    v_scales=jnp.asarray(vs))
+    rest = (jnp.asarray(table), jnp.asarray(start))
+    flat = np.asarray(pa_kernel.paged_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), *rest, **kw))
+    kst, vst = _stacked(rng, kp, 3, layer), _stacked(rng, vp, 3, layer)
+
+    def call(l):
+        return pa_kernel.paged_attention(
+            jnp.asarray(q), kst, vst, *rest, layer=l, **kw)
+
+    got = jax.jit(call)(jnp.int32(layer)) if traced else call(layer)
+    np.testing.assert_array_equal(np.asarray(got), flat)
+    assert not np.array_equal(np.asarray(call((layer + 1) % 3)), flat)
+
+
+@pytest.mark.parametrize("kernel", ["pallas", "einsum"])
+def test_functional_call_takes_the_stacked_pool_on_both_kernels(kernel):
+    """``F.paged_attention(..., layer=l)``: the fused kernel reads the
+    layer in place, the oracle slices it; each equals its own 4-D call."""
+    rng = np.random.default_rng(11)
+    q, kp, vp, ks, vs, table, start = _case(
+        rng, t=3, hkv=2, group=2, page_size=8, int8=True)
+    flat = _run(kernel, q, kp, vp, ks, vs, table, start)
+    got = F.paged_attention(
+        jnp.asarray(q), _stacked(rng, kp, 2, 1), _stacked(rng, vp, 2, 1),
+        jnp.asarray(table), jnp.asarray(start), k_scales=jnp.asarray(ks),
+        v_scales=jnp.asarray(vs), kernel=kernel, layer=1)
+    np.testing.assert_array_equal(np.asarray(raw(got)), flat)
+
+
+@pytest.mark.parametrize("stack,layer", [(True, None), (False, 0)],
+                         ids=["stacked_without_layer", "flat_with_layer"])
+def test_a_layer_index_goes_with_a_stacked_pool_and_only_with_it(
+        stack, layer):
+    rng = np.random.default_rng(0)
+    q, kp, vp, _, _, table, start = _case(
+        rng, t=1, hkv=2, group=1, page_size=8)
+    if stack:
+        kp, vp = kp[None], vp[None]
+    with pytest.raises(ValueError, match="layer"):
+        pa_kernel.paged_attention(
+            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(table), jnp.asarray(start), layer=layer)
+
+
 @pytest.mark.parametrize("shape,heads", [
     # (hkv, rows8, d, p, kv_itemsize, has_scales) at the serving cell's
     # widths: decode and verify take every head, a prefill what fits

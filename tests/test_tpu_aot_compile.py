@@ -6,7 +6,10 @@ TPU compiler is installed in the CPU sandbox and compiles for a chip that
 is described, not attached, so these cases guard the flash, paged and
 grouped-matmul kernels at the shapes `chip_smoke.py` and the roadmap's
 cells run them at — at no chip time. Nothing executes: a compile that
-passes says nothing about results or speed.
+passes says nothing about results or speed. The compiled text does say
+what XLA:TPU made of a program, so the serving engine's own decode, verify
+and prefill programs are compiled here too and held to their contract: the
+KV pool keeps one layout and nothing copies, slices or re-lays it.
 
 The topology is described inside a fixture, after a test of this file has
 started, and compiled in the test's own process (libtpu allows one process
@@ -17,6 +20,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -256,3 +260,153 @@ def test_the_decode_kernels_line_is_what_the_benchmark_looks_for(
     assert len(lines) == 1, lines
     assert rx.search(lines[0]), lines[0]
     assert re.search(r"%paged_attention(\.\d+)? = f32\[8,", lines[0])
+
+
+# -- the engine's programs leave the KV pool where and as it is (PR 30) ------
+#
+# Until PR 30 a decode pass of the serving cell copied the whole pool four
+# times and sliced and re-laid one layer of it before each of its 48 kernel
+# calls: 79% of the device's busy time (PERF.md). The cause was visible in
+# the compiled text alone, and so is its absence.
+
+#: the serving cell's engine (GPT-3 1.3B: 16 heads of 128, 8 slots x 2048,
+#: page 16). The layout choice does not depend on depth, so the bf16 pool
+#: is two layers deep, and the int8 pool four: a K or V pool of less than
+#: these 134 MB XLA takes into VMEM whole (a pool-sized ``slice-start``
+#: before the first kernel call), which no pool of a served depth allows
+ENGINE_PROGRAMS = ("decode", "verify_k4", "prefill_b512")
+ENGINE_LAYERS = {"bf16": 2, "int8": 4}
+
+#: what may have a result as large as one layer's pool: the program's own
+#: arguments and results, and a write in place
+_IN_PLACE = {"dynamic-update-slice", "scatter"}
+_NO_TRAFFIC = {"parameter", "tuple", "get-tuple-element", "bitcast"}
+
+
+@pytest.fixture(scope="module")
+def engine_programs(one_chip):
+    """``text_of(kv, program)``: the compiled text of one of the engine's
+    own programs, built by its own ``_build_*`` from shapes (a described
+    chip holds no array), and the shape of its K pool. The engine asks
+    ``jax.default_backend()`` for its kernel, its donation and the kernel's
+    interpret mode: steered here."""
+    from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
+    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
+
+    made = {}
+
+    def engine(kv):
+        if kv not in made:
+            model = GPTForCausalLM(GPTConfig(
+                vocab_size=1024, hidden_size=2048,
+                num_hidden_layers=ENGINE_LAYERS[kv], num_attention_heads=16,
+                intermediate_size=2048, max_position_embeddings=2048,
+                hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
+            )).astype("bfloat16")
+            model.eval()
+            made[kv] = DecodeEngine(model, EngineConfig(
+                num_slots=8, max_length=2048, page_size=16, kv_dtype=kv,
+                prompt_buckets=(128, 512, 1024), speculate_k=4))
+        return made[kv]
+
+    def shaped(a):
+        return None if a is None else jax.ShapeDtypeStruct(
+            np.shape(a), a.dtype, sharding=one_chip)
+
+    def text_of(kv, program):
+        if (kv, program) in made:
+            return made[kv, program], made[kv]._kc.shape
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            eng = engine(kv)
+            assert eng.stats()["attn_kernel"] == "pallas" and eng._donate
+            s, key = eng.config.num_slots, eng._zero_key
+            row = np.zeros(eng._mp, np.int32)
+            per_slot = [np.zeros(s, np.int32), eng._tables,
+                        np.zeros((s,) + key.shape, key.dtype),
+                        np.ones(s, np.float32), np.zeros(s, np.int32),
+                        np.ones(s, np.float32), np.ones(s, bool)]
+            if program == "decode":
+                fn, args = eng._build_decode(), [np.zeros(s, np.int32)]
+            elif program == "verify_k4":
+                fn, args = eng._build_verify(5), [np.zeros((s, 5), np.int32)]
+            else:
+                fn, args, per_slot = eng._build_prefill(512), [], [
+                    np.zeros((1, 512), np.int32), np.int32(0), np.int32(512),
+                    row, key, np.float32(1), np.int32(0), np.float32(1),
+                    np.asarray(True)]
+            pools = [eng._kc, eng._vc, eng._ksc, eng._vsc]
+            made[kv, program] = fn.lower(*jax.tree.map(
+                shaped, (eng._state_vals(), *pools, *args, *per_slot),
+                is_leaf=lambda a: a is None)).compile().as_text()
+        return made[kv, program], eng._kc.shape
+
+    return text_of
+
+
+_COMPUTATION = re.compile(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(
+    r"\s+(ROOT )?%[\w.\-]+ = (.+?) ([a-z][\w\-]*)\(")
+
+
+def _elements(type_text):
+    return max([int(np.prod([int(n) for n in dims.split(",") if n]))
+                for dims in re.findall(r"\w+\[([\d,]*)\]", type_text)] or [0])
+
+
+def _pool_sized_traffic(text, big):
+    """Instructions that the device runs (those of a fused computation
+    are its fusion's) whose result has ``big`` elements or more and is
+    neither an argument, a tuple, a bitcast nor a write in place."""
+    roots, large, body = {}, [], None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            body = head.group(1)
+        ins = _INSTRUCTION.match(line)
+        if not ins:
+            continue
+        root, result, op = ins.groups()
+        if root:
+            roots[body] = op
+        if (not body.startswith("fused_computation")
+                and _elements(result) >= big):
+            large.append((op, line.strip()))
+    found = []
+    for op, line in large:
+        if op == "fusion":  # a fusion is what its root is
+            op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+        if op not in _NO_TRAFFIC | _IN_PLACE:
+            found.append(line[:160])
+    return found
+
+
+@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_engine_program_keeps_the_kv_pool_in_one_layout(
+        engine_programs, kv, program):
+    """(a) every array of the pool's shape in the compiled text, the
+    program's two arguments and two results among them, has one and the
+    same layout: the row-major one it arrives in. A second layout means a
+    copy of the whole pool, 1.6 GB at the cell's 24 layers."""
+    text, pool_shape = engine_programs(kv, program)
+    dims = ",".join(str(n) for n in pool_shape)
+    orders = set(re.findall(rf"\w+\[{dims}\]\{{([\d,]*)", text))
+    assert orders == {"4,3,2,1,0"}, orders
+    assert text.count(f"[{dims}]") >= 4  # two arguments, two results
+
+
+@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_engine_program_moves_nothing_as_large_as_a_layers_pool(
+        engine_programs, kv, program):
+    """(b) no copy, slice, transpose or fusion whose result is as large
+    as ONE layer's pool, other than a write in place: the kernel reads its
+    layer through its index maps, the token write is an in-place
+    ``dynamic-update-slice``, the prefill's page write a scatter."""
+    text, (_, n, hkv, p, d) = engine_programs(kv, program)
+    assert re.search(r"%paged_attention[.\d]* = f32\[", text)
+    assert _pool_sized_traffic(text, n * hkv * p * d) == []
+    wrote = "scatter" if program.startswith("prefill") else (
+        "dynamic-update-slice")
+    assert re.search(rf" {wrote}\(", text)
