@@ -183,8 +183,38 @@ def end_to_end(tracks, lead_in_s, seconds):
     if ttft:
         out["serve_ttft_p95_ms"] = percentile(ttft, 95) * 1e3
         out["serve_ttft_p50_ms"] = percentile(ttft, 50) * 1e3
+    # where the judged tail sits among the gaps: on the edge between two
+    # kinds of step (a plain step, an admit of one bucket or another) it
+    # swings from seed to seed, inside one kind it does not
+    around = {f"itl_p{q}_ms": percentile(gaps, q) * 1e3
+              for q in (50, 90, 93, 97, 99) if gaps}
     return out, failed, {"window_tokens": n_tokens, "itl_gaps": len(gaps),
-                         "ttft_samples": len(ttft)}
+                         "ttft_samples": len(ttft), **around}
+
+
+def window_steps(steps, lead_in_s, seconds, buckets):
+    """Where the window's seconds went, by kind of step: plain decode steps,
+    and admit steps by the largest of the configuration's prompt buckets
+    that their admits' uncached tails need. A run that completes
+    fewer tokens than its neighbours shows here whether its steps were
+    slower, or heavier, or fewer."""
+    lo, hi = lead_in_s, lead_in_s + seconds
+    inside = [s for s in steps if lo <= s.attrs["t"] < hi]
+    out = {"window_steps": len(inside),
+           "window_step_s": sum(s.seconds for s in inside),
+           "window_step_max_ms": max((s.seconds for s in inside),
+                                     default=0.0) * 1e3,
+           "window_steps_over_150_ms": sum(s.seconds > 0.15 for s in inside),
+           "window_admits": sum(len(s.attrs["admitted"]) for s in inside)}
+    for s in inside:
+        kind = "decode"
+        if s.attrs["admitted"]:
+            tail = max(p - c for p, c in s.attrs["admitted"])
+            kind = "admit_b%s" % next(
+                (b for b in sorted(buckets) if b >= tail), "over")
+        out[f"window_{kind}_steps"] = out.get(f"window_{kind}_steps", 0) + 1
+        out[f"window_{kind}_s"] = out.get(f"window_{kind}_s", 0.0) + s.seconds
+    return out
 
 
 def make_tracks(mix, seed, lead_in_s, seconds, vocab):
@@ -248,6 +278,39 @@ def _nucleus_edge(lq, temperature, top_p):
     return jnp.argmin(jnp.where(p >= thr, p, jnp.inf), axis=-1)
 
 
+def _readings_at(lg, lq, next_ids, first, count, key, *, greedy, control,
+                 temp, top_p):
+    """One request's readings over the positions ``[first, first + count)``
+    of its padded sequence, at the padded shape whatever the request: one
+    compiled program a kind of reading, found in the compile cache by every
+    later run (sliced to each request's own length, every new length
+    compiled a dozen small programs, 40-80 s of a run's check: PERF.md
+    section 6, PR 32). ``lg``: the reference's logits ``[T, V]``, ``lq`` the
+    control's (``lg`` again where there is none), ``next_ids [T]`` the token
+    that followed each position."""
+    import jax
+    import jax.numpy as jnp
+
+    at = jnp.arange(lg.shape[0])
+    at = (at >= first) & (at < first + count)
+    if greedy:
+        chosen = jnp.argmax(lq, axis=-1) if control == "quant" else next_ids
+        gap = (lg.max(-1)
+               - jnp.take_along_axis(lg, chosen[:, None], -1)[:, 0])
+        gap = jnp.where(at, gap, 0.0)
+        return gap.max(), gap.sum()
+    if control == "no_top_p":
+        chosen = jax.random.categorical(key, lg / temp, axis=-1)
+    elif control == "quant":
+        chosen = _nucleus_edge(lq, temp, top_p)
+    else:
+        chosen = next_ids
+    p = jax.nn.softmax(lg / temp, axis=-1)
+    p_tok = jnp.take_along_axis(p, chosen[:, None], -1)
+    above = jnp.sum(jnp.where(p > p_tok, p, 0.0), axis=-1)
+    return jnp.max(jnp.where(at, above, 0.0)), jnp.float32(0.0)
+
+
 def reference_readings(cell, seed, greedy, sampled=(), quant=None,
                        no_top_p=False, ref_weights=None, pad_to=None):
     """The reference's logits over prompt + served tokens of each request of
@@ -277,38 +340,30 @@ def reference_readings(cell, seed, greedy, sampled=(), quant=None,
                                     seed, cfg["dtype"])
     pad = pad_to or -(-max(len(t.req.prompt) + len(t.tokens)
                            for t in list(greedy) + list(sampled)) // 128) * 128
+    control = "quant" if quant else "no_top_p" if no_top_p else None
+    read = jax.jit(_readings_at, static_argnames=(
+        "greedy", "control", "temp", "top_p"))
     gap_max = gap_sum = excess = 0.0
     n_greedy = n_sampled = 0
     key = jax.random.key(int(seed) & 0x7FFFFFFF, impl="threefry2x32")
     for t in list(greedy) + list(sampled):
         plen, served = len(t.req.prompt), np.asarray(t.tokens, np.int32)
-        ids = np.zeros(pad, np.int32)
+        ids = np.zeros(pad + 1, np.int32)
         ids[:plen] = t.req.prompt
         ids[plen:plen + len(served)] = served
-        at = slice(plen - 1, plen - 1 + len(served))
-        lg = ref.logits(w, jnp.asarray(ids), **kw)[at]
-        lq = (ref.logits(w, jnp.asarray(ids), quant=quant, **kw)[at]
-              if quant else None)
+        lg = ref.logits(w, jnp.asarray(ids[:pad]), **kw)
+        lq = (ref.logits(w, jnp.asarray(ids[:pad]), quant=quant, **kw)
+              if quant else lg)
+        key, sub = jax.random.split(key)
+        a, b = read(lg, lq, jnp.asarray(ids[1:]), plen - 1, len(served), sub,
+                    greedy=t.req.greedy, control=control, temp=temp,
+                    top_p=top_p)
         if t.req.greedy:
-            chosen = (jnp.argmax(lq, axis=-1) if quant
-                      else jnp.asarray(served))
-            gap = (lg.max(-1)
-                   - jnp.take_along_axis(lg, chosen[:, None], -1)[:, 0])
-            gap_max = max(gap_max, float(gap.max()))
-            gap_sum += float(gap.sum())
+            gap_max = max(gap_max, float(a))
+            gap_sum += float(b)
             n_greedy += len(served)
         else:
-            if no_top_p:
-                key, sub = jax.random.split(key)
-                chosen = jax.random.categorical(sub, lg / temp, axis=-1)
-            elif quant:
-                chosen = _nucleus_edge(lq, temp, top_p)
-            else:
-                chosen = jnp.asarray(served)
-            p = jax.nn.softmax(lg / temp, axis=-1)
-            p_tok = jnp.take_along_axis(p, chosen[:, None], -1)
-            above = jnp.sum(jnp.where(p > p_tok, p, 0.0), axis=-1)
-            excess = max(excess, float(above.max()) - top_p)
+            excess = max(excess, float(a) - top_p)
             n_sampled += len(served)
     return {"logit_gap_max": gap_max if n_greedy else None,
             "logit_gap_mean": gap_sum / n_greedy if n_greedy else None,
@@ -363,6 +418,8 @@ def run(cell, seed, seconds, spans, tracer, t_process):
         "gen_lateness_max_ms": max(
             [(t.submitted - t.due) * 1e3 for t in tracks
              if t.submitted is not None], default=0.0)}
+    counters.update(window_steps(
+        steps, lead, seconds, cfg["engine"].get("prompt_buckets", ())))
     if counters["compiles_in_window"]:
         raise SystemExit("serve.py: a program compiled inside the window")
     peak = memory_peak_bytes(cell.chips)

@@ -72,7 +72,7 @@ def test_span_medians_sum_a_steps_parts_and_keep_to_the_traced_window():
     _decode_step(100.0, 0.002, 0.001, 0.003, 0.190, 0.001)
     _decode_step(100.3, 0.004, 0.002, 0.003, 0.180, 0.001)
     _decode_step(100.6, 0.003, 0.003, 0.003, 0.200, 0.001)
-    ctx = _ctx("serve_short_1p3b")
+    ctx = _ctx("serve_short_1p3b_knee80")
     assert _read(ctx, "eng_decode_readback_p50_ms") == pytest.approx(190.0)
     assert _read(ctx, "eng_decode_upload_p50_ms") == pytest.approx(2.0)
     # prep + upload + dispatch + append of each step: 7, 10, 10 ms
@@ -98,7 +98,7 @@ def test_idle_under_a_span_is_by_overlap_and_leaves_the_read_backs_out():
     # step 2 only: overlap gives each step its part
     s2 = _span("eng_step", 100.95, 102.0)
     _span("eng_prefill_readback", 101.95, 102.0, parent=s2)
-    ctx = _ctx("serve_short_1p3b", device)
+    ctx = _ctx("serve_short_1p3b_knee80", device)
     idle_host = (0.04 + 0.02) + (0.05 + 0.05)
     assert _read(ctx, "idle_in_engine_host_pct") == pytest.approx(
         100 * idle_host / 5.0)
@@ -117,12 +117,12 @@ def test_occupancy_and_the_counter_ratio():
     _span("eng_step", 100.4, 100.6, slot_steps=410, slot_capacity=1616)
     _span("eng_step", 100.6, 100.8, num_slots=8)  # a span without them
     _span("eng_step", 104.9, 105.1, slot_steps=1, slot_capacity=2)  # after
-    ctx = _ctx("serve_short_1p3b", counters={
+    ctx = _ctx("serve_short_1p3b_knee80", counters={
         "prefix_hit_tokens": 1280, "prompt_tokens_total": 10240})
     assert _read(ctx, "slot_occupancy_pct") == pytest.approx(
         100 * 410 / 1616)
     assert _read(ctx, "prefix_hit_pct") == pytest.approx(12.5)
-    ctx = _ctx("serve_short_1p3b", counters={
+    ctx = _ctx("serve_short_1p3b_knee80", counters={
         "prefix_hit_tokens": 0, "prompt_tokens_total": 10240})
     assert _read(ctx, "prefix_hit_pct") == 0.0  # a count that reads 0
 
@@ -131,7 +131,7 @@ def test_occupancy_and_the_counter_ratio():
 def test_a_reader_that_finds_nothing_returns_none_never_0(metric,
                                                           monkeypatch):
     cell = ("train_ernie3_base_seq1024_o1" if metric in NEW_TRAIN
-            else "serve_short_1p3b")
+            else "serve_short_1p3b_knee80")
     device = [(0.1, 0.8)]
     # no span of the name in the traced window (one before it), no counter
     _span("eng_step", 90.0, 90.2, slot_steps=2, slot_capacity=8)

@@ -60,7 +60,7 @@ def test_the_paged_kernels_share_leaves_the_prefill_kernel_out():
                           {"decode_ctx": 2100, "decoded": 4,
                            "admitted": [(512, 128)]}),
              harness.Span("engine.step", 99.9, 100.1, {"decode_ctx": 9e9})]
-    ctx = _ctx("serve_short_1p3b",
+    ctx = _ctx("serve_short_1p3b_knee80",
                [(0.5, 0.15, PAGED), (0.7, 0.15, PAGED), (0.85, 0.05, PREFILL),
                 (0.9, 0.02, OTHER)], steps)
     assert "f32\\[8," in ctx.pattern("= f32\\[$num_slots,")
@@ -97,7 +97,7 @@ def test_the_flash_kernels_share_holds_whole_steps_on_both_sides():
 
 
 def test_a_reader_that_finds_nothing_returns_nothing():
-    ctx = _ctx("serve_short_1p3b", [(0.9, 0.02, OTHER)], [])
+    ctx = _ctx("serve_short_1p3b_knee80", [(0.9, 0.02, OTHER)], [])
     for metric in ("paged_attn_roofline", "paged_attn_time_pct",
                    "serve_step_mfu_pct"):
         assert _read(ctx, metric) is None

@@ -32,7 +32,7 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files(tmp_path):
     # the later PR's files: a directory of its own, or new files beside
     os.makedirs(os.path.join(root, "bench_more", "traffic"))
     os.makedirs(os.path.join(root, "bench_more", "metrics"))
-    with open(os.path.join(BENCH, "traffic", "short.json")) as f:
+    with open(os.path.join(BENCH, "traffic", "short_knee80.json")) as f:
         mix = json.load(f)
     mix["prompt_len"]["median"] = 900
     # a mix lists the metrics its cells report beyond those whose entries
@@ -92,6 +92,84 @@ def test_every_cell_of_the_benchmark_resolves():
             ROOT, reg["paths"], "limits", cell.name + ".json"))
     for m in reg["per_layer"]:  # no reader file without an entry's name
         assert m["moves"] in {e["name"] for e in reg["end_to_end"]}
+
+
+def test_every_entry_that_names_a_cell_is_reported_by_it():
+    """A per-layer entry's ``workloads`` lists the cells that report it, and
+    each of those cells reports the end-to-end metric that the entry moves
+    (one name a metric: a quantity that moves ``serve_itl_p95_ms`` in one
+    cell and ``serve_tokens_per_s`` in another is two entries, ``x`` and
+    ``x.tput``). Every cell finds its mix, its limits and its
+    configuration."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        reg = json.load(f)
+    files = {c["name"]: c["file"] for c in reg["configs"]}
+    for w in reg["workloads"]:
+        cell = harness.resolve(w["name"], ROOT)
+        reported = {m["name"] for m in cell.per_layer}
+        judged = {m["name"] for m in cell.end_to_end}
+        named = [m for m in reg["per_layer"]
+                 if w["name"] in m.get("workloads", ())]
+        assert named
+        for m in named:
+            assert m["name"] in reported, (w["name"], m["name"])
+            assert m["moves"] in judged, (w["name"], m["name"])
+        assert cell.mix and harness.load_limits(cell)
+        assert os.path.exists(os.path.join(ROOT, files[w["config"]]))
+        assert cell.config["arch"]
+    # no entry names a cell that is not there
+    cells = {w["name"] for w in reg["workloads"]}
+    for m in reg["end_to_end"] + reg["per_layer"]:
+        assert set(m.get("workloads", ())) <= cells, m["name"]
+
+
+def test_the_tail_cell_and_the_saturated_cell_are_judged_apart():
+    """Below the knee the tail is judged and tokens/s is the offered load;
+    above it tokens/s completed is judged and the tail swings with the
+    smallest change, so it is per-layer there (``itl_p95_ms.tput``)."""
+    def judged(name):
+        return {m["name"] for m in harness.resolve(name, ROOT).end_to_end}
+
+    assert judged("serve_short_1p3b_knee80") == {"serve_itl_p95_ms",
+                                                 "setup_s"}
+    assert judged("serve_short_1p3b_saturated") == {"serve_tokens_per_s",
+                                                    "setup_s"}
+    tail = harness.resolve("serve_short_1p3b_knee80", ROOT)
+    sat = harness.resolve("serve_short_1p3b_saturated", ROOT)
+    assert tail.config == sat.config
+    differ = {k for k in tail.mix if tail.mix[k] != sat.mix[k]}
+    assert differ == {"rate_per_s", "doc"}
+    assert sat.mix["rate_per_s"] > tail.mix["rate_per_s"]
+    at_tail = {m["name"] for m in tail.per_layer}
+    at_sat = {m["name"] for m in sat.per_layer}
+    # the same layers in both, but the judged number itself, and the TTFT's
+    # tail: per-layer metrics come from traced runs, and above the knee the
+    # first tokens owed at the close wait for the profiler to stop
+    shared = at_tail - {"window_tokens_per_s", "ttft_p95_ms"}
+    assert {n + ".tput" for n in shared} == at_sat - {"itl_p95_ms.tput"}
+    for name in shared:  # the same reader
+        assert (harness.load_reader(tail, name)[1]
+                == harness.load_reader(sat, name + ".tput")[1])
+        assert (harness.load_reader(tail, name)[0]
+                is harness.load_reader(sat, name + ".tput")[0])
+
+
+def test_a_cell_judged_on_tokens_per_s_prints_it_with_trace_0():
+    """The rehearsal's cell that is judged on ``serve_tokens_per_s``:
+    ``serve.end_to_end`` computes it in every run, and the line carries it
+    only where the cell's ``end_to_end`` names it. A CPU run: the number is
+    never written down."""
+    import run
+    from conftest import REHEARSAL
+
+    cell = harness.resolve("rehearse_serve_saturated", registry=REHEARSAL)
+    out = run.run_cell(cell, 2147483659, 3.0, False)
+    assert out["correct"] is True and out["failed"] == 0
+    assert set(out["metrics"]) == {"serve_tokens_per_s", "setup_s"}
+    assert out["metrics"]["serve_tokens_per_s"]["value"] > 0
+    assert out["metrics"]["serve_tokens_per_s"]["unit"] == "tokens/s"
+    assert {"itl_p95_ms.tput", "decode_step_p50_ms.tput"} <= {
+        m["name"] for m in cell.per_layer}
 
 
 # -- a new architecture, as files only ---------------------------------------

@@ -2,6 +2,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 import harness
 import traffic
@@ -9,30 +10,30 @@ import work
 from conftest import BENCH, ROOT
 
 
-def _mix():
-    with open(os.path.join(BENCH, "traffic", "short.json")) as f:
+@pytest.fixture(params=["short_knee80", "short_saturated"])
+def mix(request):
+    with open(os.path.join(BENCH, "traffic", request.param + ".json")) as f:
         return json.load(f)
 
 
-def _phase(seed, seconds=51.0):
-    mix = _mix()
+def _phase(mix, seed, seconds=51.0):
     sysp = traffic.system_prompts(mix, seed, 50304)
     return traffic.serve_window(mix, seed, seconds, 50304, sysp), sysp
 
 
-def test_same_seed_same_schedule():
-    a, _ = _phase(2**31 + 5)
-    b, _ = _phase(2**31 + 5)
-    assert len(a) == len(b) == round(_mix()["rate_per_s"] * 51)
+def test_same_seed_same_schedule(mix):
+    a, _ = _phase(mix, 2**31 + 5)
+    b, _ = _phase(mix, 2**31 + 5)
+    assert len(a) == len(b) == round(mix["rate_per_s"] * 51)
     for x, y in zip(a, b):
         assert x.due == y.due and x.max_new_tokens == y.max_new_tokens
         assert x.greedy == y.greedy and x.seed == y.seed
         assert np.array_equal(x.prompt, y.prompt)
 
 
-def test_every_seed_gets_the_same_sizes_in_another_order():
-    a, _ = _phase(1)
-    b, _ = _phase(2)
+def test_every_seed_gets_the_same_sizes_in_another_order(mix):
+    a, _ = _phase(mix, 1)
+    b, _ = _phase(mix, 2)
     size = lambda r: (len(r.prompt), r.max_new_tokens, r.greedy,  # noqa: E731
                       r.shared >= 0)
     assert sorted(map(size, a)) == sorted(map(size, b))
@@ -46,9 +47,8 @@ def test_every_seed_gets_the_same_sizes_in_another_order():
             assert np.min(np.abs(quantiles - g)) < 1e-9
 
 
-def test_mix_is_what_the_file_says():
-    reqs, sysp = _phase(3)
-    mix = _mix()
+def test_mix_is_what_the_file_says(mix):
+    reqs, sysp = _phase(mix, 3)
     assert all(0 <= r.due < 51 for r in reqs)
     assert [r.due for r in reqs] == sorted(r.due for r in reqs)
     n = len(reqs)

@@ -5,7 +5,7 @@ reading is the largest), and what the control gives on a few (the upper is
 the smallest). One process and one set-up for all seeds, since set-up is
 long: the seed's weights are loaded into the one program between runs.
 
-    python3 benchmark/tools/limits.py --workload serve_short_1p3b \
+    python3 benchmark/tools/limits.py --workload serve_short_1p3b_knee80 \
         --seeds 11,12,...  --control 3 --seconds 51
 
 Serving: per seed a window at the cell's own load, the samples of finished

@@ -4,8 +4,13 @@ set-up, a few rates. A rate is sustained when the backlog (requests submitted
 and not finished) at the window's end is no larger than at its start. Run
 once when a cell is defined; the cell then fixes its rate in its mix file.
 
-    python3 benchmark/tools/sweep.py --workload serve_short_1p3b --seed 1 \
-        --rates 0.4,0.7,1.0,1.3,1.6 --seconds 25 [--describe-trace]
+    python3 benchmark/tools/sweep.py --workload serve_short_1p3b_knee80 \
+        --seed 1 --rates 10.5,11,11.5,12,12.5,10.5,11,11.5,12,12.5
+
+Rate i runs on seed + i, so a rate named twice is read on two seeds. The
+defaults are the serving cells' own window and lead-in: windows of 25 s with
+a lead-in of 8 s found the plateau and could not place the knee (PERF.md
+section 4, PR 32).
 """
 import argparse
 import os
@@ -33,9 +38,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=1)
-    ap.add_argument("--rates", default="0.4,0.7,1.0,1.3,1.6")
-    ap.add_argument("--seconds", type=float, default=25.0)
-    ap.add_argument("--lead-in", type=float, default=8.0)
+    ap.add_argument("--rates", default="10.5,11,11.5,12,12.5")
+    ap.add_argument("--seconds", type=float, default=51.0)
+    ap.add_argument("--lead-in", type=float, default=27.0)
     ap.add_argument("--describe-trace", action="store_true")
     args = ap.parse_args()
     import jax
