@@ -539,7 +539,7 @@ def multichip_phase(report, sizes: MultiSizes, seed: int):
         _check_same_tokens(
             report, f"multi mp2 engine request {i} == one-device engine",
             got[i], want[i], prompt, model)
-    _check_spread(report, "multi KV pool", [eng._kc, eng._vc],
+    _check_spread(report, "multi KV pool", jax.tree.leaves(eng.kv),
                   list(mesh.devices.flat))
 
 

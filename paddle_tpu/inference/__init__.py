@@ -229,8 +229,10 @@ def create_predictor(config: Config) -> Predictor:
 
 def enable_decode_engine(model, config: Optional[Config] = None, **kw):
     """Attach a KV-cached continuous-batching decode engine to a live
-    causal LM (a model exposing ``decode_adapter()``: GPTForCausalLM,
-    LlamaForCausalLM). After this, ``text.generation.generate`` /
+    causal LM (a model whose ``decode_adapter()`` gives the engine its
+    ``embed``, its own block as ``layer``, ``head`` and the KV pool's
+    geometry: GPTForCausalLM, LlamaForCausalLM; docs/SERVING.md "Serving a
+    new model"). After this, ``text.generation.generate`` /
     ``generate_padded`` route through the engine automatically; the
     engine is also returned for direct ``submit()``/``step()``/``run()``
     driving. Settings come from ``config.enable_decode_engine(...)`` when

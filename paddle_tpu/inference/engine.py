@@ -47,12 +47,16 @@ static-shape serving discipline on XLA):
   steps, one int32 per slot per step host transfer (``k+1`` for verify),
   absmax-scaled int8 via grad_comm's quantize/dequantize helpers.
 
-Models plug in through ``model.decode_adapter()`` (text/models/gpt.py,
-llama.py). See docs/SERVING.md for the page-table invariants and the
-accept/reject rule.
+A model plugs in through ``model.decode_adapter()`` (text/models/gpt.py,
+llama.py): ``embed``, its own block as ``layer`` (calling the engine's
+paged ``attend(q, k, v)`` where full attention stood), ``head``, and the
+pool's geometry. The pool's format is ``kv_pool.KVPool``'s alone. See
+docs/SERVING.md for "Serving a new model", the page-table invariants and
+the accept/reject rule.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import time
 from collections import OrderedDict, deque
@@ -64,15 +68,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import observability as _obs
-from ..distributed.grad_comm import dequantize_absmax, quantize_absmax
+from ..distributed import mesh as _mesh
 from ..runtime import compile_cache as _compile_cache
 from ..framework.core import Tensor, no_grad
 from ..framework.op import raw
 from ..nn import functional as F
-
-#: the parts of each compiled program carry a scope of these names: embed,
-#: qkv, kv_write, attend, attn_out, mlp, lm_head, sample
-_scope = jax.named_scope
+from ..profiler import scope as _scope
+from .kv_pool import KV_DTYPES, TRASH_PAGE, KVPool, active_mp_mesh
 
 __all__ = [
     "DecodeEngine",
@@ -83,17 +85,18 @@ __all__ = [
     "pow2_bucket",
 ]
 
-KV_DTYPES = ("f32", "bf16", "int8")
-
 #: PRNG of the request sample streams, pinned so that sampled tokens do not
 #: depend on the process-wide default: on a TPU that default is ``rbg``
 #: (framework/rng.py), whose bits change under ``vmap`` and so with the
 #: slot a request happens to decode in
 _KEY_IMPL = "threefry2x32"
 
-#: the reserved all-garbage page every unallocated page-table entry (and
-#: every masked scatter) points at; never handed out by the allocator
-TRASH_PAGE = 0
+
+def _program_kind(name: str) -> Tuple[str, int]:
+    """A program's family and size: ``("prefill", 512)`` of "prefill_b512",
+    ``("verify", 4)`` of "verify_k4", ``("decode", 0)``."""
+    kind, _, size = name.partition("_")
+    return kind, int(size[1:] or 0)
 
 
 def pow2_bucket(n: int, lo: int = 16, hi: Optional[int] = None) -> int:
@@ -416,95 +419,8 @@ class PrefixRegistry:
 
 
 # ---------------------------------------------------------------------------
-# cache plumbing (pure jnp; traced inside the engine's compiled programs)
+# sharding hints and sampling (pure jnp; traced inside the compiled programs)
 # ---------------------------------------------------------------------------
-
-
-def _block_page_write(cache, scales, layer, kv, row, cached_len, true_len,
-                      int8, page_size):
-    """Write a prompt tail kv [1, TB, Hkv, D] (positions cached_len ...
-    cached_len + TB - 1) into the pages ``row[cached_len//P + j]``.
-    Pages holding padding only (entirely >= true_len) are redirected to
-    the trash page so a padded tail bucket can never scribble past the
-    request's allocation."""
-    x = kv[0]  # [TB, Hkv, D]
-    tb, hkv, d = x.shape
-    p = page_size
-    nb = -(-tb // p)
-    if nb * p != tb:
-        x = jnp.pad(x, ((0, nb * p - tb), (0, 0), (0, 0)))
-    blk = jnp.swapaxes(x.reshape(nb, p, hkv, d), 1, 2)  # [nb, Hkv, P, D]
-    mp = row.shape[0]
-    g = cached_len // p + jnp.arange(nb)
-    need = (true_len + p - 1) // p  # pages with any real prompt content
-    idx = jnp.where(g < need, row[jnp.minimum(g, mp - 1)], TRASH_PAGE)
-    if int8:
-        q, scale = quantize_absmax(blk, axis=-1)  # scale [nb, Hkv, P, 1]
-        cache = cache.at[layer, idx].set(q.astype(cache.dtype))
-        scales = scales.at[layer, idx].set(scale[..., 0])
-        return cache, scales
-    cache = cache.at[layer, idx].set(blk.astype(cache.dtype))
-    return cache, scales
-
-
-def _token_page_write(cache, scales, layer, kv, tables, positions, int8,
-                      page_size):
-    """Write kv [S, T, Hkv, D] at absolute positions [S, T] through the
-    page tables [S, MP] (decode T=1, verify T=k+1). Inactive slots carry
-    zeroed table rows, so their writes land on the trash page.
-
-    One in-place ``dynamic_update_slice`` of ``[1, 1, Hkv, 1, D]`` a
-    (slot, token), unrolled. NOT one scatter: its ``[Hkv, D]`` update
-    window makes XLA:TPU keep the pool with heads beside the lane axis
-    (``{4,2,3,1,0}``), which is neither the layout the pool arrives in nor
-    the one the paged kernel reads, so a pass then copies the whole pool
-    in and out and re-lays a layer of it before each kernel call (30 of
-    a 37 ms pass: PERF.md, PR 30). And NOT a ``fori_loop``, whose carry
-    takes that layout too. tests/test_tpu_aot_compile.py holds the
-    engine's compiled programs to it."""
-    pg = jnp.take_along_axis(tables, positions // page_size, axis=1)
-    off = positions % page_size
-    if int8:
-        kv, scale = quantize_absmax(kv, axis=-1)  # scale [S, T, Hkv, 1]
-    rows = kv.astype(cache.dtype)[:, :, None, None, :, None, :]
-    for s_i in range(pg.shape[0]):
-        for t_i in range(pg.shape[1]):
-            at = (layer, pg[s_i, t_i], 0, off[s_i, t_i])
-            cache = jax.lax.dynamic_update_slice(
-                cache, rows[s_i, t_i], at + (0,))
-            if int8:
-                scales = jax.lax.dynamic_update_slice(
-                    scales, scale[s_i, t_i, None, None], at)
-    return cache, scales
-
-
-def _layer_kv(cache, scales, layer, int8):
-    """One layer's [N, Hkv, P, D] pool view, dequantized when int8."""
-    lay = cache[layer]
-    if int8:
-        return dequantize_absmax(lay, scales[layer][..., None])
-    return lay
-
-
-def _pin_pool_shardings(kc, vc, ksc, vsc):
-    """Trailing constraints pinning the RETURNED pools to the kv-head-
-    sharded layout the engine committed them with, so the compiled
-    program's output shardings match its input shardings and the
-    cache-carry loop never flaps between layouts (a flap would recompile,
-    breaking the buckets_used + 2 program-count gate). No-op without an
-    active mp mesh."""
-    from ..distributed import mesh as _mesh
-
-    m = _mesh.get_global_mesh()
-    if m is None or m.empty or _mesh.mesh_axis_size("mp", m) <= 1:
-        return kc, vc, ksc, vsc
-    kv = _mesh.P(None, None, "mp")  # [L, N, Hkv, ...]: shard kv heads
-    kc = _mesh.sharding_constraint(kc, kv, m)
-    vc = _mesh.sharding_constraint(vc, kv, m)
-    if ksc is not None:
-        ksc = _mesh.sharding_constraint(ksc, kv, m)
-        vsc = _mesh.sharding_constraint(vsc, kv, m)
-    return kc, vc, ksc, vsc
 
 
 def _shard_kv_heads(kv):
@@ -512,10 +428,8 @@ def _shard_kv_heads(kv):
     the mp axis on its head dim (axis -2), so the page-pool scatter that
     follows stays shard-local instead of gathering the pool. No-op
     without an active mp mesh or when mp doesn't divide Hkv."""
-    from ..distributed import mesh as _mesh
-
-    m = _mesh.get_global_mesh()
-    if m is None or m.empty or _mesh.mesh_axis_size("mp", m) <= 1:
+    m = active_mp_mesh()
+    if m is None:
         return kv
     spec = [None] * kv.ndim
     spec[-2] = "mp"
@@ -526,12 +440,8 @@ def _replicate_out(x):
     """Constraint hint forcing a program output replicated (sampled
     tokens, logits) so the one-int32-per-slot host transfer reads the
     same bits on every shard. No-op without an active mesh."""
-    from ..distributed import mesh as _mesh
-
-    m = _mesh.get_global_mesh()
-    if m is None or m.empty or _mesh.mesh_axis_size("mp", m) <= 1:
-        return x
-    return _mesh.sharding_constraint(x, _mesh.P(), m)
+    m = active_mp_mesh()
+    return x if m is None else _mesh.sharding_constraint(x, _mesh.P(), m)
 
 
 def _sample_tokens(logits, keys, temperature, top_k, top_p, greedy,
@@ -581,7 +491,8 @@ class DecodeEngine:
         cfg = self.config
         if cfg.kv_dtype not in KV_DTYPES:
             raise ValueError(
-                f"kv_dtype must be one of {KV_DTYPES}, got {cfg.kv_dtype!r}")
+                f"kv_dtype must be one of {tuple(KV_DTYPES)}, got "
+                f"{cfg.kv_dtype!r}")
         if cfg.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {cfg.page_size}")
         self.model = model
@@ -592,15 +503,7 @@ class DecodeEngine:
             raise ValueError(
                 f"max_length={cfg.max_length} exceeds the model's "
                 f"max_positions={ad.max_positions}")
-        if cfg.speculate_k and not getattr(ad, "multi_token_positions",
-                                           False):
-            raise ValueError(
-                "speculate_k > 0 needs an adapter accepting [S, T] "
-                "positions (multi_token_positions=True)")
         self.buckets = cfg.resolved_buckets()
-        self._int8 = cfg.kv_dtype == "int8"
-        store = {"f32": jnp.float32, "bf16": jnp.bfloat16,
-                 "int8": jnp.int8}[cfg.kv_dtype]
         self._mp = cfg.max_pages
         self._num_pages = cfg.resolved_num_pages()
         self._mesh = cfg.mesh
@@ -651,13 +554,17 @@ class DecodeEngine:
             _obs.inc("attn_kernel_fallback_total")
         _obs.set_gauge("attn_kernel_active",
                        1.0 if self._attn_kernel == "pallas" else 0.0)
+        #: the pool and its format (kv_pool.py); committed kv-head-sharded
+        #: ONCE on a mesh, like the replicated model state below: a
+        #: per-call device_put would re-place them every step
+        self.kv = KVPool.zeros(
+            ad.num_layers, self._num_pages, ad.num_kv_heads, cfg.page_size,
+            ad.head_dim, cfg.kv_dtype, mesh=self._mesh)
         # einsum + int8 materializes both dequantized [N, Hkv, P, D] f32
         # pools per layer per step; the fused path never does — account
         # the avoided traffic per decode/verify step
         self._fused_dequant_bytes_step = (
-            2 * ad.num_layers * self._num_pages * ad.num_kv_heads
-            * cfg.page_size * ad.head_dim * 4
-            if self._attn_kernel == "pallas" and self._int8 else 0)
+            self.kv.dequantized_bytes if self._attn_kernel == "pallas" else 0)
         try:  # price the choice in the auto-planner's cost model
             from ..distributed.auto_parallel.planner import plan_attn_kernel
 
@@ -669,30 +576,11 @@ class DecodeEngine:
                 selected=self._attn_kernel)
         except Exception:  # noqa: BLE001 — pricing never gates serving
             pass
-        shape = (ad.num_layers, self._num_pages, ad.num_kv_heads,
-                 cfg.page_size, ad.head_dim)
-        self._kc = jnp.zeros(shape, store)
-        self._vc = jnp.zeros(shape, store)
-        if self._int8:
-            self._ksc = jnp.ones(shape[:-1], jnp.float32)
-            self._vsc = jnp.ones(shape[:-1], jnp.float32)
-        else:
-            self._ksc = self._vsc = None
         if self._mesh is not None:
-            # commit the pools kv-head-sharded and the model state
-            # replicated ONCE — per-call device_put of the weights would
-            # re-replicate them every step
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as _P
+            from jax.sharding import NamedSharding, PartitionSpec
 
-            kv_sh = NamedSharding(self._mesh, _P(None, None, "mp"))
-            rep = NamedSharding(self._mesh, _P())
-            self._kc = jax.device_put(self._kc, kv_sh)
-            self._vc = jax.device_put(self._vc, kv_sh)
-            if self._int8:
-                self._ksc = jax.device_put(self._ksc, kv_sh)
-                self._vsc = jax.device_put(self._vsc, kv_sh)
-            self._replicated_sharding = rep
+            self._replicated_sharding = NamedSharding(
+                self._mesh, PartitionSpec())
         self.pool = PagePool(self._num_pages)
         cap = (cfg.prefix_registry_blocks
                if cfg.prefix_registry_blocks is not None
@@ -733,9 +621,8 @@ class DecodeEngine:
         if donate is None:
             donate = jax.default_backend() in ("tpu", "gpu")
         self._donate = bool(donate)
-        self._prefill_jit: Dict[int, object] = {}
-        self._decode_jit = None
-        self._verify_jit = None
+        #: name -> jitted program ("decode", "verify_k4", "prefill_b512")
+        self._jit: Dict[str, object] = {}
         self._compiled = set()
         #: name -> (jitted fn, abstract args) of every program that ran
         self._programs: Dict[str, tuple] = {}
@@ -988,18 +875,13 @@ class DecodeEngine:
             epoch = self._epoch
         with _obs.span("eng_decode_prep"):
             active, host = self._decode_inputs(epoch)
-            if self._decode_jit is None:
-                self._decode_jit = self._build_decode()
         warm = "decode" in self._compiled
         t0 = time.perf_counter()
         with _obs.span("eng_decode_upload"):
             dev = [jnp.asarray(a) for a in host]
         with _obs.span("eng_decode_dispatch"):
-            out = self._run_counted(
-                "decode", self._decode_jit,
-                self._state_vals(epoch), self._kc, self._vc, self._ksc,
-                self._vsc, *dev)
-            self._kc, self._vc, self._ksc, self._vsc, nxt, logits = out
+            nxt, logits = self._run(
+                "decode", self._state_vals(epoch), self.kv, *dev)
         with _obs.span("eng_decode_readback"):
             # the per-token host transfer, [S] int32: waits for the step
             nxt_host = np.asarray(nxt)
@@ -1105,8 +987,6 @@ class DecodeEngine:
                 temp[slot], top_k[slot], top_p[slot], greedy[slot] = (
                     t_, k_, p_, g_)
                 keys[slot] = req.key_np
-            if self._verify_jit is None:
-                self._verify_jit = self._build_verify(k1)
         warm = f"verify_k{k}" in self._compiled
         t0 = time.perf_counter()
         with _obs.span("eng_verify_upload"):
@@ -1114,11 +994,8 @@ class DecodeEngine:
                 tokens, positions, self._tables, keys, temp, top_k, top_p,
                 greedy)]
         with _obs.span("eng_verify_dispatch"):
-            out = self._run_counted(
-                f"verify_k{k}", self._verify_jit,
-                self._state_vals(epoch), self._kc, self._vc, self._ksc,
-                self._vsc, *dev)
-            self._kc, self._vc, self._ksc, self._vsc, targets, logits = out
+            targets, logits = self._run(
+                f"verify_k{k}", self._state_vals(epoch), self.kv, *dev)
         with _obs.span("eng_verify_readback"):
             targets_host = np.asarray(targets)  # [S, k+1] int32
         dt = time.perf_counter() - t0
@@ -1268,70 +1145,39 @@ class DecodeEngine:
         warmup, or live traffic) are skipped and counted as cache hits
         instead of re-executed — so a warmup after a weight flip is a
         cheap no-op rather than a second full sweep."""
-        cfg = self.config
-        s = cfg.num_slots
         hits0, n0 = self.aot_cache_hits, self.compile_count
-        row = np.zeros(self._mp, np.int32)
-        for tb in self.buckets:
-            if f"prefill_b{tb}" in self._compiled:
-                self.aot_cache_hits += 1
-                continue
-            fn = self._prefill_jit.get(tb)
-            if fn is None:
-                fn = self._build_prefill(tb)
-                self._prefill_jit[tb] = fn
-            ids = np.full((1, tb), 1, np.int32)
-            out = self._run_counted(
-                f"prefill_b{tb}", fn,
-                self._state_vals(), self._kc, self._vc, self._ksc,
-                self._vsc, jnp.asarray(ids), np.int32(0), np.int32(tb),
-                jnp.asarray(row), jnp.asarray(self._zero_key),
-                np.float32(1.0), np.int32(0), np.float32(1.0),
-                np.asarray(True))
-            self._kc, self._vc, self._ksc, self._vsc = out[:4]
-        positions = np.zeros(s, np.int32)
-        temp = np.ones(s, np.float32)
-        top_k = np.zeros(s, np.int32)
-        top_p = np.ones(s, np.float32)
-        greedy = np.ones(s, bool)
-        keys = np.array(np.broadcast_to(
-            self._zero_key, (s,) + self._zero_key.shape))
-        if "decode" in self._compiled:
-            self.aot_cache_hits += 1
-        else:
-            if self._decode_jit is None:
-                self._decode_jit = self._build_decode()
-            out = self._run_counted(
-                "decode", self._decode_jit,
-                self._state_vals(), self._kc, self._vc, self._ksc,
-                self._vsc, jnp.asarray(np.zeros(s, np.int32)),
-                jnp.asarray(positions),
-                jnp.asarray(self._tables), jnp.asarray(keys),
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(greedy))
-            self._kc, self._vc, self._ksc, self._vsc = out[:4]
-        verify = False
-        k = cfg.speculate_k
-        if k > 0:
-            verify = True
-            if f"verify_k{k}" in self._compiled:
+        k = self.config.speculate_k
+        for name in ([f"prefill_b{tb}" for tb in self.buckets] + ["decode"]
+                     + ([f"verify_k{k}"] if k > 0 else [])):
+            if name in self._compiled:
                 self.aot_cache_hits += 1
             else:
-                if self._verify_jit is None:
-                    self._verify_jit = self._build_verify(k + 1)
-                out = self._run_counted(
-                    f"verify_k{k}", self._verify_jit,
-                    self._state_vals(), self._kc, self._vc, self._ksc,
-                    self._vsc, jnp.asarray(np.zeros((s, k + 1), np.int32)),
-                    jnp.asarray(positions), jnp.asarray(self._tables),
-                    jnp.asarray(keys), jnp.asarray(temp),
-                    jnp.asarray(top_k), jnp.asarray(top_p),
-                    jnp.asarray(greedy))
-                self._kc, self._vc, self._ksc, self._vsc = out[:4]
+                self._run(name, *self._example_args(name))
         return {"buckets": len(self.buckets), "decode": True,
-                "verify": verify,
+                "verify": k > 0,
                 "programs": self.compile_count - n0,
                 "cache_hits": self.aot_cache_hits - hits0}
+
+    def _example_args(self, name: str) -> tuple:
+        """The arguments of program ``name`` ("decode", "verify_k4",
+        "prefill_b512"): the live state and pool, then synthetic inputs
+        with all-zero page tables, so every KV write lands on the inert
+        trash page. What ``warmup`` runs, and what a compile for a
+        described chip takes its shapes from."""
+        s, key = self.config.num_slots, self._zero_key
+        one, zero, yes = np.float32(1.0), np.int32(0), np.asarray(True)
+        kind, n = _program_kind(name)
+        if kind == "prefill":
+            inputs = (np.full((1, n), 1, np.int32), zero, np.int32(n),
+                      np.zeros(self._mp, np.int32), key, one, zero, one, yes)
+        else:
+            t = () if kind == "decode" else (n + 1,)
+            inputs = (np.zeros((s,) + t, np.int32), np.zeros(s, np.int32),
+                      np.zeros_like(self._tables),
+                      np.array(np.broadcast_to(key, (s,) + key.shape)),
+                      np.full(s, one), np.full(s, zero), np.full(s, one),
+                      np.full(s, yes))
+        return (self._state_vals(), self.kv) + inputs
 
     def stats(self) -> dict:
         return {
@@ -1486,18 +1332,12 @@ class DecodeEngine:
         self._free.pop()  # _finish may have re-appended it; net correct
         if req.status == "done":
             return {"done": self.result(rid)}
-        idx = jnp.asarray(row[:content_pages])
         out = {
             "first_token": int(req.tokens[0]),
             "true_len": t0,
             "prefill_s": float(req.prefill_s),
-            "pool_dtype": self.config.kv_dtype,
-            "k": np.asarray(jnp.take(self._kc, idx, axis=1)),
-            "v": np.asarray(jnp.take(self._vc, idx, axis=1)),
+            **self.kv.export_pages(row[:content_pages]),
         }
-        if self._int8:
-            out["ks"] = np.asarray(jnp.take(self._ksc, idx, axis=1))
-            out["vs"] = np.asarray(jnp.take(self._vsc, idx, axis=1))
         if req.tenant != "-":
             # label the handoff so the decode engine's ledger keys match
             # (absent tenant adds zero wire bytes, like the trace dict)
@@ -1576,38 +1416,7 @@ class DecodeEngine:
         row = np.zeros(self._mp, np.int32)
         row[:total_pages] = pages
         self._tables[slot] = row
-        idx = jnp.asarray(np.asarray(pages[:content_pages], np.int32))
-        k_in, v_in = kv["k"], kv["v"]
-        if self._int8 and "ks" in kv:
-            # int8 source pool -> int8 pool: copy the quantized slabs and
-            # their scales verbatim (bit-equal)
-            self._kc = self._kc.at[:, idx].set(
-                jnp.asarray(k_in, self._kc.dtype))
-            self._vc = self._vc.at[:, idx].set(
-                jnp.asarray(v_in, self._vc.dtype))
-            self._ksc = self._ksc.at[:, idx].set(
-                jnp.asarray(kv["ks"], jnp.float32))
-            self._vsc = self._vsc.at[:, idx].set(
-                jnp.asarray(kv["vs"], jnp.float32))
-        elif self._int8:
-            # float payload into an int8 pool: requantize at the same
-            # per-[page, head, token] granularity _block_page_write uses
-            qk, sk = quantize_absmax(jnp.asarray(k_in, jnp.float32), axis=-1)
-            qv, sv = quantize_absmax(jnp.asarray(v_in, jnp.float32), axis=-1)
-            self._kc = self._kc.at[:, idx].set(qk.astype(self._kc.dtype))
-            self._vc = self._vc.at[:, idx].set(qv.astype(self._vc.dtype))
-            self._ksc = self._ksc.at[:, idx].set(sk[..., 0])
-            self._vsc = self._vsc.at[:, idx].set(sv[..., 0])
-        else:
-            if "ks" in kv:  # int8 source pool -> float pool
-                k_in = dequantize_absmax(
-                    jnp.asarray(k_in), jnp.asarray(kv["ks"])[..., None])
-                v_in = dequantize_absmax(
-                    jnp.asarray(v_in), jnp.asarray(kv["vs"])[..., None])
-            self._kc = self._kc.at[:, idx].set(
-                jnp.asarray(k_in, self._kc.dtype))
-            self._vc = self._vc.at[:, idx].set(
-                jnp.asarray(v_in, self._vc.dtype))
+        self.kv = self.kv.import_pages(pages[:content_pages], kv)
         rid = self._next_id
         self._next_id += 1
         now = time.perf_counter()
@@ -1836,10 +1645,6 @@ class DecodeEngine:
         with _obs.span("eng_prefill_prep", rid=rid):
             tail = req.prompt[cached_len:]
             tb = self._bucket_for(len(tail))
-            fn = self._prefill_jit.get(tb)
-            if fn is None:
-                fn = self._build_prefill(tb)
-                self._prefill_jit[tb] = fn
             ids = np.zeros((1, tb), np.int32)
             ids[0, :len(tail)] = tail
             t_, k_, p_, g_ = req.params.fields()
@@ -1849,11 +1654,8 @@ class DecodeEngine:
                     np.float32(t_), np.int32(k_), np.float32(p_),
                     np.asarray(g_))
         with _obs.span("eng_prefill_dispatch", rid=rid, bucket=int(tb)):
-            out = self._run_counted(
-                f"prefill_b{tb}", fn,
-                self._state_vals(), self._kc, self._vc, self._ksc,
-                self._vsc, *args)
-            self._kc, self._vc, self._ksc, self._vsc, nxt, logits = out
+            nxt, _ = self._run(
+                f"prefill_b{tb}", self._state_vals(), self.kv, *args)
         with _obs.span("eng_prefill_readback", rid=rid):
             token = int(nxt)  # waits for the prefill
         now = time.perf_counter()
@@ -2003,7 +1805,22 @@ class DecodeEngine:
         with self._mesh_ctx():
             return fn.lower(*args).compile().as_text()
 
-    def _run_counted(self, name, fn, *args):
+    def _jitted(self, name: str):
+        """The jitted program of that name, built at its first use."""
+        fn = self._jit.get(name)
+        if fn is None:
+            kind, n = _program_kind(name)
+            fn = self._jit[name] = (
+                self._build_decode() if kind == "decode"
+                else self._build_verify(n + 1) if kind == "verify"
+                else self._build_prefill(n))
+        return fn
+
+    def _run(self, name: str, *args):
+        """Run program ``name`` on ``(state values, pool, *inputs)``, keep
+        the pool it returns and hand back ``(tokens, logits)``; a
+        program's first run is timed and counted as its compile."""
+        fn = self._jitted(name)
         first = name not in self._compiled
         t0 = time.perf_counter() if first else 0.0
         if first:
@@ -2043,7 +1860,8 @@ class DecodeEngine:
             _obs.inc("serving_engine_compile_total")
             _obs.record_compile("decode_engine", dt, signature=name,
                                 cache_hit=hit)
-        return out
+        self.kv, tokens, logits = out
+        return tokens, logits
 
     def _aot_key_parts(self, name: str) -> dict:
         """Semantic fingerprint for the persistent AOT compile cache:
@@ -2071,8 +1889,9 @@ class DecodeEngine:
     # All programs take the model state EXPLICITLY (param/buffer values are
     # swapped into the live tensors around the traced body and restored —
     # the jit.TracedLayer idiom), so parameters stay jit arguments rather
-    # than baked-in constants, and the paged KV pool flows through as
-    # donated inputs/outputs. Page tables arrive as plain int32 arguments.
+    # than baked-in constants, and the paged KV pool flows through as ONE
+    # donated pytree input/output. Page tables arrive as plain int32
+    # arguments.
 
     def _wire_logits(self, logits):
         """Route mp-vocab-sharded logits [..., V] through the quantized
@@ -2099,198 +1918,134 @@ class DecodeEngine:
             exact = None
         return wl, exact, wl
 
-    def _attend(self, q, kc, vc, ksc, vsc, l, tables, positions):
-        """One layer of paged attention on the resolved kernel. The fused
-        Pallas path hands the kernel the whole STORED pool and the layer's
-        index, which its index maps read, so the pool is never sliced —
-        plus the layer's absmax scale slabs when int8 (1 MB, sliced: the
-        kernel wants them with a trailing 1, which the stacked slab could
-        not take without padding every lane), so dequant happens against
-        the VMEM-resident page inside the kernel; the einsum oracle
-        dequantizes the layer's pool up front (``_layer_kv``). The
-        kernel is pinned explicitly so an ambient PADDLE_TPU_ATTN_KERNEL
-        cannot diverge a program from the engine's resolved (and
-        AOT-cache-keyed) choice."""
-        if self._attn_kernel == "pallas":
-            return F.paged_attention(
-                q, kc, vc, tables, positions, layer=l,
-                k_scales=None if ksc is None else ksc[l],
-                v_scales=None if vsc is None else vsc[l],
-                kernel="pallas")
-        return F.paged_attention(
-            q, _layer_kv(kc, ksc, l, self._int8),
-            _layer_kv(vc, vsc, l, self._int8), tables, positions,
-            kernel="einsum")
+    def _program(self, body):
+        """Jit ``body(pool, *inputs) -> (pool, tokens, logits)`` as one of
+        the engine's programs (the header above): the state swap, the
+        returned pool's pin and the pool's donation, once for all three."""
+        state = self._state
 
-    def _token_layer(self, l, x, kc, vc, ksc, vsc, tables, positions, pos2):
-        """One layer of the decode and verify programs (traced): the new
-        tokens' K/V go to their pages, then attention over the pages. Each
-        part under its ``named_scope``, which the compiled text keeps
-        (``profiler.op_scopes``)."""
-        ad, int8, psz = self.adapter, self._int8, self.config.page_size
-        with _scope("qkv"):
-            h = ad.pre_attn(l, x)
-            q, k, v = ad.qkv(l, h, pos2)
-        with _scope("kv_write"):
-            kc, ksc = _token_page_write(
-                kc, ksc, l, _shard_kv_heads(raw(k)), tables, pos2, int8, psz)
-            vc, vsc = _token_page_write(
-                vc, vsc, l, _shard_kv_heads(raw(v)), tables, pos2, int8, psz)
-        with _scope("attend"):
-            o = self._attend(q, kc, vc, ksc, vsc, l, tables, positions)
-        with _scope("attn_out"):
-            x = x + ad.attn_out(l, o)
-        with _scope("mlp"):
-            x = x + ad.mlp(l, x)
-        return x, kc, vc, ksc, vsc
-
-    def _build_prefill(self, tb: int):
-        ad, state, int8 = self.adapter, self._state, self._int8
-        layers = ad.num_layers
-        psz = self.config.page_size
-
-        def pure(state_vals, kc, vc, ksc, vsc, ids, cached_len, true_len,
-                 row, key, temp, top_k, top_p, greedy):
+        def pure(state_vals, pool, *inputs):
             originals = [t._value for t in state]
             try:
                 for t_, v_ in zip(state, state_vals):
                     t_._value = v_
                 with no_grad():
-                    positions = cached_len + jnp.arange(tb, dtype=jnp.int32)
-                    start = jnp.reshape(cached_len, (1,)).astype(jnp.int32)
-                    table = row[None]  # [1, MP]
-                    with _scope("embed"):
-                        x = ad.embed(Tensor(ids), positions)
-                    for l in range(layers):
-                        with _scope("qkv"):
-                            h = ad.pre_attn(l, x)
-                            q, k, v = ad.qkv(l, h, positions)
-                        with _scope("kv_write"):
-                            kc, ksc = _block_page_write(
-                                kc, ksc, l, _shard_kv_heads(raw(k)), row,
-                                cached_len, true_len, int8, psz)
-                            vc, vsc = _block_page_write(
-                                vc, vsc, l, _shard_kv_heads(raw(v)), row,
-                                cached_len, true_len, int8, psz)
-                        with _scope("attend"):
-                            o = self._attend(q, kc, vc, ksc, vsc, l, table,
-                                             start)
-                        with _scope("attn_out"):
-                            x = x + ad.attn_out(l, o)
-                        with _scope("mlp"):
-                            x = x + ad.mlp(l, x)
-                    with _scope("lm_head"):
-                        x = ad.final_norm(x)
-                        # right-pad positions >= true_len are inert under
-                        # the position mask; the real last-token logits sit
-                        # at tail offset true_len - 1 - cached_len
-                        last = jax.lax.dynamic_slice_in_dim(
-                            raw(x), true_len - 1 - cached_len, 1, 1)
-                        logits = raw(ad.logits(Tensor(last)))[:, 0].astype(
-                            jnp.float32)
+                    pool, tokens, logits = body(pool, *inputs)
             finally:
                 for t_, v_ in zip(state, originals):
                     t_._value = v_
+            return pool.pin(), tokens, logits
+
+        return jax.jit(pure, donate_argnums=(1,) if self._donate else ())
+
+    def _forward(self, pool, ids, positions, write, tables, starts, read):
+        """The one forward of the three programs (traced): ``embed ->
+        layers -> head``. A layer is the MODEL's own block, handed
+        ``attend(q, k, v)`` for the place where full attention stood: the
+        new tokens' K/V go to their pages (``write(pool, l, k, v)``, a
+        prompt block or a row a token), then attention over the pages of
+        ``tables`` from ``starts`` on. ``read(hidden, head)`` gives the
+        logits of the rows the program wants. Each part under its
+        ``named_scope``, which the compiled text keeps
+        (``profiler.op_scopes``): ``embed``, ``kv_write``, ``attend`` and
+        ``lm_head`` here, ``sample`` in the builders, ``qkv``, ``attn_out``
+        and ``mlp`` opened by the model. Returns the pool and f32 logits."""
+        ad = self.adapter
+
+        def attend(l, q, k, v):
+            nonlocal pool
+            with _scope("kv_write"):
+                pool = write(pool, l, _shard_kv_heads(raw(k)),
+                             _shard_kv_heads(raw(v)))
+            with _scope("attend"):
+                return pool.attend(q, l, tables, starts, self._attn_kernel)
+
+        with _scope("embed"):
+            x = ad.embed(Tensor(ids), positions)
+        for l in range(ad.num_layers):
+            x = ad.layer(l, x, positions, functools.partial(attend, l))
+        with _scope("lm_head"):
+            logits = read(raw(x), lambda h: raw(ad.head(Tensor(h))))
+            return pool, logits.astype(jnp.float32)
+
+    def _sample(self, logits, keys, temp, top_k, top_p, greedy):
+        """One token a row of ``logits`` [..., V] on the typed ``keys``
+        [...]; the sampling fields [S] hold for every row of their slot.
+        Returns the tokens [...] and the logits a program hands back,
+        both replicated."""
+        s_logits, exact_arg, wired = self._wire_logits(logits)
+        n = int(np.prod(keys.shape))
+        per = n // temp.shape[0]
+        rep = (lambda a: a) if per == 1 else (
+            lambda a: jnp.repeat(a, per, axis=0))
+        tokens = _sample_tokens(
+            s_logits.reshape(n, -1), keys.reshape(n), rep(temp), rep(top_k),
+            rep(top_p), rep(greedy),
+            exact_argmax=None if exact_arg is None else exact_arg.reshape(n)
+        ).reshape(keys.shape)
+        return _replicate_out(tokens), (
+            _replicate_out(logits) if wired is None else wired)
+
+    def _build_prefill(self, tb: int):
+        def body(pool, ids, cached_len, true_len, row, key, temp, top_k,
+                 top_p, greedy):
+            positions = cached_len + jnp.arange(tb, dtype=jnp.int32)
+            start = jnp.reshape(cached_len, (1,)).astype(jnp.int32)
+            # right-pad positions >= true_len are inert under the position
+            # mask; the real last-token logits sit at tail offset
+            # true_len - 1 - cached_len
+            pool, logits = self._forward(
+                pool, ids, positions,
+                lambda pool, l, k, v: pool.write_block(
+                    l, k, v, row, cached_len, true_len),
+                row[None], start,
+                lambda x, head: head(jax.lax.dynamic_slice_in_dim(
+                    x, true_len - 1 - cached_len, 1, 1))[:, 0])
             # sample stream keyed by DESTINATION position: token landing at
             # position true_len uses fold_in(key, true_len), matching what
             # the decode step would use — scheduling-invariant
             with _scope("sample"):
                 step_key = jax.random.fold_in(
                     jax.random.wrap_key_data(key, impl=_KEY_IMPL), true_len)
-                s_logits, exact_arg, wired = self._wire_logits(logits)
-                nxt = _sample_tokens(s_logits, step_key[None], temp[None],
-                                     top_k[None], top_p[None], greedy[None],
-                                     exact_argmax=exact_arg)
-            kc, vc, ksc, vsc = _pin_pool_shardings(kc, vc, ksc, vsc)
-            out_logits = (_replicate_out(logits[0]) if wired is None
-                          else wired[0])
-            return (kc, vc, ksc, vsc, _replicate_out(nxt[0]), out_logits)
+                nxt, logits = self._sample(
+                    logits, step_key[None], temp[None], top_k[None],
+                    top_p[None], greedy[None])
+            return pool, nxt[0], logits[0]
 
-        donate = (1, 2, 3, 4) if self._donate else ()
-        return jax.jit(pure, donate_argnums=donate)
+        return self._program(body)
 
     def _build_decode(self):
-        ad, state = self.adapter, self._state
-        layers = ad.num_layers
-
-        def pure(state_vals, kc, vc, ksc, vsc, tokens, positions, tables,
-                 keys, temp, top_k, top_p, greedy):
-            originals = [t._value for t in state]
-            try:
-                for t_, v_ in zip(state, state_vals):
-                    t_._value = v_
-                with no_grad():
-                    pos2 = positions[:, None]  # [S, 1]
-                    with _scope("embed"):
-                        x = ad.embed(Tensor(tokens[:, None]), pos2)
-                    for l in range(layers):
-                        x, kc, vc, ksc, vsc = self._token_layer(
-                            l, x, kc, vc, ksc, vsc, tables, positions, pos2)
-                    with _scope("lm_head"):
-                        x = ad.final_norm(x)
-                        logits = raw(ad.logits(x))[:, 0].astype(jnp.float32)
-            finally:
-                for t_, v_ in zip(state, originals):
-                    t_._value = v_
+        def body(pool, tokens, positions, tables, keys, *sampling):
+            pos2 = positions[:, None]  # [S, 1]
+            pool, logits = self._forward(
+                pool, tokens[:, None], pos2,
+                lambda pool, l, k, v: pool.write_tokens(
+                    l, k, v, tables, pos2),
+                tables, positions, lambda x, head: head(x)[:, 0])
             with _scope("sample"):
                 step_keys = jax.vmap(jax.random.fold_in)(
                     jax.random.wrap_key_data(keys, impl=_KEY_IMPL),
                     positions + 1)
-                s_logits, exact_arg, wired = self._wire_logits(logits)
-                nxt = _sample_tokens(s_logits, step_keys, temp, top_k,
-                                     top_p, greedy, exact_argmax=exact_arg)
-            kc, vc, ksc, vsc = _pin_pool_shardings(kc, vc, ksc, vsc)
-            out_logits = _replicate_out(logits) if wired is None else wired
-            return (kc, vc, ksc, vsc, _replicate_out(nxt), out_logits)
+                return pool, *self._sample(logits, step_keys, *sampling)
 
-        donate = (1, 2, 3, 4) if self._donate else ()
-        return jax.jit(pure, donate_argnums=donate)
+        return self._program(body)
 
     def _build_verify(self, k1: int):
         """The speculative companion of the decode program: k1 = k + 1
         tokens per slot in one pass, per-position sampling on the SAME
         position-keyed streams."""
-        ad, state = self.adapter, self._state
-        layers = ad.num_layers
-
-        def pure(state_vals, kc, vc, ksc, vsc, tokens, positions, tables,
-                 keys, temp, top_k, top_p, greedy):
-            s = tokens.shape[0]
-            originals = [t._value for t in state]
-            try:
-                for t_, v_ in zip(state, state_vals):
-                    t_._value = v_
-                with no_grad():
-                    pos2 = positions[:, None] + jnp.arange(
-                        k1, dtype=jnp.int32)[None, :]  # [S, k1]
-                    with _scope("embed"):
-                        x = ad.embed(Tensor(tokens), pos2)
-                    for l in range(layers):
-                        x, kc, vc, ksc, vsc = self._token_layer(
-                            l, x, kc, vc, ksc, vsc, tables, positions, pos2)
-                    with _scope("lm_head"):
-                        x = ad.final_norm(x)
-                        logits = raw(ad.logits(x)).astype(
-                            jnp.float32)  # [S,k1,V]
-            finally:
-                for t_, v_ in zip(state, originals):
-                    t_._value = v_
+        def body(pool, tokens, positions, tables, keys, *sampling):
+            pos2 = positions[:, None] + jnp.arange(
+                k1, dtype=jnp.int32)[None, :]  # [S, k1]
+            pool, logits = self._forward(
+                pool, tokens, pos2,
+                lambda pool, l, k, v: pool.write_tokens(
+                    l, k, v, tables, pos2),
+                tables, positions, lambda x, head: head(x))
             with _scope("sample"):
                 step_keys = jax.vmap(jax.vmap(
                     jax.random.fold_in, in_axes=(None, 0)))(
                     jax.random.wrap_key_data(keys, impl=_KEY_IMPL), pos2 + 1)
-                s_logits, exact_arg, wired = self._wire_logits(logits)
-                flat = s_logits.reshape(s * k1, -1)
-                rep = lambda a: jnp.repeat(a, k1, axis=0)
-                targets = _sample_tokens(
-                    flat, step_keys.reshape(s * k1), rep(temp), rep(top_k),
-                    rep(top_p), rep(greedy),
-                    exact_argmax=(None if exact_arg is None
-                                  else exact_arg.reshape(s * k1))
-                ).reshape(s, k1)
-            kc, vc, ksc, vsc = _pin_pool_shardings(kc, vc, ksc, vsc)
-            out_logits = _replicate_out(logits) if wired is None else wired
-            return (kc, vc, ksc, vsc, _replicate_out(targets), out_logits)
+                return pool, *self._sample(logits, step_keys, *sampling)
 
-        donate = (1, 2, 3, 4) if self._donate else ()
-        return jax.jit(pure, donate_argnums=donate)
+        return self._program(body)

@@ -343,6 +343,10 @@ _HLO_OP_NAME = re.compile(
     r'^\s*(?:ROOT )?%?([\w.\-]+) = .*\bop_name="([^"]*)"', re.M)
 _JIT_WRAPPER = re.compile(r"^p?jit\(.*\)$")
 
+#: ``with scope("mlp"):`` names a part of a jitted step (the engine's
+#: programs, a model's serving block); ``op_scopes`` reads it back
+scope = jax.named_scope
+
 
 def op_scopes(hlo_text: str) -> dict:
     """{instruction name: innermost scope} over a compiled program's text

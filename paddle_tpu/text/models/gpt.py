@@ -35,6 +35,7 @@ from ...distributed.fleet.layers.mpu import (
     mp_wire_linear,
 )
 from ...distributed.fleet.utils import recompute as _recompute
+from ...profiler import scope
 
 
 class GPTConfig:
@@ -371,11 +372,11 @@ GPTForCausalLM.generate = _gpt_generate
 
 
 # ---------------------------------------------------------------------------
-# Serving decode-engine adapter (inference/engine.py). The engine owns the
-# residual stream and the slot-indexed KV cache; the adapter exposes the
-# per-layer hooks (norm / qkv / out-proj / mlp) plus the geometry the engine
-# needs to size its [L, S, Hkv, Tmax, D] cache. One engine loop then serves
-# every decoder-only model family.
+# What the serving engine needs of a model (inference/engine.py,
+# docs/SERVING.md "Serving a new model"): ``embed``, the model's own block as
+# ``layer`` with the engine's paged ``attend(q, k, v)`` where full attention
+# stood, ``head``, and the geometry that sizes the KV pool. The engine owns
+# slots, pages and sampling; the block equation is written here.
 # ---------------------------------------------------------------------------
 
 
@@ -394,40 +395,31 @@ class _GPTDecodeAdapter:
         self.num_kv_heads = cfg.num_attention_heads
         self.head_dim = cfg.hidden_size // cfg.num_attention_heads
         self.max_positions = cfg.max_position_embeddings
-        # positions may arrive [B, T] with a DIFFERENT offset per row
-        # (the engine's speculative verify step); both the learned
-        # position table and rope gather per-element, so [B, T] is
-        # first-class here
-        self.multi_token_positions = True
 
     def embed(self, input_ids, positions):
-        """input_ids Tensor [B, T]; positions int array [T] or [B, T]."""
+        """input_ids Tensor [B, T]; positions int array [T] or [B, T] with
+        a DIFFERENT offset per row (the engine's speculative verify step):
+        the learned position table gathers per element."""
         import jax.numpy as jnp
 
         return self.lm.gpt.embeddings(
             input_ids, Tensor(jnp.asarray(positions)))
 
-    def pre_attn(self, layer, x):
-        return self.blocks[layer].ln_1(x)
+    def layer(self, l, x, positions, attend):
+        blk = self.blocks[l]
+        attn = blk.attn
+        with scope("qkv"):
+            q, k, v = _gpt_qkv(attn, blk.ln_1(x))
+        o = attend(q, k, v)
+        with scope("attn_out"):
+            b, t = o.shape[0], o.shape[1]
+            x = x + attn.out_proj(
+                o.reshape([b, t, attn.num_heads * attn.head_dim]))
+        with scope("mlp"):
+            return x + blk.mlp(blk.ln_2(x))
 
-    def qkv(self, layer, h, positions):
-        return _gpt_qkv(self.blocks[layer].attn, h)
-
-    def attn_out(self, layer, o):
-        attn = self.blocks[layer].attn
-        b, t = o.shape[0], o.shape[1]
-        return attn.out_proj(
-            o.reshape([b, t, attn.num_heads * attn.head_dim]))
-
-    def mlp(self, layer, x):
-        blk = self.blocks[layer]
-        return blk.mlp(blk.ln_2(x))
-
-    def final_norm(self, x):
-        return self.lm.gpt.final_layernorm(x)
-
-    def logits(self, hidden):
-        return self.lm._logits(hidden)
+    def head(self, x):
+        return self.lm._logits(self.lm.gpt.final_layernorm(x))
 
 
 def _gpt_decode_adapter(self):

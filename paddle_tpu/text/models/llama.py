@@ -33,6 +33,7 @@ from ...framework.core import Tensor
 from ...framework.op import defop, raw
 from ...nn import functional as F
 from ...nn import initializer as I
+from ...profiler import scope
 
 
 class LlamaConfig:
@@ -450,10 +451,10 @@ LlamaForCausalLM.generate = _llama_generate
 
 
 # ---------------------------------------------------------------------------
-# Serving decode-engine adapter (inference/engine.py; see the GPT twin in
-# gpt.py for the contract). Rope is applied inside qkv() at the engine's
-# explicit positions so prefill buckets and per-slot decode share one code
-# path.
+# What the serving engine needs of a model (inference/engine.py; the GPT
+# twin in gpt.py has the contract). Rope is applied inside ``layer`` at the
+# engine's explicit positions, [T] or per-slot [B, T], so prefill buckets
+# and per-slot decode share one code path.
 # ---------------------------------------------------------------------------
 
 
@@ -472,43 +473,34 @@ class _LlamaDecodeAdapter:
         self.num_kv_heads = cfg.num_key_value_heads
         self.head_dim = cfg.hidden_size // cfg.num_attention_heads
         self.max_positions = cfg.max_position_embeddings
-        # positions may arrive [B, T] with a DIFFERENT offset per row
-        # (the engine's speculative verify step); both the learned
-        # position table and rope gather per-element, so [B, T] is
-        # first-class here
-        self.multi_token_positions = True
 
     def embed(self, input_ids, positions):
         return self.lm.llama.embed_tokens(input_ids)
 
-    def pre_attn(self, layer, x):
-        return self.blocks[layer].input_layernorm(x)
+    def layer(self, l, x, positions, attend):
+        blk = self.blocks[l]
+        attn = blk.self_attn
+        b, t = x.shape[0], x.shape[1]
+        with scope("qkv"):
+            h = blk.input_layernorm(x)
+            q = attn.q_proj(h).reshape([b, t, attn.num_heads, attn.head_dim])
+            k = attn.k_proj(h).reshape(
+                [b, t, attn.num_kv_heads, attn.head_dim])
+            v = attn.v_proj(h).reshape(
+                [b, t, attn.num_kv_heads, attn.head_dim])
+            q = _apply_rope_positions(q, attn.rope_cos, attn.rope_sin,
+                                      positions)
+            k = _apply_rope_positions(k, attn.rope_cos, attn.rope_sin,
+                                      positions)
+        o = attend(q, k, v)
+        with scope("attn_out"):
+            x = x + attn.o_proj(
+                o.reshape([b, t, attn.num_heads * attn.head_dim]))
+        with scope("mlp"):
+            return x + blk.mlp(blk.post_attention_layernorm(x))
 
-    def qkv(self, layer, h, positions):
-        attn = self.blocks[layer].self_attn
-        b, t = h.shape[0], h.shape[1]
-        q = attn.q_proj(h).reshape([b, t, attn.num_heads, attn.head_dim])
-        k = attn.k_proj(h).reshape([b, t, attn.num_kv_heads, attn.head_dim])
-        v = attn.v_proj(h).reshape([b, t, attn.num_kv_heads, attn.head_dim])
-        q = _apply_rope_positions(q, attn.rope_cos, attn.rope_sin, positions)
-        k = _apply_rope_positions(k, attn.rope_cos, attn.rope_sin, positions)
-        return q, k, v
-
-    def attn_out(self, layer, o):
-        attn = self.blocks[layer].self_attn
-        b, t = o.shape[0], o.shape[1]
-        return attn.o_proj(
-            o.reshape([b, t, attn.num_heads * attn.head_dim]))
-
-    def mlp(self, layer, x):
-        blk = self.blocks[layer]
-        return blk.mlp(blk.post_attention_layernorm(x))
-
-    def final_norm(self, x):
-        return self.lm.llama.norm(x)
-
-    def logits(self, hidden):
-        return self.lm._logits(hidden)
+    def head(self, x):
+        return self.lm._logits(self.lm.llama.norm(x))
 
 
 def _llama_decode_adapter(self):

@@ -16,7 +16,8 @@ import pytest
 import paddle_tpu.inference as inference
 from paddle_tpu.inference.engine import (DecodeEngine, EngineConfig,
                                          PagePool, PrefixRegistry,
-                                         SamplingParams, _token_page_write)
+                                         SamplingParams)
+from paddle_tpu.inference.kv_pool import KVPool
 from paddle_tpu.distributed.grad_comm import quantize_absmax
 from paddle_tpu.text.generation import prompt_lookup_draft
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
@@ -328,6 +329,15 @@ def _scatter_token_write(cache, scales, layer, kv, tables, positions, int8,
     return cache.at[layer, pg, :, off, :].set(kv.astype(cache.dtype)), scales
 
 
+def _pool_token_write(cache, scales, layer, kv, tables, positions, int8,
+                      page_size):
+    """``KVPool.write_tokens`` on a pool whose K and V both start as
+    ``cache`` and both take ``kv``."""
+    assert (scales is not None) == int8 and cache.shape[3] == page_size
+    return KVPool(cache, cache, scales, scales).write_tokens(
+        layer, kv, kv, tables, positions)
+
+
 #: (T, position of each slot's first row; None = an idle slot, whose zeroed
 #: table row sends its write to the trash page)
 TOKEN_WRITES = {
@@ -370,13 +380,15 @@ def test_token_write_in_place_equals_the_scatter_it_replaced(
         scales = None
     kv = jnp.asarray(rng.standard_normal((s, t, hkv, d)), jnp.float32)
     args = (cache, scales, layer, kv, jnp.asarray(tables), positions, int8, p)
-    new, old = _token_page_write, _scatter_token_write
+    new, old = _pool_token_write, _scatter_token_write
     if jit:  # both: a fused quantize rounds its scale in another order
         new, old = (jax.jit(f, static_argnums=(2, 6, 7)) for f in (new, old))
     got, want = new(*args), old(*args)
     # several idle slots write the trash page's first row, in the scatter
     # in no stated order: every other page has to agree, bit for bit
-    for g, w, before in zip(got, want, (cache, scales)):
+    for g, w, before in zip(
+            (got.k, got.k_scales, got.v, got.v_scales), want * 2,
+            (cache, scales) * 2):
         if w is None:
             assert g is None
             continue

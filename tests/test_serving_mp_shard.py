@@ -98,7 +98,8 @@ def test_mp2_bit_equal_with_prefix_and_speculation(model):
 
     # the KV pool really is split over the mp axis
     from paddle_tpu.distributed.mesh import P
-    assert eng._kc.sharding.spec == P(None, None, "mp")
+    assert all(a.sharding.spec == P(None, None, "mp")
+               for a in jax.tree.leaves(eng.kv))
     assert eng._mp_degree == 2
 
     # prefix sharing survived sharding (2 full pages of shared prefix,

@@ -286,10 +286,10 @@ _NO_TRAFFIC = {"parameter", "tuple", "get-tuple-element", "bitcast"}
 @pytest.fixture(scope="module")
 def engine_programs(one_chip):
     """``text_of(kv, program)``: the compiled text of one of the engine's
-    own programs, built by its own ``_build_*`` from shapes (a described
-    chip holds no array), and the shape of its K pool. The engine asks
-    ``jax.default_backend()`` for its kernel, its donation and the kernel's
-    interpret mode: steered here."""
+    own programs, built by the engine from the shapes of its own example
+    arguments (a described chip holds no array), and the pool's shape. The
+    engine asks ``jax.default_backend()`` for its kernel, its donation and
+    the kernel's interpret mode: steered here."""
     from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
     from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
 
@@ -310,36 +310,17 @@ def engine_programs(one_chip):
         return made[kv]
 
     def shaped(a):
-        return None if a is None else jax.ShapeDtypeStruct(
-            np.shape(a), a.dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one_chip)
 
     def text_of(kv, program):
-        if (kv, program) in made:
-            return made[kv, program], made[kv]._kc.shape
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(jax, "default_backend", lambda: "tpu")
-            eng = engine(kv)
-            assert eng.stats()["attn_kernel"] == "pallas" and eng._donate
-            s, key = eng.config.num_slots, eng._zero_key
-            row = np.zeros(eng._mp, np.int32)
-            per_slot = [np.zeros(s, np.int32), eng._tables,
-                        np.zeros((s,) + key.shape, key.dtype),
-                        np.ones(s, np.float32), np.zeros(s, np.int32),
-                        np.ones(s, np.float32), np.ones(s, bool)]
-            if program == "decode":
-                fn, args = eng._build_decode(), [np.zeros(s, np.int32)]
-            elif program == "verify_k4":
-                fn, args = eng._build_verify(5), [np.zeros((s, 5), np.int32)]
-            else:
-                fn, args, per_slot = eng._build_prefill(512), [], [
-                    np.zeros((1, 512), np.int32), np.int32(0), np.int32(512),
-                    row, key, np.float32(1), np.int32(0), np.float32(1),
-                    np.asarray(True)]
-            pools = [eng._kc, eng._vc, eng._ksc, eng._vsc]
-            made[kv, program] = fn.lower(*jax.tree.map(
-                shaped, (eng._state_vals(), *pools, *args, *per_slot),
-                is_leaf=lambda a: a is None)).compile().as_text()
-        return made[kv, program], eng._kc.shape
+        if (kv, program) not in made:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                eng = engine(kv)
+                assert eng.stats()["attn_kernel"] == "pallas" and eng._donate
+                made[kv, program] = eng._jitted(program).lower(*jax.tree.map(
+                    shaped, eng._example_args(program))).compile().as_text()
+        return made[kv, program], made[kv].kv.shape
 
     return text_of
 
