@@ -1938,15 +1938,16 @@ class DecodeEngine:
 
         return jax.jit(pure, donate_argnums=(1,) if self._donate else ())
 
-    def _forward(self, pool, ids, positions, write, tables, starts, read):
+    def _forward(self, pool, ids, positions, write, attend_pages, read):
         """The one forward of the three programs (traced): ``embed ->
         layers -> head``. A layer is the MODEL's own block, handed
         ``attend(q, k, v)`` for the place where full attention stood: the
         new tokens' K/V go to their pages (``write(pool, l, k, v)``, a
-        prompt block or a row a token), then attention over the pages of
-        ``tables`` from ``starts`` on. ``read(hidden, head)`` gives the
-        logits of the rows the program wants. Each part under its
-        ``named_scope``, which the compiled text keeps
+        prompt block or a row a token), then attention over the slots'
+        pages (``attend_pages(pool, l, q)``: the pool's block read in a
+        prefill, its paged read in decode and verify). ``read(hidden,
+        head)`` gives the logits of the rows the program wants. Each part
+        under its ``named_scope``, which the compiled text keeps
         (``profiler.op_scopes``): ``embed``, ``kv_write``, ``attend`` and
         ``lm_head`` here, ``sample`` in the builders, ``qkv``, ``attn_out``
         and ``mlp`` opened by the model. Returns the pool and f32 logits."""
@@ -1958,7 +1959,7 @@ class DecodeEngine:
                 pool = write(pool, l, _shard_kv_heads(raw(k)),
                              _shard_kv_heads(raw(v)))
             with _scope("attend"):
-                return pool.attend(q, l, tables, starts, self._attn_kernel)
+                return attend_pages(pool, l, q)
 
         with _scope("embed"):
             x = ad.embed(Tensor(ids), positions)
@@ -1990,7 +1991,6 @@ class DecodeEngine:
         def body(pool, ids, cached_len, true_len, row, key, temp, top_k,
                  top_p, greedy):
             positions = cached_len + jnp.arange(tb, dtype=jnp.int32)
-            start = jnp.reshape(cached_len, (1,)).astype(jnp.int32)
             # right-pad positions >= true_len are inert under the position
             # mask; the real last-token logits sit at tail offset
             # true_len - 1 - cached_len
@@ -1998,7 +1998,8 @@ class DecodeEngine:
                 pool, ids, positions,
                 lambda pool, l, k, v: pool.write_block(
                     l, k, v, row, cached_len, true_len),
-                row[None], start,
+                lambda pool, l, q: pool.attend_block(
+                    q, l, row, cached_len, self._attn_kernel),
                 lambda x, head: head(jax.lax.dynamic_slice_in_dim(
                     x, true_len - 1 - cached_len, 1, 1))[:, 0])
             # sample stream keyed by DESTINATION position: token landing at
@@ -2021,7 +2022,9 @@ class DecodeEngine:
                 pool, tokens[:, None], pos2,
                 lambda pool, l, k, v: pool.write_tokens(
                     l, k, v, tables, pos2),
-                tables, positions, lambda x, head: head(x)[:, 0])
+                lambda pool, l, q: pool.attend(
+                    q, l, tables, positions, self._attn_kernel),
+                lambda x, head: head(x)[:, 0])
             with _scope("sample"):
                 step_keys = jax.vmap(jax.random.fold_in)(
                     jax.random.wrap_key_data(keys, impl=_KEY_IMPL),
@@ -2041,7 +2044,9 @@ class DecodeEngine:
                 pool, tokens, pos2,
                 lambda pool, l, k, v: pool.write_tokens(
                     l, k, v, tables, pos2),
-                tables, positions, lambda x, head: head(x))
+                lambda pool, l, q: pool.attend(
+                    q, l, tables, positions, self._attn_kernel),
+                lambda x, head: head(x))
             with _scope("sample"):
                 step_keys = jax.vmap(jax.vmap(
                     jax.random.fold_in, in_axes=(None, 0)))(

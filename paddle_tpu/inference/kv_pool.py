@@ -4,7 +4,7 @@
 D]`` at the store dtype, plus one absmax scale slab ``[L, num_pages, Hkv,
 page_size]`` each when the store is int8. Everything that depends on that
 format lives here: allocation and the mp commitment, the two writes, the
-read, and the page handoff between engines. ``DecodeEngine`` carries the
+two reads, and the page handoff between engines. ``DecodeEngine`` carries the
 pool through its compiled programs as ONE (donated) pytree argument and
 never indexes its arrays; a model whose cache has another format (a latent
 cache, a window) writes its pool class beside this one.
@@ -202,6 +202,32 @@ class KVPool:
             v = dequantize_absmax(v, vs[layer][..., None])
         return F.paged_attention(q, k, v, tables, positions,
                                  kernel="einsum")
+
+    def attend_block(self, q, layer, row, cached_len, kernel):
+        """One layer of a prompt tail's attention: q [1, TB, H, D] from
+        position ``cached_len`` on, over the pages of ``row`` [MP], the
+        tail's own (``write_block`` comes first) and the cached prefix's
+        alike. The fused path gathers THIS slot's pages of the layer into
+        contiguous keys ``[Hkv, MP * P, D]`` at the stored dtype (an
+        eighth of a layer's pool at the serving cell's 8 slots; int8 with
+        its scale rows, dequantised inside the kernel) and runs the
+        blocked prefill kernel over them, which reads key blocks the MXU
+        can fill and stops at each row block's causal horizon; the pool
+        keeps its one layout. The einsum oracle is ``attend``'s, on a
+        table of one slot."""
+        if kernel != "pallas":
+            return self.attend(q, layer, row[None],
+                               jnp.reshape(cached_len, (1,)), kernel)
+
+        def keys(cache):  # [L, N, Hkv, P, ...] -> [Hkv, MP * P, ...]
+            g = jnp.swapaxes(cache[layer, row], 0, 1)
+            return g.reshape(g.shape[0], -1, *g.shape[3:])
+
+        ks, vs = self.k_scales, self.v_scales
+        return F.prefill_attention(
+            q, keys(self.k), keys(self.v), cached_len,
+            k_scales=None if ks is None else keys(ks),
+            v_scales=None if vs is None else keys(vs))
 
     # -- the handoff between engines (eager) --------------------------------
 

@@ -10,6 +10,7 @@ from .attention import (  # noqa: F401
     decode_attention,
     flash_attention,
     paged_attention,
+    prefill_attention,
     resolve_attn_kernel,
     scaled_dot_product_attention,
     sparse_attention,

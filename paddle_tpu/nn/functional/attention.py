@@ -347,9 +347,13 @@ def paged_attention(query, pool_k, pool_v, page_table, start_position,
     docs/SERVING.md §paged cache). ``query`` [S, T, H, D]; ``pool_k/v``
     [N, Hkv, page_size, D]; ``page_table`` [S, max_pages] int32;
     ``start_position`` [S] int32 (global position of each slot's first
-    query row). Serves the decode step (T=1), the speculative verify
-    step (T=k+1), and the prefix-cached tail prefill (S=1, T=bucket)
-    with ONE op.
+    query row). Serves the decode step (T=1) and the speculative verify
+    step (T=k+1) of the serving engine; it is correct at any T, and the
+    prefix-cached tail prefill (S=1, T=bucket) ran through it until PR 34.
+    On the fused path the prefill now gathers its slot's pages and runs
+    :func:`prefill_attention` (MXU-sized key blocks, the causal horizon
+    skipped); on the einsum path, and under an mp-sharded pool, it still
+    runs this op.
 
     With ``layer`` (an int or int32 scalar) ``pool_k/v`` are the engine's
     stacked [L, N, Hkv, page_size, D] pools: the fused kernel reads that
@@ -376,6 +380,35 @@ def paged_attention(query, pool_k, pool_v, page_table, start_position,
         pool_k, pool_v = pool_k[layer], pool_v[layer]
     return _paged_attention_op(query, pool_k, pool_v, k_scales, v_scales,
                                page_table, start_position, scale)
+
+
+@defop(amp="white", name="prefill_attention_pallas_op")
+def _prefill_attention_pallas_op(q, k, v, k_scales, v_scales, cached_len,
+                                 scale):
+    from ...ops.pallas import prefill_attention as _pf
+
+    out = _pf.prefill_attention(
+        q[0], k, v, cached_len, scale=scale, k_scales=k_scales,
+        v_scales=v_scales)
+    return out[None].astype(q.dtype)
+
+
+def prefill_attention(query, keys, values, cached_len, scale=None,
+                      k_scales=None, v_scales=None, name=None):
+    """Causal attention of ONE sequence's new rows over its contiguous
+    keys: what the serving engine's tail prefill runs on its fused path
+    (docs/SERVING.md §paged cache), in row blocks against key blocks of
+    128 keys or more (ops/pallas/prefill_attention.py). ``query``
+    [1, T, H, D], row t at position ``cached_len + t``; ``keys`` /
+    ``values`` [Hkv, K, D] at their stored dtype, positions 0 .. K - 1 of
+    the sequence (``KVPool.attend_block`` gathers a slot's pages into
+    them); ``cached_len`` an int32 scalar; ``k_scales``/``v_scales``
+    ([Hkv, K] f32, both or neither) mark int8 absmax-quantized keys.
+    :func:`paged_attention` with ``kernel="einsum"`` on the same pages is
+    its oracle: greedy argmax equal, raw outputs within f32 tolerance
+    (tests/test_pallas_attention.py)."""
+    return _prefill_attention_pallas_op(
+        query, keys, values, k_scales, v_scales, cached_len, scale)
 
 
 @defop(name="sparse_attention_op")

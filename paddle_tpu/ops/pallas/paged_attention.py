@@ -23,15 +23,19 @@ whose grid follows the KV that is live, not the page table's capacity:
   * fused int8 dequant: when per-[page, head] absmax scales are passed,
     ``int8 * scale`` happens on the VMEM-resident page right before the
     QK / PV dots — the f32 pool is never materialized;
-  * decode (T=1), speculative verify (T=k+1) and the tail prefill (S=1,
-    T=bucket) are the SAME kernel: all T positions score in one pass,
-    each row masked at its own causal horizon ``start_position + t``;
+  * decode (T=1) and speculative verify (T=k+1) are the SAME kernel: all
+    T positions score in one pass, each row masked at its own causal
+    horizon ``start_position + t``. It is correct at any T, and until PR
+    34 the engine's tail prefill (S=1, T=bucket) ran it too, at under 2%
+    of the MXU: a prefill wants many rows against long contiguous key
+    blocks, the opposite of this grid, and now gathers its slot's pages
+    and runs ``prefill_attention.py``;
   * few, full grid steps: a page of the ``[N, Hkv, P, D]`` pool is
     contiguous over its kv heads, so one step fetches a block of heads of
     a page in one DMA and batches its two products over them. The block
     is as many heads as the call's shapes leave room for in VMEM
-    (``_heads_per_step``): all of them in decode and verify, a few in a
-    prefill;
+    (``_heads_per_step``): all of them in decode and verify, a few at a
+    prefill's row count;
   * the engine's stacked ``[L, N, Hkv, P, D]`` pool is read in place: the
     layer index travels as a scalar-prefetch operand into the index maps,
     so no caller slices a layer out (a slice is a copy of 1/L of the pool
@@ -231,8 +235,9 @@ def paged_attention(
 
     Args:
         q: ``[S, T, H, D]`` queries — T=1 for plain decode, T=k+1 for
-            speculative verify (all draft positions scored in one pass),
-            T=bucket with S=1 for the prefix-cached tail prefill.
+            speculative verify (all draft positions scored in one pass);
+            any T is correct (T=bucket with S=1 was the tail prefill's
+            call until it got ``prefill_attention``).
         k_pool, v_pool: ``[N, Hkv, P, D]`` page pools in their STORED
             dtype (f32, bf16, or int8 when scales are passed), or the
             engine's stacked ``[L, N, Hkv, P, D]`` pools with ``layer``.

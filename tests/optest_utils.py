@@ -83,6 +83,10 @@ SKIP = {
                                  "(serving decode runs under no-grad); "
                                  "forward parity vs the einsum oracle in "
                                  "test_pallas_attention.py",
+    "prefill_attention_pallas_op": "Pallas prefill kernel: no VJP by design "
+                                   "(a serving prefill runs under no-grad); "
+                                   "forward parity vs the einsum oracle in "
+                                   "test_pallas_attention.py",
     # --- higher-order callables, not tensor ops -------------------------
     "recompute": "takes a callable (checkpoint wrapper), not a tensor op",
     "spmd_pipeline": "pipeline schedule driver (callable + mesh), covered "

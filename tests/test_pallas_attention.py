@@ -1,5 +1,6 @@
-"""Pallas decode kernel plane: fused paged attention vs the einsum
-oracle (docs/SERVING.md §kernel plane).
+"""Pallas serving kernel plane: fused paged attention (decode, verify) and
+the tail prefill's blocked attention vs the einsum oracle
+(docs/SERVING.md §kernel plane).
 
 The fused kernel (paddle_tpu/ops/pallas/paged_attention.py) streams KV
 pages at their stored dtype — int8 dequant fused against per-page absmax
@@ -24,7 +25,9 @@ from paddle_tpu.framework.op import raw
 from paddle_tpu.inference.engine import (DecodeEngine, EngineConfig,
                                          SamplingParams)
 from paddle_tpu.nn.functional import attention as attn_mod
+from paddle_tpu.inference.kv_pool import KVPool
 from paddle_tpu.ops.pallas import paged_attention as pa_kernel
+from paddle_tpu.ops.pallas import prefill_attention as pf_kernel
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
 VOCAB = 61
@@ -307,6 +310,152 @@ def test_scales_must_come_in_pairs():
 
 
 # ---------------------------------------------------------------------------
+# the tail prefill's block read: blocked kernel vs einsum oracle
+# ---------------------------------------------------------------------------
+
+#: The prefill's cases, as the paged ones above: ``t`` rows (the bucket)
+#: whose last sits at context ``ctx`` - 1, over a table of ``max_pages``
+#: pages of 8. ``q`` / ``pool``: the dtypes the two arrive in (bf16 with
+#: bf16 is the pair that enters the first product unwidened); ``blocks``:
+#: the largest (row, key) blocks, made small so that a test's few rows are
+#: several blocks.
+BLOCK_READ_CASES = {
+    "nothing_cached": dict(t=32, ctx=32, max_pages=8),
+    "128_cached": dict(t=32, ctx=160, max_pages=24),
+    "short_of_its_bucket": dict(t=32, ctx=40, max_pages=12),
+    "cached_len_plus_bucket_at_max_length": dict(t=32, ctx=96, max_pages=12),
+    "gqa": dict(t=32, ctx=48, max_pages=8, group=4),
+    "int8": dict(t=32, ctx=160, max_pages=24, int8=True),
+    "int8_gqa_bf16_queries": dict(
+        t=32, ctx=72, max_pages=12, group=2, int8=True, q="bfloat16"),
+    "bf16_queries_on_a_bf16_pool": dict(
+        t=32, ctx=160, max_pages=24, q="bfloat16", pool="bfloat16"),
+    "float32_queries_on_a_bf16_pool": dict(
+        t=32, ctx=48, max_pages=8, pool="bfloat16"),
+    "bf16_queries_on_a_float32_pool": dict(
+        t=32, ctx=48, max_pages=8, q="bfloat16"),
+    "rows_and_keys_that_no_block_divides": dict(
+        t=40, ctx=90, max_pages=13, group=3),
+    "four_row_blocks_three_key_blocks": dict(
+        t=64, ctx=300, max_pages=40, group=2, blocks=(32, 128)),
+    "row_blocks_of_gqa_rows_straddle_positions": dict(
+        t=24, ctx=280, max_pages=40, group=3, blocks=(16, 128)),
+    "every_key_block_live_for_the_last_row_block_only": dict(
+        t=256, ctx=256, max_pages=32, hkv=1, blocks=(64, 128)),
+    "int8_several_blocks": dict(
+        t=48, ctx=200, max_pages=40, int8=True, blocks=(16, 128)),
+}
+
+
+def _slot_keys(pool, row):
+    """``KVPool.attend_block``'s gather, in numpy: one slot's pages
+    [N, Hkv, P, ...] -> contiguous keys [Hkv, MP * P, ...]."""
+    g = np.swapaxes(pool[row], 0, 1)
+    return g.reshape(g.shape[0], -1, *g.shape[3:])
+
+
+@pytest.mark.parametrize("name", list(BLOCK_READ_CASES))
+def test_block_read_matches_einsum_oracle(name, monkeypatch):
+    spec = dict(BLOCK_READ_CASES[name])
+    blocks = spec.pop("blocks", None)
+    qdt, pdt = spec.pop("q", "float32"), spec.pop("pool", "float32")
+    ctx = spec.pop("ctx")
+    spec = dict(dict(hkv=2, group=1, page_size=8, int8=False), **spec)
+    q, kp, vp, ks, vs, table, start = _case(
+        np.random.default_rng(len(name)), ctx=[ctx], **spec)
+    if blocks:
+        monkeypatch.setattr(pf_kernel, "_BLOCK_Q", blocks[0])
+        monkeypatch.setattr(pf_kernel, "_BLOCK_K", blocks[1])
+        assert pf_kernel._block_sizes(
+            spec["t"] * spec["group"], table.shape[1] * 8) == blocks
+        assert table.shape[1] * 8 > blocks[1]
+    q = jnp.asarray(q, qdt)
+    if not spec["int8"]:
+        kp, vp = jnp.asarray(kp, pdt), jnp.asarray(vp, pdt)
+    # the oracle on the values the kernel is handed, widened
+    ref = _run("einsum", q.astype(jnp.float32), kp, vp, ks, vs, table, start)
+    kw = {} if ks is None else dict(
+        k_scales=jnp.asarray(_slot_keys(ks, table[0])),
+        v_scales=jnp.asarray(_slot_keys(vs, table[0])))
+    got = np.asarray(pf_kernel.prefill_attention(
+        q[0], jnp.asarray(_slot_keys(np.asarray(kp), table[0])),
+        jnp.asarray(_slot_keys(np.asarray(vp), table[0])),
+        jnp.int32(start[0]), **kw))[None]
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=1e-4)
+    np.testing.assert_array_equal(got.argmax(-1), ref.argmax(-1))
+    # the paged kernel, which the prefill ran until PR 34, agrees too
+    paged = _run("pallas", q.astype(jnp.float32), kp, vp, ks, vs, table,
+                 start)
+    np.testing.assert_allclose(got, paged, atol=2e-5, rtol=1e-4)
+
+
+def test_block_read_skips_key_blocks_past_the_causal_horizon(monkeypatch):
+    """A key block wholly past its row block's horizon is neither
+    multiplied (NaNs there change nothing; masked to p = 0 they would
+    still poison the second product) nor fetched (the index map stands
+    still on the last live block)."""
+    monkeypatch.setattr(pf_kernel, "_BLOCK_K", 128)
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((128, 2, 16)), jnp.float32)
+    k, v = (rng.standard_normal((2, 512, 16)).astype(np.float32)
+            for _ in range(2))
+    clean = np.asarray(pf_kernel.prefill_attention(
+        q, jnp.asarray(k), jnp.asarray(v), jnp.int32(100)))
+    # the last row sits at position 227, in key block 1 of 4
+    assert int(pf_kernel._last_key_block(jnp.int32(100), 0, 128, 128, 1,
+                                         4)) == 1
+    k[:, 256:], v[:, 256:] = np.nan, np.nan
+    dirty = np.asarray(pf_kernel.prefill_attention(
+        q, jnp.asarray(k), jnp.asarray(v), jnp.int32(100)))
+    assert np.isfinite(clean).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("rows,keys,want", [
+    (1024, 2048, (512, 1024)),  # the serving cell's buckets
+    (512, 2048, (512, 1024)),
+    (128, 2048, (128, 1024)),
+    (2048, 2048, (512, 1024)),  # GQA 32/8 at bucket 512
+    (40, 104, (48, 128)),       # keys padded to the lane width
+    (96, 1536, (96, 512)),
+    (96, 768, (96, 256)),
+    (8, 1152, (16, 128)),
+])
+def test_block_sizes_follow_the_calls_shapes(rows, keys, want):
+    assert pf_kernel._block_sizes(rows, keys) == want
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16", "int8"])
+def test_pool_block_read_gathers_its_slot_and_its_layer(kv_dtype):
+    """``KVPool.attend_block`` end to end on a stacked pool: write a
+    prefix and a tail through ``write_block``, read the tail back on both
+    kernels. Other layers and other slots' pages hold other values, so a
+    wrong gather cannot pass."""
+    rng = np.random.default_rng(3)
+    layers, hkv, p, d, mp = 3, 2, 8, 16, 12
+    pool = KVPool.zeros(layers, 1 + 2 * mp, hkv, p, d, kv_dtype)
+    row = np.zeros(mp, np.int32)
+    row[:7] = rng.permutation(np.arange(1, 1 + 2 * mp))[:7]
+    row = jnp.asarray(row)
+    for layer in range(layers):
+        for at, n in ((0, 16), (16, 40)):  # 2 cached pages, a tail of 40
+            k, v = (jnp.asarray(rng.standard_normal((1, n, hkv, d)),
+                                jnp.float32) for _ in range(2))
+            pool = pool.write_block(layer, k, v, row, jnp.int32(at),
+                                    jnp.int32(at + n - 3))
+    q = jnp.asarray(rng.standard_normal((1, 40, 2 * hkv, d)), jnp.float32)
+    got, ref = (np.asarray(raw(pool.attend_block(
+        q, 1, row, jnp.int32(16), kernel))) for kernel in (
+        "pallas", "einsum"))
+    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=1e-4)
+    np.testing.assert_array_equal(got.argmax(-1), ref.argmax(-1))
+    other = np.asarray(raw(pool.attend_block(
+        q, 2, row, jnp.int32(16), "pallas")))
+    assert not np.allclose(other, got, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
 # mask fill constant + kernel selection knob
 # ---------------------------------------------------------------------------
 
@@ -429,6 +578,35 @@ def test_engine_greedy_bit_equal_pallas_vs_einsum(model):
         attn_kernel="pallas", **cfg)), prompts, max_new=8)
     for a, b in zip(ref, got):
         np.testing.assert_array_equal(a, b)
+
+
+def test_engine_greedy_bit_equal_over_a_cached_prefix_and_compile_gate(
+        model):
+    """The prefill's block read behind a cached prefix (``cached_len`` 16
+    and 24 on the later prompts, tails in two more buckets): greedy streams
+    bit-equal to the einsum engine's, and the program count is still the
+    prefill buckets used + ONE decode + ONE verify."""
+    rng = np.random.default_rng(9)
+    shared = _prompt(rng, 26)
+    prompts = [shared, np.concatenate([shared[:16], _prompt(rng, 3)]),
+               np.concatenate([shared[:24], _prompt(rng, 35)])]
+    cfg = dict(num_slots=2, max_length=96, page_size=8, prefix_cache=True,
+               speculate_k=2, spec_adaptive=False,
+               prompt_buckets=(8, 32, 64))
+    streams, engines = [], []
+    for kernel in ("einsum", "pallas"):
+        eng = DecodeEngine(model, EngineConfig(attn_kernel=kernel, **cfg))
+        # one at a time: a later prompt finds the earlier one's pages
+        streams.append([_drain(eng, [p], max_new=6)[0] for p in prompts])
+        engines.append(eng)
+    for a, b in zip(*streams):
+        np.testing.assert_array_equal(a, b)
+    for eng in engines:
+        st = eng.stats()
+        assert st["prefix_hit_tokens"] == 16 + 24
+        buckets_used = [n for n in st["compiled"] if n.startswith("prefill_")]
+        assert len(buckets_used) == 3, st["compiled"]
+        assert st["compile_count"] == len(buckets_used) + 2, st["compiled"]
 
 
 @pytest.mark.slow

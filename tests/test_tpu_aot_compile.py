@@ -3,8 +3,8 @@
 Interpret mode (every other kernel test here) cannot see what Mosaic
 refuses: a slice off the tiling, more VMEM than a kernel may hold. The
 TPU compiler is installed in the CPU sandbox and compiles for a chip that
-is described, not attached, so these cases guard the flash, paged and
-grouped-matmul kernels at the shapes `chip_smoke.py` and the roadmap's
+is described, not attached, so these cases guard the flash, paged, prefill
+and grouped-matmul kernels at the shapes `chip_smoke.py` and the roadmap's
 cells run them at — at no chip time. Nothing executes: a compile that
 passes says nothing about results or speed. The compiled text does say
 what XLA:TPU made of a program, so the serving engine's own decode, verify
@@ -27,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.ops.pallas import flash_attention as flash_mod
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
 from paddle_tpu.ops.pallas.paged_attention import paged_attention
+from paddle_tpu.ops.pallas.prefill_attention import prefill_attention
 
 
 @pytest.fixture(scope="module")
@@ -158,6 +159,38 @@ def test_paged_attention_gqa_compiles(one_chip, call):
         "bf16", *PAGED_CALLS[call], heads=32, kv_heads=8))
 
 
+# -- prefill attention (the serving path's tail prefill, since PR 34) --------
+
+def _prefill_shapes(kv, t, heads=16, kv_heads=16):
+    d, keys = 128, 2048
+    pool = ((kv_heads, keys, d), jnp.int8 if kv == "int8" else jnp.bfloat16)
+    shapes = [((t, heads, d), jnp.bfloat16), pool, pool, ((), jnp.int32)]
+    if kv == "int8":
+        shapes += [((kv_heads, keys), jnp.float32)] * 2
+    return shapes
+
+
+def _prefill(q, k, v, cached_len, ks=None, vs=None):
+    return prefill_attention(q, k, v, cached_len, k_scales=ks, v_scales=vs,
+                             interpret=False)
+
+
+@pytest.mark.parametrize("bucket", [128, 512, 1024])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_prefill_attention_compiles(one_chip, kv, bucket):
+    """One slot's gathered keys (8 slots x 2048: 2048 keys) under each
+    bucket's rows. Lenient on VMEM like every lone compile: the engine's
+    own prefill programs are compiled further down."""
+    _compile(_prefill, one_chip, *_prefill_shapes(kv, bucket))
+
+
+def test_prefill_attention_gqa_compiles(one_chip):
+    """Llama's layout at bucket 512: a kv head's four query heads ride in
+    the row dimension, 2048 rows in four row blocks."""
+    _compile(_prefill, one_chip, *_prefill_shapes(
+        "bf16", 512, heads=32, kv_heads=8))
+
+
 # -- grouped matmul (MoE expert FFN at OLMoE widths, ROADMAP R1) ------------
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
@@ -180,6 +213,7 @@ def test_grouped_matmul_compiles(one_chip, n, backward):
 
 KERNEL_NAMES = {
     "paged_attention": "paged",
+    "prefill_attention": "prefill",
     "flash_attention_fwd": "flash",
     "flash_attention_bwd_dq": "flash",
     "flash_attention_bwd_dkv": "flash",
@@ -191,8 +225,8 @@ KERNEL_NAMES = {
 @pytest.fixture(scope="module")
 def named_programs(one_chip):
     """The compiled text of one forward+backward flash program, one paged
-    decode program and one forward+backward grouped matmul, compiled when
-    the first case asks."""
+    decode program, one bucket-1024 prefill call and one forward+backward
+    grouped matmul, compiled when the first case asks."""
     texts = {}
 
     def flash(q, k, v):
@@ -209,6 +243,7 @@ def named_programs(one_chip):
     programs = {
         "flash": (flash, (qkv, qkv, qkv)),
         "paged": (_paged, _paged_shapes("bf16", *PAGED_CALLS["decode"])),
+        "prefill": (_prefill, _prefill_shapes("bf16", 1024)),
         "gmm": (gmm, (((8192, 2048), jnp.bfloat16),
                       ((64, 2048, 1024), jnp.bfloat16),
                       ((64,), jnp.int32))),
@@ -239,13 +274,11 @@ def test_kernel_is_named_in_the_compiled_program(named_programs, kernel):
         re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
 
 
-def test_the_decode_kernels_line_is_what_the_benchmark_looks_for(
-        named_programs):
-    """``paged_attn_roofline`` and ``paged_attn_time_pct`` find the decode
-    kernel's device events by their HLO line: ONE custom call whose single
-    result is float32 with the slot axis first. A tuple result, a bf16
-    result or another leading axis silences both. The pattern is read from
-    the benchmark's own file, ``$num_slots`` filled in as its reader does."""
+def _decode_kernel_pattern(num_slots):
+    """The pattern by which ``paged_attn_roofline`` and
+    ``paged_attn_time_pct`` find the DECODE kernel's device events, read
+    from the benchmark's own file, ``$num_slots`` filled in as its reader
+    does."""
     import json
     import os
 
@@ -254,12 +287,34 @@ def test_the_decode_kernels_line_is_what_the_benchmark_looks_for(
                            "paged_attn_roofline.json")) as f:
         pattern = json.load(f)["args"]["pattern"]
     assert "$num_slots" in pattern
-    rx = re.compile(pattern.replace("$num_slots", "8"))
+    return re.compile(pattern.replace("$num_slots", str(num_slots)))
+
+
+def test_the_decode_kernels_line_is_what_the_benchmark_looks_for(
+        named_programs):
+    """``paged_attn_roofline`` and ``paged_attn_time_pct`` find the decode
+    kernel's device events by their HLO line: ONE custom call whose single
+    result is float32 with the slot axis first. A tuple result, a bf16
+    result or another leading axis silences both. The pattern is read from
+    the benchmark's own file, ``$num_slots`` filled in as its reader does."""
+    rx = _decode_kernel_pattern(8)
     lines = [ln for ln in named_programs("paged").splitlines()
              if "tpu_custom_call" in ln and " custom-call(" in ln]
     assert len(lines) == 1, lines
     assert rx.search(lines[0]), lines[0]
     assert re.search(r"%paged_attention(\.\d+)? = f32\[8,", lines[0])
+
+
+def test_the_prefill_kernels_line_is_not_what_the_decode_roofline_counts(
+        named_programs):
+    """The prefill's call must stay out of the decode kernel's roofline
+    share: its result leads with 1, never with the cell's 8 slots."""
+    lines = [ln for ln in named_programs("prefill").splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(lines) == 1, lines
+    assert re.search(r"%prefill_attention(\.\d+)? = f32\[1,16,1024,128\]",
+                     lines[0]), lines[0]
+    assert not _decode_kernel_pattern(8).search(lines[0]), lines[0]
 
 
 # -- the engine's programs leave the KV pool where and as it is (PR 30) ------
@@ -362,6 +417,18 @@ def _pool_sized_traffic(text, big):
     return found
 
 
+#: which attention kernel each kind of program calls, and no other: decode
+#: and verify the paged one, a prefill the blocked one over its slot's
+#: gathered pages (PR 34)
+_ENGINE_KERNEL = {"decode": "paged_attention", "verify": "paged_attention",
+                  "prefill": "prefill_attention"}
+
+
+def _kernels_in(text):
+    return set(re.findall(
+        r"%(\w+?)[.\d]* = f32\[[^\n]*tpu_custom_call", text))
+
+
 @pytest.mark.parametrize("program", ENGINE_PROGRAMS)
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_engine_program_keeps_the_kv_pool_in_one_layout(
@@ -382,12 +449,32 @@ def test_engine_program_keeps_the_kv_pool_in_one_layout(
 def test_engine_program_moves_nothing_as_large_as_a_layers_pool(
         engine_programs, kv, program):
     """(b) no copy, slice, transpose or fusion whose result is as large
-    as ONE layer's pool, other than a write in place: the kernel reads its
-    layer through its index maps, the token write is an in-place
-    ``dynamic-update-slice``, the prefill's page write a scatter."""
+    as ONE layer's pool, other than a write in place: the paged kernel
+    reads its layer through its index maps, a prefill gathers its ONE
+    slot's pages of a layer (an eighth of it) for the blocked kernel, the
+    token write is an in-place ``dynamic-update-slice``, the prefill's
+    page write a scatter."""
     text, (_, n, hkv, p, d) = engine_programs(kv, program)
-    assert re.search(r"%paged_attention[.\d]* = f32\[", text)
+    assert _kernels_in(text) == {_ENGINE_KERNEL[program.split("_")[0]]}
     assert _pool_sized_traffic(text, n * hkv * p * d) == []
     wrote = "scatter" if program.startswith("prefill") else (
         "dynamic-update-slice")
     assert re.search(rf" {wrote}\(", text)
+
+
+@pytest.mark.parametrize("bucket", [128, 512, 1024])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_engine_prefill_program_compiles_with_the_blocked_kernel_inside(
+        engine_programs, kv, bucket):
+    """Each of the cell's three prefill programs compiles for the described
+    v5e with ``prefill_attention`` INSIDE it, where its operands come from
+    HBM and are double-buffered: what a lone compile of the kernel cannot
+    show of VMEM (PR 27). The slot's gathered pages are the largest array
+    the attention adds, and the pool keeps its layout beside the gather."""
+    text, pool_shape = engine_programs(kv, f"prefill_b{bucket}")
+    assert _kernels_in(text) == {"prefill_attention"}
+    assert re.search(rf"%prefill_attention[.\d]* = f32\[1,16,{bucket},128\]",
+                     text)
+    dims = ",".join(str(n) for n in pool_shape)
+    assert set(re.findall(rf"\w+\[{dims}\]\{{([\d,]*)", text)) == {
+        "4,3,2,1,0"}
