@@ -503,6 +503,13 @@ class DecodeEngine:
             raise ValueError(
                 f"max_length={cfg.max_length} exceeds the model's "
                 f"max_positions={ad.max_positions}")
+        #: the two depths, stated apart: ``num_layers`` layers of weights
+        #: run ``loops`` times (1 unless the adapter says otherwise), each
+        #: run ended by ``close_loop``; a (loop, layer) has its own cache
+        #: entry, so the pool is ``cache_layers`` deep
+        self._loops = int(getattr(ad, "loops", 1))
+        self._close_loop = getattr(ad, "close_loop", None)
+        self.cache_layers = self._loops * ad.num_layers
         self.buckets = cfg.resolved_buckets()
         self._mp = cfg.max_pages
         self._num_pages = cfg.resolved_num_pages()
@@ -558,7 +565,7 @@ class DecodeEngine:
         #: ONCE on a mesh, like the replicated model state below: a
         #: per-call device_put would re-place them every step
         self.kv = KVPool.zeros(
-            ad.num_layers, self._num_pages, ad.num_kv_heads, cfg.page_size,
+            self.cache_layers, self._num_pages, ad.num_kv_heads, cfg.page_size,
             ad.head_dim, cfg.kv_dtype, mesh=self._mesh)
         # einsum + int8 materializes both dequantized [N, Hkv, P, D] f32
         # pools per layer per step; the fused path never does — account
@@ -572,7 +579,7 @@ class DecodeEngine:
                 num_slots=cfg.num_slots, max_pages=self._mp,
                 kv_heads=ad.num_kv_heads, query_heads=ad.num_heads,
                 page_size=cfg.page_size, head_dim=ad.head_dim,
-                layers=ad.num_layers, kv_dtype=cfg.kv_dtype,
+                layers=self.cache_layers, kv_dtype=cfg.kv_dtype,
                 selected=self._attn_kernel)
         except Exception:  # noqa: BLE001 — pricing never gates serving
             pass
@@ -780,8 +787,9 @@ class DecodeEngine:
                 # an idle poll (serving/worker.py makes 200 a second) is no
                 # step: it leaves no tree
                 return self._step(None)
-            with _obs.span("eng_step",
-                           num_slots=self.config.num_slots) as sp:
+            with _obs.span("eng_step", num_slots=self.config.num_slots,
+                           loops=self._loops,
+                           cache_layers=self.cache_layers) as sp:
                 busy = self._step(sp)
                 if sp:
                     sp.attrs.update(
@@ -1208,6 +1216,9 @@ class DecodeEngine:
             "admission_waits": self.admission_waits,
             "admission_wait_s": self.admission_wait_s,
             "attn_kernel": self._attn_kernel,
+            "loops": self._loops,
+            "cache_layers": self.cache_layers,
+            "kv_bytes_per_token": self.kv.bytes_per_token,
             "weight_epoch": int(self._epoch),
             "pinned_epochs": sorted(self._epoch_vals),
         }
@@ -1948,25 +1959,47 @@ class DecodeEngine:
         prefill, its paged read in decode and verify). ``read(hidden,
         head)`` gives the logits of the rows the program wants. Each part
         under its ``named_scope``, which the compiled text keeps
-        (``profiler.op_scopes``): ``embed``, ``kv_write``, ``attend`` and
-        ``lm_head`` here, ``sample`` in the builders, ``qkv``, ``attn_out``
-        and ``mlp`` opened by the model. Returns the pool and f32 logits."""
+        (``profiler.op_scopes``): ``embed``, ``kv_write``, ``attend``,
+        ``loop_close`` and ``lm_head`` here, ``sample`` in the builders,
+        ``qkv``, ``attn_out`` and ``mlp`` opened by the model.
+
+        An adapter that states ``loops`` > 1 has its ``num_layers`` layers
+        run that many times over the same weights, ``close_loop`` ending
+        each run: ONE compiled loop over ``u`` with the pool in its carry,
+        whose body is the model's layers at cache entries ``u * num_layers
+        + l`` (a traced index: the pool's reads and writes take it as they
+        take an int). Without ``loops`` the layers are traced once, at
+        entries ``l``, and no loop construct reaches the program. Returns
+        the pool and f32 logits."""
         ad = self.adapter
 
-        def attend(l, q, k, v):
-            nonlocal pool
-            with _scope("kv_write"):
-                pool = write(pool, l, _shard_kv_heads(raw(k)),
-                             _shard_kv_heads(raw(v)))
-            with _scope("attend"):
-                return attend_pages(pool, l, q)
+        def run_layers(u, carry):
+            pool, x = carry[0], Tensor(carry[1])
+
+            def attend(entry, q, k, v):
+                nonlocal pool
+                with _scope("kv_write"):
+                    pool = write(pool, entry, _shard_kv_heads(raw(k)),
+                                 _shard_kv_heads(raw(v)))
+                with _scope("attend"):
+                    return attend_pages(pool, entry, q)
+
+            for l in range(ad.num_layers):
+                x = ad.layer(l, x, positions, functools.partial(
+                    attend, u * ad.num_layers + l))
+            if self._close_loop is not None:
+                with _scope("loop_close"):
+                    x = self._close_loop(x)
+            return pool, raw(x)
 
         with _scope("embed"):
-            x = ad.embed(Tensor(ids), positions)
-        for l in range(ad.num_layers):
-            x = ad.layer(l, x, positions, functools.partial(attend, l))
+            x = raw(ad.embed(Tensor(ids), positions))
+        if self._loops == 1:
+            pool, x = run_layers(0, (pool, x))
+        else:
+            pool, x = jax.lax.fori_loop(0, self._loops, run_layers, (pool, x))
         with _scope("lm_head"):
-            logits = read(raw(x), lambda h: raw(ad.head(Tensor(h))))
+            logits = read(x, lambda h: raw(ad.head(Tensor(h))))
             return pool, logits.astype(jnp.float32)
 
     def _sample(self, logits, keys, temp, top_k, top_p, greedy):

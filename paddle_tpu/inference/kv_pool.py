@@ -105,11 +105,13 @@ class KVPool:
         return cls(*children)
 
     @classmethod
-    def zeros(cls, num_layers, num_pages, num_kv_heads, page_size, head_dim,
-              kv_dtype, mesh=None):
-        """An empty pool; on a ``mesh`` committed kv-head-sharded ONCE
-        (``P(None, None, "mp")``: GQA groups stay whole per shard)."""
-        shape = (num_layers, num_pages, num_kv_heads, page_size, head_dim)
+    def zeros(cls, cache_layers, num_pages, num_kv_heads, page_size,
+              head_dim, kv_dtype, mesh=None):
+        """An empty pool, ``cache_layers`` entries deep: one a layer, or one
+        a (loop, layer) where a model runs its layers more than once; on a
+        ``mesh`` committed kv-head-sharded ONCE (``P(None, None, "mp")``:
+        GQA groups stay whole per shard)."""
+        shape = (cache_layers, num_pages, num_kv_heads, page_size, head_dim)
         arrays = [jnp.zeros(shape, KV_DTYPES[kv_dtype]) for _ in "kv"]
         if kv_dtype == "int8":
             arrays += [jnp.ones(shape[:-1], jnp.float32) for _ in "kv"]
@@ -130,6 +132,15 @@ class KVPool:
     @property
     def kv_dtype(self) -> str:
         return next(n for n, d in KV_DTYPES.items() if self.k.dtype == d)
+
+    @property
+    def bytes_per_token(self) -> int:
+        """What one cached token holds over every entry, K and V and, for
+        an int8 store, their scales."""
+        l, _, hkv, _, d = self.shape
+        per_head = d * self.k.dtype.itemsize + (
+            0 if self.k_scales is None else self.k_scales.dtype.itemsize)
+        return 2 * l * hkv * per_head
 
     @property
     def dequantized_bytes(self) -> int:

@@ -219,9 +219,11 @@ class Layer:
         out = destination if destination is not None else collections.OrderedDict()
         for n, p in self.named_parameters(prefix=structured_name_prefix.rstrip("."), include_sublayers=include_sublayers):
             out[n] = p
+        # a buffer is non-persistable in the layer that registered it
+        transient = {id(l._buffers[n]) for l in self.sublayers(include_self=True)
+                     for n in l._non_persistable_buffer_names}
         for n, b in self.named_buffers(prefix=structured_name_prefix.rstrip("."), include_sublayers=include_sublayers):
-            leaf = n.rsplit(".", 1)[-1]
-            if leaf not in self._non_persistable_buffer_names:
+            if id(b) not in transient:
                 out[n] = b
         return out
 

@@ -528,7 +528,9 @@ SPANS = {
         "paddle_tpu/inference/engine.py",
         "Root of one DecodeEngine.step() that had something running or "
         "waiting (an idle poll leaves none): admissions, then one decode or "
-        "verify pass (attrs: num_slots, running and waiting after the "
+        "verify pass (attrs: num_slots; loops and cache_layers, how many "
+        "times a pass runs the model's layers and how deep the KV pool is, "
+        "loops x layers; running and waiting after the "
         "admissions, context_tokens live in the running slots, emitted = "
         "{rid: new tokens}; slot_steps and slot_capacity, the engine's "
         "running totals of slots advanced and of decode passes x num_slots; "

@@ -26,3 +26,9 @@ from .llama import (  # noqa: F401
     LlamaForCausalLM,
     LlamaModel,
 )
+from .ouro import (  # noqa: F401
+    OuroConfig,
+    OuroDecoderLayer,
+    OuroForCausalLM,
+    OuroModel,
+)

@@ -287,3 +287,57 @@ def test_pool_is_one_donated_argument_of_a_program(toy, kv_dtype, leaves):
     for i in range(leaves):
         arg = re.search(rf"%arg{state + i}: [^%]*", main).group(0)
         assert f"tf.aliasing_output = {i} : i32" in arg
+
+
+# -- (4) the two depths: a model without loops compiles no loop ---------------
+
+
+def _tiny_lm(kind):
+    from paddle_tpu.text import models
+
+    paddle.seed(7)
+    if kind == "toy":
+        return ParallelLM()
+    if kind == "gpt":
+        return models.GPTForCausalLM(models.GPTConfig(
+            vocab_size=VOCAB, hidden_size=HIDDEN, num_hidden_layers=LAYERS,
+            num_attention_heads=HEADS, intermediate_size=4 * HIDDEN,
+            max_position_embeddings=POSITIONS, hidden_dropout_prob=0.0,
+            attention_probs_dropout_prob=0.0))
+    if kind == "llama":
+        return models.LlamaForCausalLM(models.LlamaConfig(
+            vocab_size=VOCAB, hidden_size=HIDDEN, num_hidden_layers=LAYERS,
+            num_attention_heads=HEADS, max_position_embeddings=POSITIONS,
+            use_flash_attention=False))
+    return models.OuroForCausalLM(models.OuroConfig(
+        vocab_size=VOCAB, hidden_size=HIDDEN, intermediate_size=88,
+        num_hidden_layers=LAYERS, num_attention_heads=HEADS,
+        num_key_value_heads=HEADS, head_dim=HIDDEN // HEADS,
+        max_position_embeddings=POSITIONS, total_ut_steps=3))
+
+
+@pytest.mark.parametrize("kind,loops", [("gpt", 1), ("llama", 1), ("toy", 1),
+                                        ("ouro", 3)])
+def test_only_an_adapter_that_states_loops_compiles_a_loop(kind, loops):
+    """An adapter states its weight layers; one that runs them more than
+    once states ``loops`` and ``close_loop`` too, and the pool is ``loops x
+    num_layers`` entries deep. Without them ``_forward`` traces the layers
+    once and no loop construct reaches the decode program's text: GPT's and
+    Llama's programs are what they were. With them the loops are ONE
+    ``while`` whose carry holds the pool, closed under ``loop_close``."""
+    model = _tiny_lm(kind)
+    model.eval()
+    eng = DecodeEngine(model, EngineConfig(
+        num_slots=2, max_length=16, page_size=4, donate=True))
+    assert eng.stats()["loops"] == loops
+    assert eng.stats()["cache_layers"] == loops * LAYERS == eng.kv.shape[0]
+    lowered = eng._build_decode().lower(*eng._example_args("decode"))
+    text = lowered.as_text(debug_info=True)
+    # a loop over the layers is a ``while`` that carries the pool (the
+    # sampler's threefry rounds are whiles too, over a few scalars)
+    pool = "tensor<" + "x".join(str(n) for n in eng.kv.shape) + "x"
+    carrying = [ln for ln in text.splitlines()
+                if "stablehlo.while" in ln and pool in ln]
+    assert len(carrying) == (1 if loops > 1 else 0)
+    assert ("loop_close" in text) == (loops > 1)
+    assert text.count("tf.aliasing_output") == 2  # the pool is still donated

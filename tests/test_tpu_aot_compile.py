@@ -478,3 +478,88 @@ def test_engine_prefill_program_compiles_with_the_blocked_kernel_inside(
     dims = ",".join(str(n) for n in pool_shape)
     assert set(re.findall(rf"\w+\[{dims}\]\{{([\d,]*)", text)) == {
         "4,3,2,1,0"}
+
+
+# -- a looped model's engine programs (PR 35): the pool in a loop's carry ----
+#
+# A model that runs its layers more than once (text/models/ouro.py) has the
+# engine compile ONE loop over the runs with the pool in its carry: where
+# PR 30 found that a ``fori_loop`` over token writes made XLA:TPU pick
+# another layout for the pool. With the paged kernel in the body it does
+# not, and the compiled text says so.
+
+#: the looped cell's engine (Ouro-2.6B: 16 heads of 128, ffn 5632, 8 slots
+#: x 512, page 16) at 2 layers x 4 loops: a K pool of 8 entries, 135 MB
+LOOPED_PROGRAMS = ("decode", "prefill_b256")
+
+
+@pytest.fixture(scope="module")
+def looped_engine_programs(one_chip):
+    from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
+    from paddle_tpu.text.models import OuroConfig, OuroForCausalLM
+
+    made = {}
+
+    def text_of(program):
+        if program not in made:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                if "engine" not in made:
+                    model = OuroForCausalLM(OuroConfig(
+                        vocab_size=1024, num_hidden_layers=2,
+                        total_ut_steps=4, max_position_embeddings=1024,
+                    )).astype("bfloat16")
+                    model.eval()
+                    made["engine"] = DecodeEngine(model, EngineConfig(
+                        num_slots=8, max_length=512, page_size=16,
+                        kv_dtype="bf16", prompt_buckets=(64, 128, 256)))
+                eng = made["engine"]
+                assert eng.stats()["attn_kernel"] == "pallas" and eng._donate
+                assert eng.stats()["cache_layers"] == 8
+                made[program] = eng._jitted(program).lower(*jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        np.shape(a), a.dtype, sharding=one_chip),
+                    eng._example_args(program))).compile().as_text()
+        return made[program], made["engine"].kv.shape
+
+    return text_of
+
+
+@pytest.mark.parametrize("program", LOOPED_PROGRAMS)
+def test_looped_engine_program_keeps_the_pool_in_one_layout_in_its_carry(
+        looped_engine_programs, program):
+    """The loops are one ``while``; every array of the pool's shape, the
+    loop's carry among them, keeps the row-major layout it arrives in; the
+    kernel is inside; and nothing as large as ONE entry's pool is copied,
+    sliced or re-laid. (An entry's pool, 8.4 M elements, is smaller than an
+    MLP matrix here, 11.5 M: the weights' prefetches into VMEM, rank 2, are
+    not the pool's traffic.)"""
+    text, pool_shape = looped_engine_programs(program)
+    assert len(re.findall(r" while\(", text)) == 1
+    dims = ",".join(str(n) for n in pool_shape)
+    assert set(re.findall(rf"\w+\[{dims}\]\{{([\d,]*)", text)) == {
+        "4,3,2,1,0"}
+    assert _kernels_in(text) == {_ENGINE_KERNEL[program.split("_")[0]]}
+    big = int(np.prod(pool_shape[1:]))
+    moved = [ln for ln in _pool_sized_traffic(text, big)
+             if not ln.startswith("%while")  # the carry itself: in place
+             and any(len(d.split(",")) > 2 and _elements(f"x[{d}]") >= big
+                    for d in re.findall(r"\w+\[([\d,]*)\]", ln))]
+    assert moved == []
+    assert re.search(r" dynamic-update-slice\(" if program == "decode"
+                     else r" scatter\(", text)
+
+
+def test_the_looped_decode_kernels_line_is_what_the_benchmark_looks_for(
+        looped_engine_programs):
+    """Inside the loop's body the decode call is still ONE line a layer
+    that ``paged_attn_roofline.json``'s pattern (and
+    ``loop_paged_attn_roofline.json``'s, the same) finds: a float32 result
+    with the slot axis first."""
+    text, _ = looped_engine_programs("decode")
+    lines = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(lines) == 2  # one a weight layer, whatever the loops
+    for ln in lines:
+        assert _decode_kernel_pattern(8).search(ln), ln
+        assert re.search(r"%paged_attention(\.\d+)? = f32\[8,16,8,128\]", ln)
