@@ -647,6 +647,12 @@ class DecodeEngine:
         #: slot reads one): over ``kv_pages_capacity`` it is how far the
         #: paged kernel's work is below what the tables hold
         self.kv_pages_live = 0
+        #: page slots of the blocks that the paged kernel's walk takes for
+        #: those passes, ``ceil(live / ppb) * ppb`` a slot at the kernel's
+        #: own pages a block for the pass's shapes, never past the table:
+        #: ``kv_pages_live`` over it is how full the blocks run
+        self.kv_block_pages = 0
+        self._pages_per_block: Dict[int, int] = {}  # rows a slot -> ppb
         self.spec_proposed = 0
         self.spec_accepted = 0
         self._t_decode_ema = None
@@ -800,6 +806,7 @@ class DecodeEngine:
                         slot_capacity=(self.decode_steps
                                        * self.config.num_slots),
                         kv_pages_live=self.kv_pages_live,
+                        kv_block_pages=self.kv_block_pages,
                         kv_pages_capacity=self.kv_pages_capacity)
         finally:
             self._report = None
@@ -923,6 +930,17 @@ class DecodeEngine:
         live = np.minimum(
             (positions + (t - 1)) // self.config.page_size + 1, self._mp)
         self.kv_pages_live += int(live.sum())
+        ppb = self._pages_per_block.get(t)
+        if ppb is None:
+            from ..ops.pallas.paged_attention import block_shape
+
+            ad = self.adapter
+            ppb = self._pages_per_block[t] = block_shape(
+                t, ad.num_heads, ad.num_kv_heads, ad.head_dim,
+                self.config.page_size, self.kv.k.dtype.itemsize,
+                self.kv.k_scales is not None)[1]
+        self.kv_block_pages += int(np.minimum(
+            -(-live // ppb) * ppb, self._mp).sum())
 
     @property
     def kv_pages_capacity(self) -> int:
@@ -1197,6 +1215,7 @@ class DecodeEngine:
             "verify_steps": self.verify_steps,
             "slot_steps": self.slot_steps,
             "kv_pages_live": self.kv_pages_live,
+            "kv_block_pages": self.kv_block_pages,
             "kv_pages_capacity": self.kv_pages_capacity,
             "total_tokens": self.total_tokens,
             "prompt_tokens_total": self.prompt_tokens_total,

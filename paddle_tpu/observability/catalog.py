@@ -536,7 +536,9 @@ SPANS = {
         "running totals of slots advanced and of decode passes x num_slots; "
         "kv_pages_live and kv_pages_capacity, its running totals of the "
         "page slots those passes' attention had to read and of the page "
-        "slots their tables held)"),
+        "slots their tables held; kv_block_pages, the page slots of the "
+        "blocks that the paged kernel's walk takes for them, "
+        "ceil(live / pages a block) blocks a slot)"),
     "eng_admit": (
         "paddle_tpu/inference/engine.py",
         "One admission attempt, child of eng_step: page reservation, "

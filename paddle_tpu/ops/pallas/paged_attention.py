@@ -8,46 +8,62 @@ tensors plus a dense ``[S, Hkv, G, T, MP*P]`` logits tensor in HBM every
 step, and — under int8 KV — by a separate whole-pool dequant pass.
 
 This kernel fuses the whole per-slot pipeline into one Pallas program
-whose grid follows the KV that is live, not the page table's capacity:
+that walks the KV that is live, not the page table's capacity:
 
-  * page-table-aware gather: the K/V pool blocks are addressed through a
-    scalar-prefetched page table (``pltpu.PrefetchScalarGridSpec``), so
-    pages stream HBM→VMEM at their STORED dtype and the gathered f32
-    copies never exist;
+  * the grid is one step a slot (times the blocks of kv heads, where the
+    rows leave room for fewer than all of them: ``_heads_per_step``). The
+    pools stay in HBM (``memory_space=pl.ANY``) and the BODY walks the
+    slot's page slots up to its last live one, ``(start_position + T - 1)
+    // P``, in blocks of ``_pages_per_block`` pages (8 pages = 128 keys at
+    the serving cells' shapes; from the call's shapes alone, against the
+    same VMEM budget as the head block): an idle slot costs one block of
+    one page's copy, not the table's width, and no grid step is skipped
+    because none is made;
+  * page-table-aware gather by manual DMA: a page of the ``[N, Hkv, P,
+    D]`` pool is contiguous over its kv heads, so each live page slot of
+    a block is ONE ``pltpu.make_async_copy`` of ``hb`` heads of page
+    ``page_table[s, j]`` into its place in a ``[hb, ppb, P, D]`` VMEM
+    buffer, at the STORED dtype; the gathered f32 copies never exist.
+    Two buffers: the next block's copies (or the first block's of the
+    NEXT grid step) are started before this block's products;
   * GQA-native query folding: the G query heads sharing a kv head ride in
     the kernel's row dimension (``rows = T * G``) — kv heads are never
     replicated in HBM;
-  * online (streaming) softmax across the page grid dimension: running
-    max / denominator / accumulator live in VMEM scratch, so no
-    ``[.., MP*P]`` logits tensor is written to HBM;
-  * fused int8 dequant: when per-[page, head] absmax scales are passed,
-    ``int8 * scale`` happens on the VMEM-resident page right before the
-    QK / PV dots — the f32 pool is never materialized;
+  * online (streaming) softmax across the blocks: running max /
+    denominator / accumulator live in VMEM scratch, so no ``[.., MP*P]``
+    logits tensor is written to HBM; a block's logits are ``[hb, rows,
+    128]``, a full lane width, and each of its two products meets the MXU
+    with 128 keys. Accumulation, logits, softmax state and probabilities
+    are float32; bf16 queries meet a bf16 pool unwidened in the first
+    product (the same products exactly); into the second the float32
+    probabilities go as their three bf16 terms against V's bf16 values
+    (a bf16 or int8 pool; a float32 pool meets them in float32), which
+    is the float32 product in one pass over V and not the one bf16 term
+    that the MXU takes of a float32 operand by default;
+  * fused int8 dequant: when per-[page, head] absmax scales are passed, a
+    key's scale multiplies its logit and a value's scale its probability
+    on the way into the second product (one factor of a whole row of K or
+    V either way) — the f32 pool is never materialized, in HBM or VMEM;
   * decode (T=1) and speculative verify (T=k+1) are the SAME kernel: all
     T positions score in one pass, each row masked at its own causal
     horizon ``start_position + t``. It is correct at any T, and until PR
     34 the engine's tail prefill (S=1, T=bucket) ran it too, at under 2%
     of the MXU: a prefill wants many rows against long contiguous key
-    blocks, the opposite of this grid, and now gathers its slot's pages
-    and runs ``prefill_attention.py``;
-  * few, full grid steps: a page of the ``[N, Hkv, P, D]`` pool is
-    contiguous over its kv heads, so one step fetches a block of heads of
-    a page in one DMA and batches its two products over them. The block
-    is as many heads as the call's shapes leave room for in VMEM
-    (``_heads_per_step``): all of them in decode and verify, a few at a
-    prefill's row count;
+    blocks, and now gathers its slot's pages and runs
+    ``prefill_attention.py``;
   * the engine's stacked ``[L, N, Hkv, P, D]`` pool is read in place: the
-    layer index travels as a scalar-prefetch operand into the index maps,
-    so no caller slices a layer out (a slice is a copy of 1/L of the pool
-    before every call);
-  * no work past a slot's last live page ``(start_position + T - 1) //
-    P``: the index maps stand still there (a block whose index does not
-    change is not fetched again) and the body is skipped, so an idle slot
-    costs one page and not the table's width.
+    layer index travels as a scalar-prefetch operand to where a page's
+    copy starts, so no caller slices a layer out (a slice is a copy of
+    1/L of the pool before every call);
+  * page slots of a slot's last block past its last live page are not
+    fetched: their keys mask themselves by position, and their V rows (or
+    V's scales) are zeroed in the buffer, since ``0 x`` what VMEM held is
+    NaN for a NaN.
 
-The einsum op remains the bit-equality reference oracle: greedy argmax
-must agree everywhere (tests/test_pallas_attention.py), raw outputs agree
-to f32 tolerance (online vs dense softmax differ in ulps only).
+The einsum op remains the reference oracle: greedy argmax must agree
+everywhere (tests/test_pallas_attention.py), raw outputs agree to f32
+tolerance (online vs dense softmax differ in ulps only, and so do two
+walks that rescale at other block edges).
 
 Runs off-TPU via ``interpret=True`` (the default there), per the repo's
 robustness rule that every Pallas call site declares its interpret mode
@@ -89,30 +105,41 @@ def _scratch(shape):
 #: may hold on a v5e; the margin is for what ``_bytes_per_head`` leaves out.
 _VMEM_BUDGET = 14 * 2 ** 20
 
+#: The most keys in a block of pages: one vreg's lanes and one pass of the
+#: MXU's 128 columns. The body's work is the BLOCK's, whatever is live in
+#: it, so a wider block adds dead keys to a slot's last one, and a
+#: narrower one more waits on copies. Swept on a v5e at the serving cells'
+#: shapes (scripts/paged_attention_trace.py --block-keys; PERF.md, PR 36;
+#: ms a call at 64 | 128 | 256 keys): every slot idle 0.0078 | 0.0093 |
+#: 0.0125, the GPT cell's contexts 0.0270 | 0.0243 | 0.0262, a full pool
+#: 0.218 | 0.179 | 0.179, the looped cell's 0.0368 | 0.0315 | 0.0316.
+_BLOCK_KEYS = 128
 
-def _bytes_per_head(rows8, d, p, kv_itemsize, has_scales):
-    """VMEM that one kv head adds to a grid step: the query and result
-    blocks (float32, double-buffered), the m / l / acc scratch, the body's
-    temporaries (logits, probabilities and their selects: about four
-    ``[rows8, 128]`` float32 arrays), and the double-buffered K and V pages
-    with their scale columns (a ``[P, 1]`` column pads out to 128 lanes).
-    Inside the engine's bucket-512 prefill program Mosaic counted 21.47 MiB
-    for 8 heads of 512 rows (PR 27, on the chip); this gives 22.1. The
-    kernel compiled ALONE needs less, because XLA then keeps the small
-    query and result arrays in VMEM and nothing double-buffers them: a
-    lone compile that passes proves nothing about the budget."""
-    per_head = 4 * rows8 * (4 * d + 2 * 128 + d + 4 * 128)
-    per_head += 2 * 2 * p * d * kv_itemsize
+
+def _bytes_per_head(rows8, d, p, kv_itemsize, has_scales, ppb=1):
+    """VMEM that one kv head adds to a grid step whose blocks are ``ppb``
+    pages: the query and result blocks (float32, double-buffered), the
+    m / l / acc scratch, the body's temporaries (logits, probabilities and
+    their selects: about four ``[rows8, keys]`` float32 arrays, a lane
+    width at least), and a page's share ``ppb`` times: K and V in both
+    buffers at the stored dtype, a float32 copy of each (what a pair that
+    meets in float32 is widened to), and with scales their rows in both
+    buffers (counted at a padded tile a head). The kernel compiled ALONE
+    needs less, because XLA then keeps the small query and result arrays
+    in VMEM and nothing double-buffers them: a lone compile that passes
+    proves nothing about the budget (PR 27, on the chip)."""
+    per_head = 4 * rows8 * (4 * d + 2 * 128 + d + 4 * max(128, ppb * p))
+    per_page = 2 * 2 * p * d * kv_itemsize + 2 * p * d * 4
     if has_scales:
-        per_head += 2 * 2 * p * 128 * 4
-    return per_head
+        per_page += 2 * 2 * p * 128 * 4
+    return per_head + ppb * per_page
 
 
 def _heads_per_step(hkv, rows8, d, p, kv_itemsize, has_scales):
     """How many kv heads one grid step takes: the most that divides
-    ``hkv`` and keeps what grows with it under ``_VMEM_BUDGET``. Decode
-    and verify (8-16 rows) take every head; a prefill, whose row block is
-    the whole bucket, takes a few or one."""
+    ``hkv`` and keeps what grows with it under ``_VMEM_BUDGET`` at one
+    page a block. Decode and verify (8-16 rows) take every head; a call
+    of a prefill's row count takes a few or one."""
     hb = max(1, min(hkv, _VMEM_BUDGET // _bytes_per_head(
         rows8, d, p, kv_itemsize, has_scales)))
     while hkv % hb:
@@ -120,78 +147,203 @@ def _heads_per_step(hkv, rows8, d, p, kv_itemsize, has_scales):
     return hb
 
 
+def _pages_per_block(rows8, d, p, hb, kv_itemsize, has_scales):
+    """How many pages one block of the body's walk holds: the largest
+    power of two, up to ``_BLOCK_KEYS`` keys, that ``hb`` heads keep under
+    ``_VMEM_BUDGET``. 8 pages of 16 keys in decode and verify at the
+    serving cells' shapes; 1 where the rows leave no more."""
+    ppb = 1
+    while (2 * ppb * p <= _BLOCK_KEYS and hb * _bytes_per_head(
+            rows8, d, p, kv_itemsize, has_scales, 2 * ppb) <= _VMEM_BUDGET):
+        ppb *= 2
+    return ppb
+
+
+def block_shape(t, heads, kv_heads, d, p, kv_itemsize, has_scales):
+    """``(hb, ppb)`` of a call of ``t`` rows a slot: the kv heads a grid
+    step takes and the pages a block of its walk holds, from the call's
+    shapes alone. The engine counts the page slots its passes walked with
+    the same ``ppb`` (``DecodeEngine.kv_block_pages``)."""
+    rows8 = _ceil8(t * (heads // kv_heads))
+    hb = _heads_per_step(kv_heads, rows8, d, p, kv_itemsize, has_scales)
+    return hb, _pages_per_block(rows8, d, p, hb, kv_itemsize, has_scales)
+
+
 def _last_live_page(sp_ref, s_i, t, page_size, num_page_slots):
     """The last page slot that any query row of slot ``s_i`` can see: row
     ``t - 1`` sits at position ``start_position + t - 1``. Everything past
-    it is masked for every row, so the grid neither fetches nor multiplies
+    it is masked for every row, so the walk neither fetches nor multiplies
     it. An idle slot (position 0) has one live page slot."""
     return jnp.minimum(
         jax.lax.div(sp_ref[s_i] + (t - 1), page_size), num_page_slots - 1)
 
 
 def _paged_kernel(
-    *refs, scale, page_size, num_page_slots, groups, rows, t, fill,
-    has_scales,
+    *refs, scale, num_page_slots, groups, rows, t, fill, has_scales,
 ):
-    """One grid step = one (slot, block of kv heads, page_slot) triple.
+    """One grid step = one (slot, block of kv heads) pair; the body walks
+    the slot's live pages in blocks of ``ppb``.
 
-    Grid is (S, Hkv // hb, MP) with the page dimension innermost; m/l/acc
-    scratch carries the online softmax across page slots, one row block a
-    kv head. A page of the pool is contiguous over its kv heads, so one
-    step fetches ``hb`` heads of a page in one DMA and the two products
-    are batched over the head axis. Row r of a head's folded query block
-    is (draft position t = r // groups, query head h_kv * groups +
-    r % groups); kv positions on page slot j are ``j * page_size +
-    offset`` in the sequence's virtual key order — exactly the
-    gathered-layout positions the einsum oracle masks.
+    The pools stay in HBM. Block b of slot s is page slots ``b * ppb ..
+    b * ppb + ppb - 1`` of its table: each live one is ONE copy of ``hb``
+    heads of physical page ``page_table[s, j]`` (contiguous in the
+    page-major pool) into its place in a ``[hb, ppb, P, D]`` VMEM buffer,
+    which the body reads as ``[hb, ppb * P, D]`` keys. There are two such
+    buffers: the next block's copies, or the first block's of the NEXT
+    grid step, start before this block's products, and ``par_ref`` hands
+    the buffer's parity from one grid step to the next. m / l / acc
+    scratch carries the online softmax across blocks, one row block a kv
+    head. Row r of a head's folded query block is (draft position t =
+    r // groups, query head h_kv * groups + r % groups); the keys of block
+    b sit at ``b * ppb * P + offset`` in the sequence's virtual key order
+    — exactly the gathered-layout positions the einsum oracle masks.
 
-    Page slots past the slot's last live one (``_last_live_page``) do no
-    work: the index maps hold the last live page's block, which is
-    therefore not fetched again, and the body's products and softmax
-    update are skipped. Such a page would have contributed ``p = 0`` and
-    ``alpha = 1`` exactly, so leaving it out changes no bit of a row.
+    The walk ends at the slot's last live page (``_last_live_page``).
+    Page slots of the last block past it are not fetched; their K rows
+    are masked like every key past a row's horizon, and their V rows (or
+    V's scales) are zeroed in the buffer, since ``0 x`` whatever VMEM
+    held is not 0 for a NaN. Such a page would have contributed ``p = 0``
+    and ``alpha = 1`` exactly.
     """
     if has_scales:
-        (pt_ref, sp_ref, ly_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
+        (pt_ref, sp_ref, ly_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
+         k_buf, v_buf, ks_buf, vs_buf, sems, par_ref,
          m_scr, l_scr, acc_scr) = refs
     else:
-        (pt_ref, sp_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-        ks_ref = vs_ref = None
-    del pt_ref, ly_ref  # consumed by the BlockSpec index maps, not the body
-    s_idx = pl.program_id(0)
-    j = pl.program_id(2)
+        (pt_ref, sp_ref, ly_ref, q_ref, k_hbm, v_hbm, o_ref,
+         k_buf, v_buf, sems, par_ref, m_scr, l_scr, acc_scr) = refs
+    s_idx, h_idx = pl.program_id(0), pl.program_id(1)
+    num_s, num_h = pl.num_programs(0), pl.num_programs(1)
+    _, hb, ppb, page_size, _ = k_buf.shape
+    block_keys = ppb * page_size
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, fill)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def live_pages(s_i, b):
+        """How many page slots of block ``b`` of slot ``s_i`` are live."""
+        last = _last_live_page(sp_ref, s_i, t, page_size, num_page_slots)
+        return jnp.clip(last + 1 - b * ppb, 0, ppb)
 
-    @pl.when(j <= _last_live_page(sp_ref, s_idx, t, page_size,
-                                  num_page_slots))
-    def _page():
-        q = q_ref[0]  # [hb, rows8, d] f32
-        k = k_ref[0].astype(jnp.float32)  # [hb, page_size, d]
-        v = v_ref[0].astype(jnp.float32)
+    def copies(s_i, h_i, b, buf, i):
+        """The copies of page slot ``i`` of block ``b`` of (slot ``s_i``,
+        head block ``h_i``) into buffer ``buf``: ``hb`` heads of one page,
+        contiguous in the page-major pool, to their place among the
+        block's keys."""
+        page = pt_ref[s_i, b * ppb + i]
+        heads = pl.ds(h_i * hb, hb)
+        made = [
+            pltpu.make_async_copy(
+                k_hbm.at[ly_ref[0], page, heads], k_buf.at[buf, :, i],
+                sems.at[0, buf]),
+            pltpu.make_async_copy(
+                v_hbm.at[ly_ref[0], page, heads], v_buf.at[buf, :, i],
+                sems.at[1, buf])]
         if has_scales:
-            # fused absmax dequant: int8 page * per-[page, head] scale, on
-            # the VMEM-resident block — the f32 pool never exists in HBM
-            k = k * ks_ref[0]  # scale block [hb, page_size, 1]
-            v = v * vs_ref[0]
+            made += [
+                pltpu.make_async_copy(
+                    ks_hbm.at[page, h_i], ks_buf.at[buf, i],
+                    sems.at[0, buf]),
+                pltpu.make_async_copy(
+                    vs_hbm.at[page, h_i], vs_buf.at[buf, i],
+                    sems.at[1, buf])]
+        return made
+
+    def start(s_i, h_i, b, buf):
+        def page(i, carry):
+            for copy in copies(s_i, h_i, b, buf, i):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(s_i, b), page, 0)
+
+    def wait(b, buf):
+        def landed(i, carry):
+            for copy in copies(s_idx, h_idx, b, buf, i):
+                copy.wait()
+            return carry
+
+        def never_fetched(i, carry):
+            if has_scales:  # int8 V is finite; its scale need not be
+                vs_buf[buf, i] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
+            else:
+                v_buf[buf, :, i] = jnp.zeros(
+                    (hb,) + v_buf.shape[3:], v_buf.dtype)
+            return carry
+
+        live = live_pages(s_idx, b)
+        jax.lax.fori_loop(0, live, landed, 0)
+        jax.lax.fori_loop(live, ppb, never_fetched, 0)
+
+    def key_row(scale_buf, buf):
+        """A block's scales, a row of ``hb * P`` a page, as one row a
+        head over the block's keys: [hb, 1, ppb * P]."""
+        pages = scale_buf[buf]
+        return jnp.concatenate([jnp.stack([
+            pages[i][:, h * page_size:(h + 1) * page_size]
+            for h in range(hb)]) for i in range(ppb)], axis=-1)
+
+    @pl.when(jnp.logical_and(s_idx == 0, h_idx == 0))
+    def _first_block_of_the_call():
+        par_ref[0] = 0
+        start(s_idx, h_idx, 0, 0)
+
+    m_scr[:] = jnp.full_like(m_scr, fill)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    parity = par_ref[0]
+    num_blocks = jax.lax.div(
+        _last_live_page(sp_ref, s_idx, t, page_size, num_page_slots),
+        ppb) + 1
+    # the grid step after this one, whose first block this one's last
+    # block prefetches
+    h_next = jax.lax.rem(h_idx + 1, num_h)
+    s_next = s_idx + jax.lax.div(h_idx + 1, num_h)
+
+    def block(b, carry):
+        buf = jax.lax.rem(parity + b, 2)
+
+        @pl.when(b + 1 < num_blocks)
+        def _next_block():
+            start(s_idx, h_idx, b + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(b + 1 == num_blocks, s_next < num_s))
+        def _next_grid_step():
+            start(s_next, h_next, 0, 1 - buf)
+
+        wait(b, buf)
+        q = q_ref[0]  # [hb, rows8, d], bfloat16 or float32
+        k, v = k_buf[buf], v_buf[buf]
+        if k.dtype != q.dtype:
+            k = k.astype(jnp.float32)
+        # A bf16 or int8 V holds bf16 values, and the MXU multiplies bf16:
+        # left to itself Mosaic rounds a float32 operand to ONE bf16 term
+        # (read on the chip, PR 36: 8e-4 off the oracle, as the kernel
+        # before it). So the float32 probabilities go in as the three bf16
+        # terms of a float32 product's own passes, whose sum is p to
+        # 2^-24, stacked on the row axis: one pass over V as the weights.
+        split = v.dtype != jnp.float32
+        v = v.astype(jnp.bfloat16 if split else jnp.float32)
+        # [hb, ppb, P, D] -> [hb, ppb * P, D]: the block's keys in order
+        k = k.reshape(hb, block_keys, k.shape[-1])
+        v = v.reshape(hb, block_keys, v.shape[-1])
         s_log = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale  # [hb, rows8, page_size]
+        ) * scale  # [hb, rows8, block_keys]
+        if has_scales:
+            # fused absmax dequant: a key's scale is one factor of its
+            # whole row of K, so it multiplies the key's logit, and a
+            # value's scale its probability on the way into the second
+            # product — the f32 pool never exists, in HBM or in VMEM
+            s_log = s_log * key_row(ks_buf, buf)
 
         shape = s_log.shape[1:]
         row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         qpos = sp_ref[s_idx] + row // groups
-        kpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        kpos = b * block_keys + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1)
         # causal at each row's own horizon; padding rows (row >= rows) are
-        # fully masked and sliced off by the wrapper. Trash/unallocated
-        # page slots up to the last live one mask themselves: their
-        # virtual positions exceed the horizon.
+        # fully masked and sliced off by the wrapper. Trash, unallocated
+        # and unfetched page slots mask themselves: their virtual
+        # positions exceed the horizon.
         mask = jnp.logical_and(kpos <= qpos, row < rows)
         s_log = jnp.where(mask[None], s_log, fill)
 
@@ -205,17 +357,31 @@ def _paged_kernel(
         # zeros
         p = jnp.where(s_log > fill * 0.5, jnp.exp(s_log - m_new), 0.0)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
+        p_v = p * key_row(vs_buf, buf) if has_scales else p
+        if split:
+            hi = p_v.astype(jnp.bfloat16).astype(jnp.float32)
+            mid = (p_v - hi).astype(jnp.bfloat16).astype(jnp.float32)
+            lo = p_v - hi - mid
+            terms = jnp.concatenate([hi, mid, lo], axis=1).astype(
+                jnp.bfloat16)  # [hb, 3 * rows8, block_keys]
+        else:
+            terms = p_v
+        pv = jax.lax.dot_general(
+            terms, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
+        if split:
+            r8 = p_v.shape[1]
+            pv = pv[:, :r8] + (pv[:, r8:2 * r8] + pv[:, 2 * r8:])
+        acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
 
-    @pl.when(j == num_page_slots - 1)
-    def _emit():
-        safe = jnp.maximum(l_scr[:, :, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / safe).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, num_blocks, block, 0)
+    par_ref[0] = jax.lax.rem(parity + num_blocks, 2)
+    safe = jnp.maximum(l_scr[:, :, :1], 1e-30)
+    o_ref[0] = (acc_scr[:] / safe).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -247,9 +413,9 @@ def paged_attention(
             draft position t attends keys ``<= start_position + t``.
         layer: which layer of a stacked pool to attend over (an int or
             an int32 scalar, traced or not); required with 5-D pools and
-            refused with 4-D ones. It reaches the index maps as a third
-            scalar-prefetch operand and the pool's block squeezes the
-            layer axis, so the pool is never sliced.
+            refused with 4-D ones. It reaches the body as a third
+            scalar-prefetch operand and indexes the pool where a page's
+            copy starts, so the pool is never sliced.
         scale: logit scale; defaults to ``1/sqrt(D)``.
         k_scales, v_scales: optional ``[N, Hkv, P]`` f32 absmax scales —
             passing them turns on fused int8 dequant (both or neither).
@@ -261,10 +427,11 @@ def paged_attention(
     Returns:
         ``[S, T, H, D]`` f32 attention output.
 
-    The work follows the live KV, not the table's width: a slot's page
-    slots past ``(start_position + T - 1) // P`` are neither fetched nor
-    multiplied, and a grid step takes as many kv heads of a page as fit
-    (``_heads_per_step``, from the call's shapes alone).
+    The work follows the live KV, not the table's width: the grid is one
+    step a slot (and block of kv heads: as many as fit,
+    ``_heads_per_step``), and the body walks the slot's page slots up to
+    ``(start_position + T - 1) // P`` in blocks of ``_pages_per_block``
+    pages; both sizes come from the call's shapes alone.
     """
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
@@ -274,72 +441,88 @@ def paged_attention(
             f"only it does: pool rank {k_pool.ndim}, layer {layer!r}")
     if layer is None:  # one layer's pool is a stack of one: a bitcast
         k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    _, t, h, d = q.shape
+    hkv, p = k_pool.shape[2:4]
+    if h % hkv:
+        raise ValueError(f"num heads {h} not divisible by kv heads {hkv}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    hb, ppb = block_shape(t, h, hkv, d, p, k_pool.dtype.itemsize,
+                          k_scales is not None)
+    return _walk_call(
+        q, k_pool, v_pool, page_table.astype(jnp.int32),
+        start_position.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), k_scales, v_scales,
+        scale=float(scale) if scale is not None else 1.0 / math.sqrt(d),
+        hb=hb, ppb=ppb, interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "hb", "ppb", "interpret"))
+def _walk_call(q, k_pool, v_pool, page_table, start_position, layer,
+               k_scales, v_scales, *, scale, hb, ppb, interpret):
+    """The call at its block sizes. Jitted, so that a program which makes
+    it once a layer traces and lowers the kernel once, not once a layer
+    (the GPT decode program unrolls 24; XLA inlines the calls: the
+    compiled program is the same)."""
     s, t, h, d = q.shape
     _, n, hkv, p, _ = k_pool.shape
     mp = page_table.shape[1]
-    if h % hkv:
-        raise ValueError(f"num heads {h} not divisible by kv heads {hkv}")
     groups = h // hkv
     rows = t * groups
     rows8 = _ceil8(rows)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    sc = float(scale) if scale is not None else 1.0 / math.sqrt(d)
-    fill = mask_fill_value(jnp.float32)
     has_scales = k_scales is not None
-    hb = _heads_per_step(hkv, rows8, d, p, k_pool.dtype.itemsize, has_scales)
 
+    # bf16 x bf16 into a float32 accumulator is the same products exactly
+    # as the widened pair's; every other pair meets in float32
+    if (has_scales or q.dtype != jnp.bfloat16
+            or k_pool.dtype != jnp.bfloat16):
+        q = q.astype(jnp.float32)
     # GQA-native folding: [S, T, H, D] -> [S, Hkv, T*G, D]; the G query
     # heads of a kv head travel as kernel rows, so kv pages are read once
     # per kv head — never replicated across query heads.
-    qg = q.astype(jnp.float32).reshape(s, t, hkv, groups, d)
+    qg = q.reshape(s, t, hkv, groups, d)
     qg = qg.transpose(0, 2, 1, 3, 4).reshape(s, hkv, rows, d)
     if rows8 != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows8 - rows), (0, 0)))
 
-    def q_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref):
+    def q_index(s_i, h_i, pt_ref, sp_ref, ly_ref):
         return (s_i, h_i, 0, 0)
 
-    def page_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref):
-        # the page-table gather: grid step (s, h, j) streams physical
-        # page pt[s, j] for head block h straight from the pool; past the
-        # last live page slot the index stands still, and a block whose
-        # index does not change is not fetched again
-        live = jnp.minimum(j, _last_live_page(sp_ref, s_i, t, p, mp))
-        return (pt_ref[s_i, live], h_i, 0, 0)
-
-    def pool_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref):
-        return (ly_ref[0],) + page_index(s_i, h_i, j, pt_ref, sp_ref, ly_ref)
-
-    in_specs = [
-        pl.BlockSpec((1, hb, rows8, d), q_index),
-        # the layer axis is squeezed: the body sees [1, hb, P, D] as ever
-        pl.BlockSpec((None, 1, hb, p, d), pool_index),
-        pl.BlockSpec((None, 1, hb, p, d), pool_index),
-    ]
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, hb, rows8, d), q_index), in_hbm, in_hbm]
     args = [qg, k_pool, v_pool]
+    scratch = [pltpu.VMEM((2, hb, ppb, p, d), k_pool.dtype),
+               pltpu.VMEM((2, hb, ppb, p, d), v_pool.dtype)]
     if has_scales:
-        # trailing singleton dim: per-row stats blocks must keep their
-        # last two dims equal to the array dims for Mosaic tiling
-        in_specs.append(pl.BlockSpec((1, hb, p, 1), page_index))
-        in_specs.append(pl.BlockSpec((1, hb, p, 1), page_index))
-        args.append(k_scales.astype(jnp.float32).reshape(n, hkv, p, 1))
-        args.append(v_scales.astype(jnp.float32).reshape(n, hkv, p, 1))
+        # a copy's last axis is whole lane widths: a page's scales of one
+        # head block travel as ONE row [1, hb * P], padded out to 128s
+        lanes = -(-hb * p // 128) * 128
+        in_specs += [in_hbm, in_hbm]
+        args += [jnp.pad(
+            a.astype(jnp.float32).reshape(n, hkv // hb, 1, hb * p),
+            ((0, 0), (0, 0), (0, 0), (0, lanes - hb * p)))
+            for a in (k_scales, v_scales)]
+        scratch += [pltpu.VMEM((2, ppb, 1, lanes), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2, 2)),  # (K | V, buffer)
+        pltpu.SMEM((1,), jnp.int32),      # the next block's buffer
+        _scratch((hb, rows8, 128)),
+        _scratch((hb, rows8, 128)),
+        _scratch((hb, rows8, d)),
+    ]
 
     kernel = functools.partial(
-        _paged_kernel, scale=sc, page_size=p, num_page_slots=mp,
-        groups=groups, rows=rows, t=t, fill=fill, has_scales=has_scales,
+        _paged_kernel, scale=scale, num_page_slots=mp, groups=groups,
+        rows=rows, t=t, fill=mask_fill_value(jnp.float32),
+        has_scales=has_scales,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s, hkv // hb, mp),
+        grid=(s, hkv // hb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hb, rows8, d), q_index),
-        scratch_shapes=[
-            _scratch((hb, rows8, 128)),
-            _scratch((hb, rows8, 128)),
-            _scratch((hb, rows8, d)),
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
@@ -347,8 +530,7 @@ def paged_attention(
         grid_spec=grid_spec,
         interpret=interpret,
         name="paged_attention",
-    )(page_table.astype(jnp.int32), start_position.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), *args)
+    )(page_table, start_position, layer, *args)
     out = out[:, :, :rows]
     return out.reshape(s, hkv, t, groups, d).transpose(
         0, 2, 1, 3, 4).reshape(s, t, h, d)

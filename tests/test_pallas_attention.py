@@ -103,6 +103,27 @@ def test_kernel_matches_einsum_oracle(page_size, group, int8, t):
                                  page_size=page_size, int8=int8))
 
 
+def _call_shapes(spec):
+    """(rows8, d, p, kv_itemsize, has_scales) of a ``_case(**spec)``."""
+    return (pa_kernel._ceil8(spec["t"] * spec["group"]), 16,
+            spec["page_size"], 1 if spec["int8"] else 4, spec["int8"])
+
+
+def _force_heads(monkeypatch, heads, spec, expect=None):
+    """The VMEM budget that leaves ``heads`` kv heads a grid step, the
+    pages a block held at what the unforced call takes."""
+    shapes = _call_shapes(spec)
+    assert pa_kernel._heads_per_step(spec["hkv"], *shapes) == spec["hkv"]
+    ppb = pa_kernel._pages_per_block(*shapes[:3], spec["hkv"], *shapes[3:])
+    monkeypatch.setattr(pa_kernel, "_VMEM_BUDGET",
+                        heads * pa_kernel._bytes_per_head(*shapes))
+    assert pa_kernel._heads_per_step(spec["hkv"], *shapes) == (
+        expect or heads)
+    assert pa_kernel._pages_per_block(
+        *shapes[:3], expect or heads, *shapes[3:]) < ppb
+    monkeypatch.setattr(pa_kernel, "_pages_per_block", lambda *a: ppb)
+
+
 #: What a grid that follows the live KV can get wrong: where the last live
 #: page slot lies, a table far wider than it, a slot with nothing live, the
 #: prefill's one slot of many rows, and a head block smaller than the heads
@@ -148,14 +169,104 @@ def test_kernel_matches_einsum_oracle_where_the_grid_follows_live_kv(
     whole = _assert_matches_oracle(case)
     if heads is None:
         return
-    # a smaller head block is the same arithmetic a head: bit-equal
-    shapes = (pa_kernel._ceil8(spec["t"] * spec["group"]), 16, 8,
-              1 if spec["int8"] else 4, spec["int8"])
-    assert pa_kernel._heads_per_step(spec["hkv"], *shapes) == spec["hkv"]
-    monkeypatch.setattr(pa_kernel, "_VMEM_BUDGET",
-                        heads * pa_kernel._bytes_per_head(*shapes))
-    assert pa_kernel._heads_per_step(spec["hkv"], *shapes) == expect
+    # a smaller head block is the same arithmetic a head: bit-equal, at
+    # the whole call's pages a block (the budget that leaves fewer heads
+    # would leave fewer pages too, and rescale in another order)
+    _force_heads(monkeypatch, heads, spec, expect)
     np.testing.assert_array_equal(_assert_matches_oracle(case), whole)
+
+
+#: What the body's own walk can get wrong (PR 36): where a slot's live
+#: pages end inside its last block, a buffer handed from one grid step to
+#: the next, an idle slot between live ones, and page slots of a block
+#: that are never fetched. ``keys``: the most keys a block (pages of 8, so
+#: 32 is 4 pages a block); slots' live pages in the comments.
+BLOCK_WALK_CASES = {
+    # 2 of 4, idle, 4 of 4, 5 = one block and a page, 8 = two blocks
+    "ends_mid_block_on_its_edge_and_a_page_past_it": dict(
+        t=1, ctx=[16, None, 32, 33, 64], max_pages=12, keys=32),
+    "verify_rows_reach_into_the_next_block": dict(
+        t=3, ctx=[33, None, 34, 32], max_pages=12, keys=32),
+    "idle_slots_first_and_last": dict(
+        t=1, ctx=[None, 70, None, 9, None], max_pages=12, keys=32),
+    "one_page_a_block": dict(
+        t=1, ctx=[16, None, 33], max_pages=6, keys=8),
+    "two_pages_a_block_gqa_int8": dict(
+        t=3, ctx=[40, None, 17, 16], max_pages=6, keys=16, group=2,
+        int8=True),
+    "a_block_wider_than_the_table": dict(
+        t=1, ctx=[20, None, 5], max_pages=3, keys=128),
+    "head_blocks_hand_the_buffer_on": dict(
+        t=1, ctx=[33, None, 16], max_pages=12, keys=32, hkv=4, heads=2),
+    "head_blocks_hand_the_buffer_on_int8": dict(
+        t=3, ctx=[33, 8, None], max_pages=12, keys=32, hkv=4, heads=1,
+        int8=True),
+}
+
+
+@pytest.mark.parametrize("name", list(BLOCK_WALK_CASES))
+def test_kernel_matches_einsum_oracle_where_the_body_walks_blocks(
+        name, monkeypatch):
+    spec = dict(BLOCK_WALK_CASES[name])
+    keys, heads = spec.pop("keys"), spec.pop("heads", None)
+    spec = dict(dict(hkv=2, group=1, page_size=8, int8=False), **spec)
+    monkeypatch.setattr(pa_kernel, "_BLOCK_KEYS", keys)
+    shapes = _call_shapes(spec)
+    assert pa_kernel._pages_per_block(
+        *shapes[:3], spec["hkv"], *shapes[3:]) == keys // 8
+    if heads is not None:
+        _force_heads(monkeypatch, heads, spec)
+    case = _case(np.random.default_rng(len(name)), **spec)
+    # an unreferenced page of NaN: no entry of the table names it (dead
+    # entries are the trash page, 0), so nothing may read it, and what a
+    # block's unfetched page slots leave in VMEM must not reach a row
+    q, kp, vp, ks, vs, table, start = case
+    if not spec["int8"]:
+        spare = np.full((1,) + kp.shape[1:], np.nan, np.float32)
+        kp, vp = np.concatenate([kp, spare]), np.concatenate([vp, spare])
+    got = _assert_matches_oracle((q, kp, vp, ks, vs, table, start))
+    assert np.isfinite(got).all()
+
+
+@pytest.mark.parametrize("t", [1, 5])
+@pytest.mark.parametrize("q_dtype,pool_dtype", [
+    ("bfloat16", "bfloat16"),  # the cells' pair: the first product unwidened
+    ("float32", "bfloat16"),
+    ("bfloat16", "float32"),
+])
+def test_kernel_matches_einsum_oracle_at_the_dtypes_queries_and_pool_come_in(
+        q_dtype, pool_dtype, t):
+    """bf16 queries on a bf16 pool enter the first product as they are
+    (bf16 x bf16 into a float32 accumulator is the same products
+    exactly); every other pair meets in float32. The oracle runs on the
+    values the kernel is handed, widened; the result is float32."""
+    q, kp, vp, _, _, table, start = _case(
+        np.random.default_rng(t), t=t, hkv=2, group=2, page_size=8,
+        ctx=[40, None, 17, 64], max_pages=10)
+    q = jnp.asarray(q, q_dtype)
+    kp, vp = jnp.asarray(kp, pool_dtype), jnp.asarray(vp, pool_dtype)
+    got = np.asarray(pa_kernel.paged_attention(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(start)))
+    assert got.dtype == np.float32
+    ref = _run("einsum", q.astype(jnp.float32), kp.astype(jnp.float32),
+               vp.astype(jnp.float32), None, None, table, start)
+    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=1e-4)
+    np.testing.assert_array_equal(got.argmax(-1), ref.argmax(-1))
+
+
+def test_a_row_count_that_fills_the_budget_leaves_one_page_a_block(
+        monkeypatch):
+    """The same budget gives a decode call blocks of several pages and a
+    call of many rows blocks of ONE page, with every head still in the
+    step: 1 is legal and works."""
+    spec = dict(hkv=2, group=1, page_size=8, int8=False)
+    monkeypatch.setattr(
+        pa_kernel, "_VMEM_BUDGET", 1 + 2 * pa_kernel._bytes_per_head(
+            *_call_shapes(dict(spec, t=64))))
+    assert pa_kernel.block_shape(1, 2, 2, 16, 8, 4, False) == (2, 16)
+    assert pa_kernel.block_shape(64, 2, 2, 16, 8, 4, False) == (2, 1)
+    _assert_matches_oracle(_case(
+        np.random.default_rng(0), t=64, ctx=[90], max_pages=12, **spec))
 
 
 #: The engine's call since PR 30: the stacked ``[L, N, Hkv, P, D]`` pool and
@@ -199,9 +310,7 @@ def test_stacked_pool_call_equals_the_4d_call_on_its_layer(
     rng = np.random.default_rng(len(name))
     q, kp, vp, ks, vs, table, start = _case(rng, **spec)
     if heads is not None:
-        monkeypatch.setattr(
-            pa_kernel, "_VMEM_BUDGET", heads * pa_kernel._bytes_per_head(
-                pa_kernel._ceil8(spec["t"] * spec["group"]), 16, 8, 4, False))
+        _force_heads(monkeypatch, heads, spec)
     kw = {} if ks is None else dict(k_scales=jnp.asarray(ks),
                                     v_scales=jnp.asarray(vs))
     rest = (jnp.asarray(table), jnp.asarray(start))
@@ -268,14 +377,59 @@ def test_heads_per_step_follows_the_calls_shapes(shape, heads):
             <= pa_kernel._VMEM_BUDGET)
 
 
-def test_bytes_per_head_covers_what_mosaic_counted_on_the_chip():
-    """Inside the engine's bucket-512 prefill program, 8 heads a step were
-    refused at run time: "Scoped allocation with size 21.47M and limit
-    16.00M" (PR 27, my chip run), though the kernel compiled alone passes
-    (XLA keeps the lone call's query and result in VMEM). The estimate has
-    to stay above that reading, and the budget under the limit."""
-    assert 8 * pa_kernel._bytes_per_head(512, 128, 16, 2, False) >= int(
-        21.47 * 2 ** 20)
+@pytest.mark.parametrize("shape,pages", [
+    # (rows8, d, p, hb, kv_itemsize, has_scales): 128 keys a block at the
+    # serving cells' decode and verify shapes, fewer where the rows fill
+    # the budget, never none
+    ((8, 128, 16, 16, 2, False), 8),     # GPT-3 1.3B and Ouro-2.6B decode
+    ((8, 128, 16, 16, 1, True), 8),      # the int8 pool with its scales
+    ((24, 128, 16, 8, 2, False), 8),     # GQA 32/8, verify k=4
+    ((8, 64, 32, 12, 2, False), 4),      # pages of 32 keys
+    ((8, 128, 128, 16, 2, False), 1),    # a page as wide as a block
+    ((8, 128, 256, 16, 2, False), 1),    # and wider: one page, not none
+    ((512, 128, 16, 4, 2, False), 8),    # what a prefill bucket was
+    ((1024, 128, 16, 2, 2, False), 8),
+    ((2048, 128, 16, 1, 2, False), 8),
+    ((2568, 128, 16, 1, 2, False), 4),   # the rows leave four pages,
+    ((2584, 128, 16, 1, 2, False), 2),   # two,
+    ((2600, 128, 16, 1, 2, False), 1),   # one
+    ((8192, 128, 16, 1, 2, False), 1),   # never none, whatever the rows
+])
+def test_pages_per_block_follows_the_calls_shapes(shape, pages):
+    rows8, d, p, hb, item, scales = shape
+    got = pa_kernel._pages_per_block(*shape)
+    assert got == pages and got & (got - 1) == 0
+    assert got == 1 or got * p <= pa_kernel._BLOCK_KEYS
+    assert (got == 1 or hb * pa_kernel._bytes_per_head(
+        rows8, d, p, item, scales, got) <= pa_kernel._VMEM_BUDGET)
+    assert (2 * got * p > pa_kernel._BLOCK_KEYS
+            or hb * pa_kernel._bytes_per_head(
+                rows8, d, p, item, scales, 2 * got)
+            > pa_kernel._VMEM_BUDGET)
+
+
+def test_bytes_per_head_covers_the_walks_buffers_under_the_chips_limit():
+    """What the estimate has to stay above is what the kernel allocates
+    for sure: both buffers of K and V at the stored dtype, the scale rows,
+    the double-buffered query and result blocks and the m / l / acc
+    scratch; Mosaic's temporaries come on top (PR 27, my chip run:
+    "Scoped allocation with size 21.47M and limit 16.00M" for a body that
+    compiled alone). At the cells' bf16 shapes the whole step stays under
+    a half of the budget, and the budget under the chip's 16 MiB."""
+    for rows8, d, p, hb, item, scales in (
+            (8, 128, 16, 16, 2, False), (8, 128, 16, 16, 1, True),
+            (512, 128, 16, 4, 2, False)):
+        ppb = pa_kernel._pages_per_block(rows8, d, p, hb, item, scales)
+        allocated = hb * (2 * 2 * ppb * p * d * item      # K, V buffers
+                          + 2 * 2 * rows8 * d * 4         # q, result
+                          + rows8 * (2 * 128 + d) * 4)    # m, l, acc
+        if scales:
+            allocated += 2 * 2 * ppb * 8 * max(128, hb * p) * 4
+        estimate = hb * pa_kernel._bytes_per_head(
+            rows8, d, p, item, scales, ppb)
+        assert allocated < estimate <= pa_kernel._VMEM_BUDGET
+        if rows8 == 8 and not scales:
+            assert estimate < pa_kernel._VMEM_BUDGET // 2
     assert pa_kernel._VMEM_BUDGET < 16 * 2 ** 20
 
 

@@ -372,6 +372,49 @@ def test_kv_page_counts_follow_the_positions_of_each_pass(engine):
     engine.kv_pages_live = live0
 
 
+def test_kv_block_pages_are_the_page_slots_of_the_walks_blocks(
+        engine, monkeypatch, tmp_path):
+    """``kv_block_pages`` is what the paged kernel's walk takes for the
+    same passes: whole blocks of the kernel's own pages a block for the
+    pass's shapes (here 2, through a cap of 16 keys on pages of 8; the
+    tiny model's 16 would make every slot one block as wide as its
+    table), never past the table; ``eng_step`` carries it."""
+    from paddle_tpu.ops.pallas import paged_attention as pa_kernel
+
+    monkeypatch.setattr(pa_kernel, "_BLOCK_KEYS", 16)
+    engine._pages_per_block.clear()
+    try:
+        ppb = pa_kernel.block_shape(1, 4, 4, 8, 8, 4, False)[1]
+        assert ppb == 2
+        before = engine.stats()
+        engine.submit(_prompt(9, 4), max_new_tokens=6)   # positions 9 .. 13
+        engine.submit(_prompt(15, 5), max_new_tokens=4)  # 15, 16, 17
+        with jax.profiler.trace(str(tmp_path)):
+            while engine.step():
+                pass
+        after = engine.stats()
+        live = [p // 8 + 1 for p in (9, 10, 11, 12, 13, 15, 16, 17)]
+        live += [1, 1]  # the second slot, once the shorter answer has left
+        assert after["kv_pages_live"] - before["kv_pages_live"] == sum(live)
+        walked = sum(-(-n // ppb) * ppb for n in live)
+        assert walked == 2 * 6 + 2 * 4 + 2 * 2
+        assert after["kv_block_pages"] - before["kv_block_pages"] == walked
+        assert (after["kv_pages_live"] <= after["kv_block_pages"]
+                <= after["kv_pages_capacity"])
+        last = [r for r in tracing.recorded() if r.name == "eng_step"][-1]
+        assert last.attrs["kv_block_pages"] == engine.kv_block_pages
+        # a verify pass of k + 1 rows has its own pages a block, and a
+        # block never counts page slots that the table does not have
+        blocks0 = engine.kv_block_pages
+        engine._pages_per_block[4] = 3
+        engine._count_kv_pages(np.array([0, 13, 63]), 4)
+        assert engine.kv_block_pages - blocks0 == 3 + 3 + 8
+        engine.kv_block_pages = blocks0
+        engine.kv_pages_live = after["kv_pages_live"]
+    finally:
+        engine._pages_per_block.clear()
+
+
 # -- names inside the compiled programs --------------------------------------------
 
 

@@ -113,10 +113,11 @@ def test_flash_attention_padding_mask_compiles(one_chip, flash_on_tpu):
     _compile(fwd_bwd, one_chip, qkv, qkv, qkv, ((b, 1, 1, t), jnp.bool_))
 
 
-# -- paged attention (serving path: GPT-1.3B, 8 slots, 2048 tokens, page 16) -
+# -- paged attention (serving path: GPT-1.3B, 8 slots, 2048 tokens, page 16;
+# Ouro-2.6B, 8 slots, 512 tokens: the same heads and page, a table of 32) ----
 
-def _paged_shapes(kv, slots, t, heads=16, kv_heads=16):
-    d, page, max_pages = 128, 16, 128
+def _paged_shapes(kv, slots, t, heads=16, kv_heads=16, max_pages=128):
+    d, page = 128, 16
     n = 1 + 8 * max_pages
     pool = ((n, kv_heads, page, d),
             jnp.int8 if kv == "int8" else jnp.bfloat16)
@@ -133,12 +134,14 @@ def _paged(q, kp, vp, table, start, ks=None, vs=None):
                            v_scales=vs, interpret=False)
 
 
-#: (slots, T): the decode and verify passes over 8 slots, and the tail
-#: prefill's one slot at each bucket, where a grid step's head block is
-#: what the VMEM budget leaves (16, 4 and 2 heads of the 16). A lone
-#: kernel's compile is LENIENT on VMEM: XLA keeps its small query and
-#: result in VMEM, where the engine's program double-buffers them from HBM
-#: (tests/test_pallas_attention.py pins the estimate on the chip's count)
+#: (slots, T): the decode and verify passes over 8 slots, whose body walks
+#: a slot's live pages in blocks of 8 (manual copies from the pool in HBM
+#: into two VMEM buffers: PR 36), and one slot at what a prefill bucket's
+#: row counts were, where a grid step's head block is what the VMEM budget
+#: leaves (16, 4 and 2 heads of the 16). A lone kernel's compile is LENIENT
+#: on VMEM: XLA keeps its small query and result in VMEM, where the
+#: engine's program double-buffers them from HBM: the engine's own decode
+#: and verify programs are compiled with the kernel inside further down
 PAGED_CALLS = {"decode": (8, 1), "verify_k4": (8, 5),
                "prefill_128": (1, 128), "prefill_512": (1, 512),
                "prefill_1024": (1, 1024)}
@@ -148,6 +151,17 @@ PAGED_CALLS = {"decode": (8, 1), "verify_k4": (8, 5),
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_paged_attention_compiles(one_chip, kv, call):
     _compile(_paged, one_chip, *_paged_shapes(kv, *PAGED_CALLS[call]))
+
+
+@pytest.mark.parametrize("call", ["decode", "verify_k4"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_attention_compiles_over_the_looped_cells_table(
+        one_chip, kv, call):
+    """Ouro-2.6B's call, 192 a decode pass: 8 slots x 32 page slots."""
+    compiled = _compile(_paged, one_chip, *_paged_shapes(
+        kv, *PAGED_CALLS[call], max_pages=32))
+    assert re.search(r"%paged_attention(\.\d+)? = f32\[8,16,8,128\]",
+                     compiled.as_text())
 
 
 @pytest.mark.parametrize("call", ["decode", "prefill_512"])
@@ -460,6 +474,30 @@ def test_engine_program_moves_nothing_as_large_as_a_layers_pool(
     wrote = "scatter" if program.startswith("prefill") else (
         "dynamic-update-slice")
     assert re.search(rf" {wrote}\(", text)
+
+
+@pytest.mark.parametrize("program", ["decode", "verify_k4"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_engine_decode_program_compiles_with_the_walking_kernel_inside(
+        engine_programs, kv, program):
+    """The cell's decode and verify programs compile for the described
+    v5e with the kernel that walks the pages itself INSIDE them: the pools
+    stay operands in HBM (two buffers of 8 pages each in VMEM, the scale
+    rows beside them under int8), the query and result come from HBM and
+    are double-buffered (what a lone compile cannot show of VMEM: PR 27),
+    and every layer's call is the ONE line that the benchmark's readers
+    look for: ``paged_attention``, a float32 result, the slot axis
+    first."""
+    text, pool_shape = engine_programs(kv, program)
+    lines = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(lines) == ENGINE_LAYERS[kv]
+    dims = ",".join(str(n) for n in pool_shape)
+    for ln in lines:
+        assert re.search(r"%paged_attention(\.\d+)? = f32\[8,16,8,128\]", ln)
+        assert _decode_kernel_pattern(8).search(ln), ln
+        # both pools reach the call whole, as its operands
+        assert len(re.findall(rf"\w+\[{dims}\]", ln)) == 2, ln
 
 
 @pytest.mark.parametrize("bucket", [128, 512, 1024])
