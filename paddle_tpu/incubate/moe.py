@@ -16,8 +16,9 @@ exactly like capacity-factor semantics in the reference.
 """
 from __future__ import annotations
 
+import contextlib
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,7 @@ from ..nn import initializer as I
 from ..framework.core import Tensor
 from ..framework.op import defop, raw
 from ..distributed import mesh as _mesh
+from ..profiler import scope as _scope
 
 
 def _expert_axis() -> Optional[str]:
@@ -223,17 +225,7 @@ def _moe_apply_dropless(flat, logits, w_in, b_in, w_out, b_out, act, top_k):
     row_gid = expert_ids[order]
     xs = flat[order // top_k].astype(flat.dtype)  # [gk, H] sorted copies
 
-    # measured on v5e (8k tokens, 1024->4096, 8 experts): 512-row blocks
-    # are ~6% faster than 128 (less per-visit overhead). Use them only
-    # once the padding tail is amortized (gk >= 2048 keeps the tail under
-    # 25%; at gk just above 512 it would nearly double the row tiles);
-    # tiny inputs keep a pow2 block so the tail stays bounded
-    if gk >= 2048:
-        block_m = 512
-    elif gk >= 128:
-        block_m = 128
-    else:
-        block_m = max(8, 1 << (gk - 1).bit_length())
+    block_m = _gmm_block_m(gk)
     pad = (-gk) % block_m
     xs_p = jnp.pad(xs, ((0, pad), (0, 0)))
 
@@ -248,6 +240,98 @@ def _moe_apply_dropless(flat, logits, w_in, b_in, w_out, b_out, act, top_k):
     y_tok = y[inv].reshape(g, top_k, h)
     out = jnp.sum(gates[..., None].astype(flat.dtype) * y_tok, axis=1)
     return out, aux
+
+
+def _gmm_block_m(gk: int) -> int:
+    """Row block of the grouped products over ``gk`` sorted token copies.
+    Measured on v5e (8k tokens, 1024->4096, 8 experts): 512-row blocks are
+    ~6% faster than 128 (less per-visit overhead). Use them only once the
+    padding tail is amortized (gk >= 2048 keeps the tail under 25%; at gk
+    just above 512 it would nearly double the row tiles); tiny inputs keep
+    a pow2 block so the tail stays bounded."""
+    if gk >= 2048:
+        return 512
+    if gk >= 128:
+        return 128
+    return max(8, 1 << (gk - 1).bit_length())
+
+
+#: the traced counts that ``routed_experts`` leaves while ``count_experts``
+#: is open: one int32 a layer traced, the experts it sent tokens to
+_EXPERT_COUNTS: Optional[list] = None
+
+
+@contextlib.contextmanager
+def count_experts():
+    """Collect, while open, how many of its experts each ``routed_experts``
+    traced inside sent at least one token to (a traced int32 a layer): a
+    compiled program sums the list into an output of its own, so that the
+    count comes from the routing on the device."""
+    global _EXPERT_COUNTS
+    prev, _EXPERT_COUNTS = _EXPERT_COUNTS, []
+    try:
+        yield _EXPERT_COUNTS
+    finally:
+        _EXPERT_COUNTS = prev
+
+
+@defop(name="moe_routed_experts")
+def routed_experts(x, router_w, w_gate_up, w_down, top_k: int,
+                   expert_range: Optional[Tuple[int, int]] = None):
+    """A sparse SwiGLU expert layer, dropless, as one expert-parallel rank
+    computes it (Qwen3-MoE's block): ``x [G, H]`` routed over ALL experts,
+    the part of the result that the experts held here give.
+
+        r = softmax_f32(x Wr)                  Wr [H, E]; products in f32
+        S = top_k(r), w_e = r_e / sum_S r      (norm_topk_prob)
+        y = sum_{e in S, held} w_e (silu(x G_e) * (x U_e)) D_e
+
+    ``w_gate_up [E_held, H, 2F]`` holds each expert's G and U side by side
+    (one grouped product for both), ``w_down [E_held, F, H]``;
+    ``expert_range = (lo, hi)`` names the experts held (all when None).
+    Token copies routed to a held expert are sorted by expert and run as
+    the two grouped products of ``ops/pallas/grouped_matmul.py``; copies
+    routed elsewhere sort past the groups, where the kernel gives zero
+    rows. Over every range the parts add up to the whole layer (no
+    exchange is written: on one chip the layer holds every expert).
+    Returns y [G, H] in x's dtype."""
+    from ..ops.pallas.grouped_matmul import grouped_matmul
+
+    g, h = x.shape
+    e = router_w.shape[1]
+    lo, hi = expert_range or (0, e)
+    held = w_gate_up.shape[0]
+    if hi - lo != held:
+        raise ValueError(f"expert_range {(lo, hi)} does not match the "
+                         f"{held} experts held")
+    f = w_down.shape[1]
+    gk = g * top_k
+    with _scope("moe_route"):
+        probs = jax.nn.softmax(jnp.dot(
+            x, router_w, preferred_element_type=jnp.float32), axis=-1)
+        topv, topi = jax.lax.top_k(probs, top_k)  # [G, k]
+        gates = topv / topv.sum(-1, keepdims=True)
+        local = topi.reshape(-1) - lo  # [gk]
+        # copies of experts held elsewhere sort last, past every group
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(group)  # stable: ties keep token order
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+            jnp.int32)
+        if _EXPERT_COUNTS is not None:
+            _EXPERT_COUNTS.append(jnp.sum(sizes > 0, dtype=jnp.int32))
+        block_m = _gmm_block_m(gk)
+        pad = (-gk) % block_m
+        xs = jnp.pad(x[order // top_k], ((0, pad), (0, 0)))
+    with _scope("moe_experts"):
+        gu = grouped_matmul(xs, w_gate_up, sizes, block_m=block_m)
+        gu = gu.astype(jnp.float32)
+        a = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(x.dtype)
+        y = grouped_matmul(a, w_down, sizes, block_m=block_m)[:gk]
+        y = y[jnp.argsort(order)].reshape(g, top_k, h)  # (token, choice)
+        # an elementwise product: a contraction would take one bf16 term
+        # of the float32 gates on a TPU
+        out = jnp.sum(gates[..., None] * y.astype(jnp.float32), axis=1)
+    return out.astype(x.dtype)
 
 
 # ------------------------------------------------- global_scatter / gather --

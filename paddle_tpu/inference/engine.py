@@ -47,6 +47,16 @@ static-shape serving discipline on XLA):
   steps, one int32 per slot per step host transfer (``k+1`` for verify),
   absmax-scaled int8 via grad_comm's quantize/dequantize helpers.
 
+* **Block diffusion.** A model whose adapter states ``block_length``
+  (text/models/sdar.py) writes a block of positions at a time: a step is
+  ONE compiled block pass over every slot's current block (all of its
+  positions in place, masked ones as the mask token, under a block-causal
+  horizon), which draws a token at each masked position and unmasks some
+  by the model's rule, on device; tokens are emitted as they become final.
+  The batch moves in rounds of one block: when no slot has a masked
+  position left, ONE compiled commit pass writes every finished block's
+  final keys and values, and only then are new requests admitted.
+
 A model plugs in through ``model.decode_adapter()`` (text/models/gpt.py,
 llama.py): ``embed``, its own block as ``layer`` (calling the engine's
 paged ``attend(q, k, v)`` where full attention stood), ``head``, and the
@@ -94,7 +104,8 @@ _KEY_IMPL = "threefry2x32"
 
 def _program_kind(name: str) -> Tuple[str, int]:
     """A program's family and size: ``("prefill", 512)`` of "prefill_b512",
-    ``("verify", 4)`` of "verify_k4", ``("decode", 0)``."""
+    ``("verify", 4)`` of "verify_k4", ``("decode", 0)``, ``("block", 4)`` of
+    "block_b4" and ``("commit", 4)`` of "commit_b4"."""
     kind, _, size = name.partition("_")
     return kind, int(size[1:] or 0)
 
@@ -221,6 +232,19 @@ class SamplingParams:
 
 
 @dataclass
+class BlockState:
+    """One slot's block in a block-diffusion engine: its positions from
+    ``start``, which of them are still masked (kept by position: a prompt
+    may hold the mask token's id), the tokens of the others, how many were
+    masked when it began and the passes run on it."""
+    start: int
+    tokens: np.ndarray  # [B] int32; a masked position's entry is unused
+    masked: np.ndarray  # [B] bool
+    to_unmask: int
+    passes: int = 0
+
+
+@dataclass
 class Request:
     req_id: int
     prompt: np.ndarray
@@ -265,6 +289,11 @@ class Request:
     #: if the engine promotes a newer epoch mid-flight — the per-epoch
     #: greedy bit-equal contract rides on this
     epoch: int = 0
+    #: a block-diffusion engine's current block of this request
+    block: Optional[BlockState] = None
+    #: generated position -> the pass of its block that unmasked it (0 is
+    #: the block's first), recorded by a block-diffusion engine
+    unmask_pass: Dict[int, int] = field(default_factory=dict)
 
 
 @dataclass
@@ -470,6 +499,32 @@ def _sample_tokens(logits, keys, temperature, top_k, top_p, greedy,
     return jnp.where(greedy, arg, sampled).astype(jnp.int32)
 
 
+def _unmask(drawn, conf, tokens, masked, count, rule, threshold):
+    """Which masked positions of each slot's block a pass unmasks, on
+    device: ``drawn``, ``conf`` [S, B] the tokens drawn at every position
+    and their probabilities, ``tokens``, ``masked`` [S, B] the block's
+    state, ``count`` [S] how many to unmask. ``sequential`` takes the
+    leftmost masked positions, ``low_confidence_static`` the most probable
+    drawn tokens, ``low_confidence_dynamic`` every one above ``threshold``
+    where there are at least ``count``, else as static. Returns the new
+    tokens and mask."""
+    b = masked.shape[1]
+    if rule == "sequential":
+        score = -jnp.broadcast_to(jnp.arange(b, dtype=jnp.float32),
+                                  masked.shape)
+    else:
+        score = conf
+    score = jnp.where(masked, score, -jnp.inf)
+    # rank of each position among its slot's, best first (ties: leftmost)
+    rank = jnp.argsort(jnp.argsort(-score, axis=1, stable=True), axis=1)
+    if rule == "low_confidence_dynamic":
+        count = jnp.maximum(count, jnp.sum(masked & (conf > threshold),
+                                           axis=1, dtype=count.dtype))
+    chosen = masked & (rank < count[:, None])
+    return (jnp.where(chosen, drawn, tokens).astype(jnp.int32),
+            masked & ~chosen)
+
+
 class DecodeEngine:
     """Continuous-batching serving engine over a decoder-only LM.
 
@@ -510,6 +565,18 @@ class DecodeEngine:
         self._loops = int(getattr(ad, "loops", 1))
         self._close_loop = getattr(ad, "close_loop", None)
         self.cache_layers = self._loops * ad.num_layers
+        #: a block-diffusion model's block length (0: one token a pass);
+        #: its block pass and commit pass take the place of decode
+        self._block = int(getattr(ad, "block_length", 0) or 0)
+        if self._block:
+            if cfg.page_size % self._block:
+                raise ValueError(
+                    f"block_length={self._block} must divide page_size="
+                    f"{cfg.page_size}: a registered prompt page must hold "
+                    "whole blocks, whose keys no later position changes")
+            if cfg.speculate_k:
+                raise ValueError("speculate_k must be 0 for a block-"
+                                 "diffusion model: its pass is the block")
         self.buckets = cfg.resolved_buckets()
         self._mp = cfg.max_pages
         self._num_pages = cfg.resolved_num_pages()
@@ -655,6 +722,15 @@ class DecodeEngine:
         self._pages_per_block: Dict[int, int] = {}  # rows a slot -> ppb
         self.spec_proposed = 0
         self.spec_accepted = 0
+        #: a block-diffusion engine's running totals: commit passes; rows
+        #: its passes computed for live slots (prefill rows included); and
+        #: over the block and commit passes, the experts that the routing
+        #: sent tokens to, summed over layers (counted on the device) and
+        #: the experts held, passes x layers x experts a layer
+        self.commit_passes = 0
+        self.rows_computed = 0
+        self.experts_touched = 0
+        self.experts_capacity = 0
         self._t_decode_ema = None
         self._t_verify_ema = None
         self._tok_verify_ema = None
@@ -808,13 +884,25 @@ class DecodeEngine:
                         kv_pages_live=self.kv_pages_live,
                         kv_block_pages=self.kv_block_pages,
                         kv_pages_capacity=self.kv_pages_capacity)
+                    if self._block:
+                        sp.attrs.update(
+                            block_length=self._block,
+                            commit_passes=self.commit_passes,
+                            rows_computed=self.rows_computed,
+                            experts_touched=self.experts_touched,
+                            experts_capacity=self.experts_capacity)
         finally:
             self._report = None
             self.last_step = report
         return busy
 
     def _step(self, sp) -> bool:
-        self._admit()
+        if self._block:
+            # a round of one block ends when no slot has a masked position
+            # left: commit, then admit into the fresh round
+            self._end_block_round()
+        if not self._block or self._at_round_start():
+            self._admit()
         if sp:
             # the batch that the decode pass below runs with
             sp.attrs.update(
@@ -827,6 +915,10 @@ class DecodeEngine:
             return bool(self._waiting)
         self._backoff_s = 0.0
         epochs = sorted({r.epoch for r in self._running.values()})
+        if self._block:
+            for e in epochs:  # one pass a weight epoch, as decode below
+                self._step_block(epoch=e)
+            return True
         if len(epochs) > 1:
             # mixed-epoch flip window: one masked decode per epoch group
             # (excluded slots' table rows are zeroed, so their KV writes
@@ -924,11 +1016,178 @@ class DecodeEngine:
             _obs.inc("serving_tokens_total", len(active))
             self._update_gauges()
 
-    def _count_kv_pages(self, positions, t: int):
-        """One pass's live page slots, from the positions that the pass
-        was called with (0 for a slot that sat it out)."""
-        live = np.minimum(
+    # -- block diffusion: the block pass, the commit pass and emission -----
+
+    def _at_round_start(self) -> bool:
+        """No running slot has run a pass on its current block: the
+        moment a block-diffusion engine admits."""
+        return all(r.block.passes == 0 for r in self._running.values())
+
+    def _end_block_round(self):
+        """When no running slot has a masked position left, commit every
+        slot's finished block (one commit pass a weight epoch) and start
+        its next block, all masked."""
+        run = list(self._running.items())
+        if not run or any(r.block.masked.any() for _, r in run):
+            return
+        b = self._block
+        for e in sorted({r.epoch for _, r in run}):
+            self._step_commit([(s, r) for s, r in run if r.epoch == e], e)
+        for _, req in run:
+            start = req.block.start + b
+            req.block = BlockState(start, np.zeros(b, np.int32),
+                                   np.ones(b, bool), to_unmask=b)
+
+    def _unmask_count(self, blk: BlockState) -> int:
+        """``k_s`` of the block's next pass: its masked count at its start
+        split evenly over ``denoise_steps`` passes, the remainder to the
+        earliest; past them, whatever is still masked."""
+        steps = int(self.adapter.denoise_steps)
+        if blk.passes >= steps:
+            return int(blk.masked.sum())
+        base, rem = divmod(blk.to_unmask, steps)
+        return base + (blk.passes < rem)
+
+    def _block_inputs(self, active):
+        """The block pass's host arrays, in the program's argument order:
+        tokens, masked [S, B], positions [S] (each block's start), tables,
+        keys, the sampling fields, and how many to unmask [S]. A slot that
+        sits the pass out has no masked position and unmasks none."""
+        s, b = self.config.num_slots, self._block
+        tokens = np.zeros((s, b), np.int32)
+        masked = np.zeros((s, b), bool)
+        positions = np.zeros(s, np.int32)
+        count = np.zeros(s, np.int32)
+        temp = np.ones(s, np.float32)
+        top_k = np.zeros(s, np.int32)
+        top_p = np.ones(s, np.float32)
+        greedy = np.ones(s, bool)
+        keys = np.array(np.broadcast_to(self._zero_key,
+                                        (s,) + self._zero_key.shape))
+        for slot, req in active:
+            blk = req.block
+            tokens[slot], masked[slot] = blk.tokens, blk.masked
+            positions[slot] = blk.start
+            count[slot] = self._unmask_count(blk)
+            t_, k_, p_, g_ = req.params.fields()
+            temp[slot], top_k[slot], top_p[slot], greedy[slot] = t_, k_, p_, g_
+            keys[slot] = req.key_np
+        tables = self._tables
+        ids = {slot for slot, _ in active}
+        if len(ids) < len(self._running):
+            tables = self._tables.copy()
+            tables[[sl for sl in self._running if sl not in ids]] = 0
+        return (tokens, masked, positions, tables, keys, temp, top_k, top_p,
+                greedy, count)
+
+    def _step_block(self, epoch: int):
+        """ONE block pass over every running slot of ``epoch``: each masked
+        position gets a token drawn, the model's rule unmasks some, and the
+        tokens that are now final and follow the request's last emitted one
+        are emitted."""
+        if self._acct is not None:
+            self._acct_tick(time.perf_counter())
+        active = [(slot, req) for slot, req in self._running.items()
+                  if req.epoch == epoch]
+        b, name = self._block, f"block_b{self._block}"
+        with _obs.span("eng_block_pass", live=len(active),
+                       rows=len(active) * b) as sp:
+            host = self._block_inputs(active)
+            t0 = time.perf_counter()
+            with _obs.span("eng_block_upload"):
+                dev = [jnp.asarray(a) for a in host]
+            with _obs.span("eng_block_dispatch"):
+                out, logits = self._run(
+                    name, self._state_vals(epoch), self.kv, *dev)
+            with _obs.span("eng_block_readback"):
+                new_tokens, new_masked, touched = (np.asarray(a) for a in out)
+            _obs.observe("serving_decode_step_seconds",
+                         time.perf_counter() - t0)
+            self._last_logits = logits
+            self.decode_steps += 1
+            self.slot_steps += len(active)
+            self.rows_computed += len(active) * b
+            self.experts_touched += int(touched)
+            self.experts_capacity += self.adapter.num_layers * int(
+                self.adapter.num_experts)
+            live = self.kv_pages_live
+            self._count_kv_pages(host[2], b)
+            final = 0
+            for slot, req in active:
+                blk = req.block
+                now = blk.masked & ~new_masked[slot]
+                for i in np.flatnonzero(now):
+                    req.unmask_pass[blk.start + int(i)] = blk.passes
+                blk.tokens = np.where(now, new_tokens[slot], blk.tokens)
+                blk.masked = new_masked[slot].copy()
+                blk.passes += 1
+                if req.decode_t0 is None:
+                    req.decode_t0 = t0
+                req.decode_steps_n += 1
+                final += self._emit_final(req)
+            _obs.inc("serving_tokens_total", final)
+            if sp:
+                sp.attrs.update(final=final, experts_touched=int(touched),
+                                kv_pages=self.kv_pages_live - live)
+            self._update_gauges()
+
+    def _emit_final(self, req: Request) -> int:
+        """Emit, in order, the tokens of the request's block that are final
+        and follow its last emitted one; returns how many."""
+        blk, n = req.block, 0
+        while req.status == "running":
+            i = len(req.prompt) + len(req.tokens) - blk.start
+            if i >= len(blk.masked) or blk.masked[i]:
+                break
+            if req.first_token_time is None:
+                req.first_token_time = time.perf_counter()
+                _obs.observe("serving_ttft_seconds",
+                             req.first_token_time - req.submit_time)
+            self.total_tokens += 1
+            n += 1
+            self._append_token(req, int(blk.tokens[i]))
+        return n
+
+    def _step_commit(self, group, epoch: int):
+        """ONE commit pass: the final tokens of the finished blocks of
+        ``group`` [(slot, request)] through every layer, writing their keys
+        and values over what the block passes left there. Other slots ride
+        along with zeroed table rows (their writes land on the trash page)
+        at position 0."""
+        s, b = self.config.num_slots, self._block
+        tokens = np.zeros((s, b), np.int32)
+        positions = np.zeros(s, np.int32)
+        tables = np.zeros_like(self._tables)
+        for slot, req in group:
+            tokens[slot] = req.block.tokens
+            positions[slot] = req.block.start
+            tables[slot] = self._tables[slot]
+        with _obs.span("eng_block_commit", slots=len(group),
+                       rows=len(group) * b) as sp:
+            touched, _ = self._run(
+                f"commit_b{b}", self._state_vals(epoch), self.kv,
+                *[jnp.asarray(a) for a in (tokens, positions, tables)])
+            touched = int(np.asarray(touched))  # waits for the pass
+            self.commit_passes += 1
+            self.rows_computed += len(group) * b
+            self.experts_touched += touched
+            # the last layer's experts do not run: nothing reads its output
+            self.experts_capacity += (self.adapter.num_layers - 1) * int(
+                self.adapter.num_experts)
+            if sp:
+                sp.attrs.update(experts_touched=touched,
+                                kv_pages=int(self._live_pages(
+                                    positions, b).sum()))
+
+    def _live_pages(self, positions, t: int):
+        """The page slots a pass of ``t`` rows a slot reads, a slot, from
+        the positions it was called with (0 for a slot that sat it out)."""
+        return np.minimum(
             (positions + (t - 1)) // self.config.page_size + 1, self._mp)
+
+    def _count_kv_pages(self, positions, t: int):
+        """One pass's live page slots (``_live_pages``), counted."""
+        live = self._live_pages(positions, t)
         self.kv_pages_live += int(live.sum())
         ppb = self._pages_per_block.get(t)
         if ppb is None:
@@ -1173,7 +1432,9 @@ class DecodeEngine:
         cheap no-op rather than a second full sweep."""
         hits0, n0 = self.aot_cache_hits, self.compile_count
         k = self.config.speculate_k
-        for name in ([f"prefill_b{tb}" for tb in self.buckets] + ["decode"]
+        passes = ([f"block_b{self._block}", f"commit_b{self._block}"]
+                  if self._block else ["decode"])
+        for name in ([f"prefill_b{tb}" for tb in self.buckets] + passes
                      + ([f"verify_k{k}"] if k > 0 else [])):
             if name in self._compiled:
                 self.aot_cache_hits += 1
@@ -1196,6 +1457,12 @@ class DecodeEngine:
         if kind == "prefill":
             inputs = (np.full((1, n), 1, np.int32), zero, np.int32(n),
                       np.zeros(self._mp, np.int32), key, one, zero, one, yes)
+        elif kind == "block":
+            inputs = list(self._block_inputs(()))
+            inputs[3] = np.zeros_like(self._tables)
+        elif kind == "commit":
+            inputs = (np.zeros((s, n), np.int32), np.zeros(s, np.int32),
+                      np.zeros_like(self._tables))
         else:
             t = () if kind == "decode" else (n + 1,)
             inputs = (np.zeros((s,) + t, np.int32), np.zeros(s, np.int32),
@@ -1203,7 +1470,7 @@ class DecodeEngine:
                       np.array(np.broadcast_to(key, (s,) + key.shape)),
                       np.full(s, one), np.full(s, zero), np.full(s, one),
                       np.full(s, yes))
-        return (self._state_vals(), self.kv) + inputs
+        return (self._state_vals(), self.kv) + tuple(inputs)
 
     def stats(self) -> dict:
         return {
@@ -1237,6 +1504,11 @@ class DecodeEngine:
             "attn_kernel": self._attn_kernel,
             "loops": self._loops,
             "cache_layers": self.cache_layers,
+            "block_length": self._block,
+            "commit_passes": self.commit_passes,
+            "rows_computed": self.rows_computed,
+            "experts_touched": self.experts_touched,
+            "experts_capacity": self.experts_capacity,
             "kv_bytes_per_token": self.kv.bytes_per_token,
             "weight_epoch": int(self._epoch),
             "pinned_epochs": sorted(self._epoch_vals),
@@ -1291,6 +1563,10 @@ class DecodeEngine:
         (plus the int8 scale slabs when this pool is int8). Raises
         ValueError on the same bad-request conditions as ``submit``.
         """
+        if self._block:
+            raise NotImplementedError(
+                "a block-diffusion engine hands no prefill over: its first "
+                "token comes from a block pass, not from the prefill")
         if params is None:
             params = SamplingParams(**kw)
         ids = np.asarray(raw(prompt), dtype=np.int32).reshape(-1)
@@ -1416,6 +1692,9 @@ class DecodeEngine:
         (the caller retries next poll). Raises ValueError on bad requests
         or a prompt/payload length mismatch.
         """
+        if self._block:
+            raise NotImplementedError(
+                "a block-diffusion engine takes no prefill from another")
         ids = np.asarray(raw(prompt), dtype=np.int32).reshape(-1)
         t0 = int(ids.shape[0])
         if t0 < 1:
@@ -1670,6 +1949,8 @@ class DecodeEngine:
 
     def _prefill(self, req: Request, slot: int, row: np.ndarray,
                  cached_len: int):
+        if self._block:
+            return self._prefill_blocks(req, slot, row, cached_len)
         t0 = int(req.prompt.shape[0])
         rid = req.req_id
         with _obs.span("eng_prefill_prep", rid=rid):
@@ -1709,6 +1990,46 @@ class DecodeEngine:
         self.prompt_tokens_total += t0
         _obs.inc("serving_tokens_total")
         self._append_token(req, token)
+
+    def _prefill_blocks(self, req: Request, slot: int, row: np.ndarray,
+                        cached_len: int):
+        """A block-diffusion admission: prefill the prompt's whole blocks
+        past the shared prefix (none, where the prefix covers them), then
+        seat the request with its first block, the prompt's tail known and
+        the rest masked. Its first token comes from the block pass."""
+        t0, b = int(req.prompt.shape[0]), self._block
+        start = t0 // b * b
+        rid = req.req_id
+        tp0 = time.perf_counter()
+        if start > cached_len:
+            with _obs.span("eng_prefill_prep", rid=rid):
+                tail = req.prompt[cached_len:start]
+                tb = self._bucket_for(len(tail))
+                ids = np.zeros((1, tb), np.int32)
+                ids[0, :len(tail)] = tail
+                args = (jnp.asarray(ids), np.int32(cached_len),
+                        np.int32(start), jnp.asarray(row),
+                        jnp.asarray(req.key_np), np.float32(1.0),
+                        np.int32(0), np.float32(1.0), np.asarray(True))
+            with _obs.span("eng_prefill_dispatch", rid=rid, bucket=int(tb)):
+                nxt, _ = self._run(
+                    f"prefill_b{tb}", self._state_vals(), self.kv, *args)
+            with _obs.span("eng_prefill_readback", rid=rid):
+                jax.block_until_ready(nxt)  # its token is not used
+            self.rows_computed += start - cached_len
+        req.prefill_t0 = tp0
+        req.prefill_s = time.perf_counter() - tp0
+        tokens = np.zeros(b, np.int32)
+        tokens[:t0 - start] = req.prompt[start:]
+        req.block = BlockState(start, tokens, np.arange(b) >= t0 - start,
+                               to_unmask=b - (t0 - start))
+        req.slot = slot
+        req.status = "running"
+        req.epoch = self._epoch
+        self._running[slot] = req
+        if self._report is not None:
+            self._report.admitted.append(rid)
+        self.prompt_tokens_total += t0
 
     def _append_token(self, req: Request, token: int):
         req.tokens.append(token)
@@ -1843,6 +2164,8 @@ class DecodeEngine:
             fn = self._jit[name] = (
                 self._build_decode() if kind == "decode"
                 else self._build_verify(n + 1) if kind == "verify"
+                else self._build_block(n) if kind == "block"
+                else self._build_commit(n) if kind == "commit"
                 else self._build_prefill(n))
         return fn
 
@@ -2051,7 +2374,8 @@ class DecodeEngine:
                 lambda pool, l, k, v: pool.write_block(
                     l, k, v, row, cached_len, true_len),
                 lambda pool, l, q: pool.attend_block(
-                    q, l, row, cached_len, self._attn_kernel),
+                    q, l, row, cached_len, self._attn_kernel,
+                    self._block or 1),
                 lambda x, head: head(jax.lax.dynamic_slice_in_dim(
                     x, true_len - 1 - cached_len, 1, 1))[:, 0])
             # sample stream keyed by DESTINATION position: token landing at
@@ -2104,5 +2428,73 @@ class DecodeEngine:
                     jax.random.fold_in, in_axes=(None, 0)))(
                     jax.random.wrap_key_data(keys, impl=_KEY_IMPL), pos2 + 1)
                 return pool, *self._sample(logits, step_keys, *sampling)
+
+        return self._program(body)
+
+    def _block_forward(self, pool, ids, positions, tables, b, read):
+        """The forward of the block and commit passes: every slot's ``b``
+        positions from its ``positions`` entry written to its pages in
+        place and attended under the block horizon. Returns the pool, what
+        ``read`` takes of the last hidden state, the positions [S, b] and
+        each routed layer's count of experts touched (``moe.count_experts``)."""
+        from ..incubate import moe
+
+        pos2 = positions[:, None] + jnp.arange(b, dtype=jnp.int32)[None]
+        with moe.count_experts() as counts:
+            pool, out = self._forward(
+                pool, ids, pos2,
+                lambda pool, l, k, v: pool.write_tokens(
+                    l, k, v, tables, pos2),
+                lambda pool, l, q: pool.attend(
+                    q, l, tables, positions, self._attn_kernel, b),
+                read)
+        return pool, out, pos2, counts
+
+    def _build_block(self, b: int):
+        """The block pass of a block-diffusion model: every slot's block of
+        ``b`` positions, masked ones as the mask token (``_block_forward``);
+        a token drawn at every position on its position-keyed stream, and
+        the model's rule unmasking ``count`` of each slot's masked ones.
+        Returns ``(tokens, masked, experts touched)`` and the logits."""
+        ad = self.adapter
+        mask_id = int(ad.mask_token_id)
+        rule, thr = ad.remasking, float(ad.confidence_threshold)
+
+        def body(pool, tokens, masked, positions, tables, keys, temp, top_k,
+                 top_p, greedy, count):
+            pool, logits, pos2, counts = self._block_forward(
+                pool, jnp.where(masked, mask_id, tokens), positions, tables,
+                b, lambda x, head: head(x))  # [S, B, V]
+            touched = sum(counts, jnp.int32(0))
+            with _scope("sample"):
+                # the token AT position p is drawn on fold_in(key, p)
+                step_keys = jax.vmap(jax.vmap(
+                    jax.random.fold_in, in_axes=(None, 0)))(
+                    jax.random.wrap_key_data(keys, impl=_KEY_IMPL), pos2)
+                drawn, logits = self._sample(logits, step_keys, temp, top_k,
+                                             top_p, greedy)
+                t = jnp.where(greedy, 1.0, temp)[:, None, None]
+                conf = jnp.take_along_axis(
+                    jax.nn.softmax(logits / t, axis=-1), drawn[..., None],
+                    -1)[..., 0]
+            with _scope("unmask"):
+                tokens, masked = _unmask(drawn, conf, tokens, masked, count,
+                                         rule, thr)
+            return pool, (tokens, masked, touched), logits
+
+        return self._program(body)
+
+    def _build_commit(self, b: int):
+        """The commit pass of a block-diffusion model: each listed slot's
+        final block through every layer (``_block_forward``), its keys and
+        values written where the block passes wrote theirs. Nothing reads
+        the last layer's output, so neither its attention nor its experts
+        run. Returns the experts touched in the layers whose experts ran."""
+        def body(pool, tokens, positions, tables):
+            pool, _, _, counts = self._block_forward(
+                pool, tokens, positions, tables, b,
+                lambda x, head: jnp.zeros((1,), jnp.float32))
+            touched = sum(counts[:-1], jnp.int32(0))
+            return pool, touched, jnp.zeros((1,), jnp.float32)
 
         return self._program(body)

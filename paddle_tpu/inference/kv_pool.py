@@ -188,9 +188,10 @@ class KVPool:
                               positions)
         return KVPool(kc, vc, ks, vs)
 
-    def attend(self, q, layer, tables, positions, kernel):
+    def attend(self, q, layer, tables, positions, kernel, block=1):
         """One layer of paged attention, q [S, T, H, D] from ``positions``
-        [S] on. The fused Pallas path hands the kernel the whole STORED
+        [S] on (block-causal over blocks of ``block`` positions where that
+        is > 1: ``F.paged_attention``). The fused Pallas path hands the kernel the whole STORED
         pool and the layer's index, which its index maps read, so the pool
         is never sliced — plus the layer's absmax scale slabs when int8
         (1 MB, sliced: the kernel wants them with a trailing 1, which the
@@ -206,15 +207,15 @@ class KVPool:
                 q, self.k, self.v, tables, positions, layer=layer,
                 k_scales=None if ks is None else ks[layer],
                 v_scales=None if vs is None else vs[layer],
-                kernel="pallas")
+                kernel="pallas", block=block)
         k, v = self.k[layer], self.v[layer]
         if ks is not None:
             k = dequantize_absmax(k, ks[layer][..., None])
             v = dequantize_absmax(v, vs[layer][..., None])
         return F.paged_attention(q, k, v, tables, positions,
-                                 kernel="einsum")
+                                 kernel="einsum", block=block)
 
-    def attend_block(self, q, layer, row, cached_len, kernel):
+    def attend_block(self, q, layer, row, cached_len, kernel, block=1):
         """One layer of a prompt tail's attention: q [1, TB, H, D] from
         position ``cached_len`` on, over the pages of ``row`` [MP], the
         tail's own (``write_block`` comes first) and the cached prefix's
@@ -228,7 +229,7 @@ class KVPool:
         table of one slot."""
         if kernel != "pallas":
             return self.attend(q, layer, row[None],
-                               jnp.reshape(cached_len, (1,)), kernel)
+                               jnp.reshape(cached_len, (1,)), kernel, block)
 
         def keys(cache):  # [L, N, Hkv, P, ...] -> [Hkv, MP * P, ...]
             g = jnp.swapaxes(cache[layer, row], 0, 1)
@@ -238,7 +239,7 @@ class KVPool:
         return F.prefill_attention(
             q, keys(self.k), keys(self.v), cached_len,
             k_scales=None if ks is None else keys(ks),
-            v_scales=None if vs is None else keys(vs))
+            v_scales=None if vs is None else keys(vs), block=block)
 
     # -- the handoff between engines (eager) --------------------------------
 

@@ -273,7 +273,7 @@ def resolve_attn_kernel(kernel=None) -> str:
 
 @defop(amp="white", name="paged_attention_pallas_op")
 def _paged_attention_pallas_op(q, pk, pv, k_scales, v_scales, page_table,
-                               start_position, scale, layer=None):
+                               start_position, scale, layer=None, block=1):
     """Fused-kernel twin of :func:`_paged_attention_op`: the pool streams
     HBM→VMEM at its stored dtype (int8 dequant fused against the absmax
     scales inside the kernel) and the softmax runs online — no gathered
@@ -284,13 +284,13 @@ def _paged_attention_pallas_op(q, pk, pv, k_scales, v_scales, page_table,
 
     out = _pa.paged_attention(
         q, pk, pv, page_table, start_position, layer=layer, scale=scale,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, block=block)
     return out.astype(q.dtype)
 
 
 @defop(amp="white", name="paged_attention_op")
 def _paged_attention_op(q, pk, pv, k_scales, v_scales, page_table,
-                        start_position, scale):
+                        start_position, scale, block=1):
     """KV-cached attention through a block/page-granular cache.
 
     q: [S, T, H, D] — T new tokens per slot (T=1 decode, T=k+1 speculative
@@ -301,7 +301,9 @@ def _paged_attention_op(q, pk, pv, k_scales, v_scales, page_table,
     j % P (unallocated entries point at the reserved trash page 0 and are
     masked); start_position: [S] int — query row i of slot s sits at
     global position start_position[s] + i and attends to key positions
-    <= its own (causal over the virtual sequence). GQA-native: query
+    <= its own (causal over the virtual sequence), or with ``block`` > 1
+    every key up to the end of its block of ``block`` positions
+    (block-causal, a block-diffusion model's block pass). GQA-native: query
     heads are grouped onto their kv head, no head replication in HBM.
     """
     s_, t, h, d = q.shape
@@ -331,6 +333,8 @@ def _paged_attention_op(q, pk, pv, k_scales, v_scales, page_table,
         qf = _shard_heads(qf, 2, mesh)
     logits = jnp.einsum("sthgd,shkd->shgtk", qf, k) * sc
     qpos = start_position[:, None] + jnp.arange(t)[None, :]       # [S, T]
+    if block > 1:
+        qpos = (qpos // block + 1) * block - 1  # the end of the row's block
     mask = jnp.arange(mp * p)[None, None, :] <= qpos[:, :, None]  # [S, T, K]
     logits = jnp.where(mask[:, None, None, :, :], logits, _MASK_FILL)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -341,7 +345,7 @@ def _paged_attention_op(q, pk, pv, k_scales, v_scales, page_table,
 
 def paged_attention(query, pool_k, pool_v, page_table, start_position,
                     scale=None, k_scales=None, v_scales=None, kernel=None,
-                    layer=None, name=None):
+                    layer=None, block=1, name=None):
     """Multi-token KV-cached attention against a paged cache (the
     page-granular companion of :func:`decode_attention`; see
     docs/SERVING.md §paged cache). ``query`` [S, T, H, D]; ``pool_k/v``
@@ -366,7 +370,11 @@ def paged_attention(query, pool_k, pool_v, page_table, start_position,
     implementation (see :func:`resolve_attn_kernel`): the fused Pallas
     kernel streams pages at their stored dtype with dequant fused in;
     the einsum oracle dequantizes up front. An mp-sharded pool always
-    takes the einsum path — the GSPMD sharding annotations live there."""
+    takes the einsum path — the GSPMD sharding annotations live there.
+
+    ``block`` > 1 makes the mask block-causal: positions are counted in
+    blocks of ``block`` from 0 and a query sees every key up to the end of
+    its own block (a block-diffusion model's block pass); 1 is causal."""
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
     choice = resolve_attn_kernel(kernel)
@@ -375,26 +383,26 @@ def paged_attention(query, pool_k, pool_v, page_table, start_position,
         if mp_deg == 1:
             return _paged_attention_pallas_op(
                 query, pool_k, pool_v, k_scales, v_scales, page_table,
-                start_position, scale, layer)
+                start_position, scale, layer, block)
     if layer is not None:
         pool_k, pool_v = pool_k[layer], pool_v[layer]
     return _paged_attention_op(query, pool_k, pool_v, k_scales, v_scales,
-                               page_table, start_position, scale)
+                               page_table, start_position, scale, block)
 
 
 @defop(amp="white", name="prefill_attention_pallas_op")
 def _prefill_attention_pallas_op(q, k, v, k_scales, v_scales, cached_len,
-                                 scale):
+                                 scale, block=1):
     from ...ops.pallas import prefill_attention as _pf
 
     out = _pf.prefill_attention(
         q[0], k, v, cached_len, scale=scale, k_scales=k_scales,
-        v_scales=v_scales)
+        v_scales=v_scales, block=block)
     return out[None].astype(q.dtype)
 
 
 def prefill_attention(query, keys, values, cached_len, scale=None,
-                      k_scales=None, v_scales=None, name=None):
+                      k_scales=None, v_scales=None, block=1, name=None):
     """Causal attention of ONE sequence's new rows over its contiguous
     keys: what the serving engine's tail prefill runs on its fused path
     (docs/SERVING.md §paged cache), in row blocks against key blocks of
@@ -406,9 +414,10 @@ def prefill_attention(query, keys, values, cached_len, scale=None,
     ([Hkv, K] f32, both or neither) mark int8 absmax-quantized keys.
     :func:`paged_attention` with ``kernel="einsum"`` on the same pages is
     its oracle: greedy argmax equal, raw outputs within f32 tolerance
-    (tests/test_pallas_attention.py)."""
+    (tests/test_pallas_attention.py). ``block`` as in
+    :func:`paged_attention`."""
     return _prefill_attention_pallas_op(
-        query, keys, values, k_scales, v_scales, cached_len, scale)
+        query, keys, values, k_scales, v_scales, cached_len, scale, block)
 
 
 @defop(name="sparse_attention_op")

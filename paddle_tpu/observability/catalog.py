@@ -538,7 +538,13 @@ SPANS = {
         "page slots those passes' attention had to read and of the page "
         "slots their tables held; kv_block_pages, the page slots of the "
         "blocks that the paged kernel's walk takes for them, "
-        "ceil(live / pages a block) blocks a slot)"),
+        "ceil(live / pages a block) blocks a slot; a block-diffusion "
+        "engine's steps add block_length and its running totals "
+        "commit_passes, rows_computed (positions its passes computed for "
+        "live slots, prefills included), experts_touched (experts the "
+        "routing sent a token to, summed over layers and over block and "
+        "commit passes, counted on the device) and experts_capacity "
+        "(passes x layers x experts held a layer))"),
     "eng_admit": (
         "paddle_tpu/inference/engine.py",
         "One admission attempt, child of eng_step: page reservation, "
@@ -574,6 +580,29 @@ SPANS = {
         "paddle_tpu/inference/engine.py",
         "Decode, host: tokens appended, requests finished, pages freed, "
         "gauges"),
+    "eng_block_pass": (
+        "paddle_tpu/inference/engine.py",
+        "A block-diffusion engine's block pass, child of eng_step: inputs, "
+        "upload, the compiled pass over every slot's block, read-back and "
+        "emission (attrs: live slots, rows = live x block_length, final = "
+        "tokens emitted, experts_touched in this pass, kv_pages its "
+        "attention read)"),
+    "eng_block_upload": (
+        "paddle_tpu/inference/engine.py",
+        "Block pass, host: the per-slot arrays to the device"),
+    "eng_block_dispatch": (
+        "paddle_tpu/inference/engine.py",
+        "Block pass, host: the call that enqueues the compiled block pass"),
+    "eng_block_readback": (
+        "paddle_tpu/inference/engine.py",
+        "Block pass: the blocking read of the blocks' tokens and masks, "
+        "i.e. the wait for the device"),
+    "eng_block_commit": (
+        "paddle_tpu/inference/engine.py",
+        "A block-diffusion engine's commit pass, child of eng_step: every "
+        "finished block's final tokens through the layers, their keys and "
+        "values written, at the start of a round (attrs: slots, rows, "
+        "experts_touched, kv_pages); waits for the device"),
     "eng_verify_prep": (
         "paddle_tpu/inference/engine.py",
         "Speculative verify step, host: as eng_decode_prep, k+1 tokens a "

@@ -46,7 +46,10 @@ that walks the KV that is live, not the page table's capacity:
     V either way) — the f32 pool is never materialized, in HBM or VMEM;
   * decode (T=1) and speculative verify (T=k+1) are the SAME kernel: all
     T positions score in one pass, each row masked at its own causal
-    horizon ``start_position + t``. It is correct at any T, and until PR
+    horizon ``start_position + t``; a block-diffusion model's block pass
+    (T = the block length, ``block`` > 1) rounds that horizon up to the
+    end of the row's block of ``block`` positions (``block_horizon``), so
+    that every row of a block sees all of its keys. It is correct at any T, and until PR
     34 the engine's tail prefill (S=1, T=bucket) ran it too, at under 2%
     of the MXU: a prefill wants many rows against long contiguous key
     blocks, and now gathers its slot's pages and runs
@@ -169,17 +172,30 @@ def block_shape(t, heads, kv_heads, d, p, kv_itemsize, has_scales):
     return hb, _pages_per_block(rows8, d, p, hb, kv_itemsize, has_scales)
 
 
-def _last_live_page(sp_ref, s_i, t, page_size, num_page_slots):
+def block_horizon(position, block):
+    """The last key position a query at ``position`` sees when positions
+    are counted in blocks of ``block`` from 0 and a block's queries see
+    all of its keys: the end of the query's block. ``block`` 1 is plain
+    causal and returns ``position`` itself, traced as before."""
+    if block == 1:
+        return position
+    return (position // block + 1) * block - 1
+
+
+def _last_live_page(sp_ref, s_i, t, page_size, num_page_slots, block=1):
     """The last page slot that any query row of slot ``s_i`` can see: row
-    ``t - 1`` sits at position ``start_position + t - 1``. Everything past
-    it is masked for every row, so the walk neither fetches nor multiplies
-    it. An idle slot (position 0) has one live page slot."""
+    ``t - 1`` sits at position ``start_position + t - 1``, whose horizon is
+    the end of its block. Everything past it is masked for every row, so
+    the walk neither fetches nor multiplies it. An idle slot (position 0)
+    has one live page slot (a block's worth of keys, which a page holds)."""
     return jnp.minimum(
-        jax.lax.div(sp_ref[s_i] + (t - 1), page_size), num_page_slots - 1)
+        jax.lax.div(block_horizon(sp_ref[s_i] + (t - 1), block), page_size),
+        num_page_slots - 1)
 
 
 def _paged_kernel(
     *refs, scale, num_page_slots, groups, rows, t, fill, has_scales,
+    block=1,
 ):
     """One grid step = one (slot, block of kv heads) pair; the body walks
     the slot's live pages in blocks of ``ppb``.
@@ -219,7 +235,8 @@ def _paged_kernel(
 
     def live_pages(s_i, b):
         """How many page slots of block ``b`` of slot ``s_i`` are live."""
-        last = _last_live_page(sp_ref, s_i, t, page_size, num_page_slots)
+        last = _last_live_page(sp_ref, s_i, t, page_size, num_page_slots,
+                               block)
         return jnp.clip(last + 1 - b * ppb, 0, ppb)
 
     def copies(s_i, h_i, b, buf, i):
@@ -290,14 +307,14 @@ def _paged_kernel(
     acc_scr[:] = jnp.zeros_like(acc_scr)
     parity = par_ref[0]
     num_blocks = jax.lax.div(
-        _last_live_page(sp_ref, s_idx, t, page_size, num_page_slots),
+        _last_live_page(sp_ref, s_idx, t, page_size, num_page_slots, block),
         ppb) + 1
     # the grid step after this one, whose first block this one's last
     # block prefetches
     h_next = jax.lax.rem(h_idx + 1, num_h)
     s_next = s_idx + jax.lax.div(h_idx + 1, num_h)
 
-    def block(b, carry):
+    def walk(b, carry):
         buf = jax.lax.rem(parity + b, 2)
 
         @pl.when(b + 1 < num_blocks)
@@ -337,10 +354,10 @@ def _paged_kernel(
 
         shape = s_log.shape[1:]
         row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        qpos = sp_ref[s_idx] + row // groups
+        qpos = block_horizon(sp_ref[s_idx] + row // groups, block)
         kpos = b * block_keys + jax.lax.broadcasted_iota(
             jnp.int32, shape, 1)
-        # causal at each row's own horizon; padding rows (row >= rows) are
+        # causal at each row's own horizon (its block's end); padding rows (row >= rows) are
         # fully masked and sliced off by the wrapper. Trash, unallocated
         # and unfetched page slots mask themselves: their virtual
         # positions exceed the horizon.
@@ -378,7 +395,7 @@ def _paged_kernel(
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
         return carry
 
-    jax.lax.fori_loop(0, num_blocks, block, 0)
+    jax.lax.fori_loop(0, num_blocks, walk, 0)
     par_ref[0] = jax.lax.rem(parity + num_blocks, 2)
     safe = jnp.maximum(l_scr[:, :, :1], 1e-30)
     o_ref[0] = (acc_scr[:] / safe).astype(o_ref.dtype)
@@ -395,6 +412,7 @@ def paged_attention(
     scale=None,
     k_scales=None,
     v_scales=None,
+    block=1,
     interpret=None,
 ):
     """Fused paged attention over a page-table-indirected KV pool.
@@ -421,6 +439,10 @@ def paged_attention(
             passing them turns on fused int8 dequant (both or neither).
             ONE layer's slab also beside a stacked pool: stacked, its
             trailing-1 reshape below would pad every lane to 128.
+        block: positions are counted in blocks of this many from 0, and
+            a query sees every key of its own block (``block_horizon``):
+            a block-diffusion model's block pass. 1 (the default) is
+            plain causal.
         interpret: force pallas interpret mode; default: interpret
             everywhere except on a real TPU backend.
 
@@ -454,13 +476,13 @@ def paged_attention(
         start_position.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), k_scales, v_scales,
         scale=float(scale) if scale is not None else 1.0 / math.sqrt(d),
-        hb=hb, ppb=ppb, interpret=interpret)
+        hb=hb, ppb=ppb, interpret=interpret, block=int(block))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "hb", "ppb", "interpret"))
+    jax.jit, static_argnames=("scale", "hb", "ppb", "interpret", "block"))
 def _walk_call(q, k_pool, v_pool, page_table, start_position, layer,
-               k_scales, v_scales, *, scale, hb, ppb, interpret):
+               k_scales, v_scales, *, scale, hb, ppb, interpret, block=1):
     """The call at its block sizes. Jitted, so that a program which makes
     it once a layer traces and lowers the kernel once, not once a layer
     (the GPT decode program unrolls 24; XLA inlines the calls: the
@@ -515,7 +537,7 @@ def _walk_call(q, k_pool, v_pool, page_table, start_position, layer,
     kernel = functools.partial(
         _paged_kernel, scale=scale, num_page_slots=mp, groups=groups,
         rows=rows, t=t, fill=mask_fill_value(jnp.float32),
-        has_scales=has_scales,
+        has_scales=has_scales, block=block,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

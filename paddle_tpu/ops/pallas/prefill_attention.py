@@ -21,6 +21,10 @@ kernel's grid:
     int32, so it travels as a scalar-prefetch operand;
   * key blocks wholly at or below the block's FIRST row's horizon take the
     body without the mask's iotas, compares and selects;
+  * block-causal where the model asks for it (``block`` > 1, a
+    block-diffusion model's prefill of whole blocks): a row's horizon is
+    rounded up to the end of its block of ``block`` positions, the paged
+    kernel's ``block_horizon``; ``block`` 1 is plain causal;
   * GQA-native as the paged kernel: the G query heads of a kv head ride in
     the row dimension (``rows = T * G``);
   * the same arithmetic as the paged kernel and the einsum oracle
@@ -46,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import mask_fill_value
+from .paged_attention import block_horizon, mask_fill_value
 
 #: the largest row and key blocks (``_block_sizes``). Swept on a v5e inside
 #: the engine's own prefill programs at GPT-3 1.3B widths
@@ -77,18 +81,20 @@ def _block_sizes(rows, keys):
     return min(_BLOCK_Q, _round_up(rows, 16)), block_k
 
 
-def _last_key_block(cached_len, i, block_q, block_k, groups, num_k):
+def _last_key_block(cached_len, i, block_q, block_k, groups, num_k,
+                    block=1):
     """The last key block that any row of row block ``i`` can see: its
     last row sits at position ``cached_len + ((i + 1) * block_q - 1) //
-    groups``. Single source for the body's gate and the index maps' clamp,
-    so the two cannot drift."""
-    horizon = cached_len + ((i + 1) * block_q - 1) // groups
+    groups`` and sees to the end of its block. Single source for the
+    body's gate and the index maps' clamp, so the two cannot drift."""
+    horizon = block_horizon(
+        cached_len + ((i + 1) * block_q - 1) // groups, block)
     return jnp.minimum(jax.lax.div(horizon, block_k), num_k - 1)
 
 
 def _prefill_kernel(
     *refs, scale, block_q, block_k, groups, rows, keys, keys_p, fill,
-    has_scales,
+    has_scales, block=1,
 ):
     """One grid step = one (kv head, row block, key block) triple; m / l /
     acc scratch carries the online softmax across a row block's key blocks.
@@ -130,7 +136,8 @@ def _prefill_kernel(
             # causal at each row's own horizon; padding rows (row >= rows)
             # are fully masked and sliced off by the wrapper
             mask = jnp.logical_and(
-                kpos <= cached_len + row // groups, row < rows)
+                kpos <= block_horizon(cached_len + row // groups, block),
+                row < rows)
             if keys_p != keys:
                 mask = jnp.logical_and(mask, kpos < keys)
             s_log = jnp.where(mask, s_log, fill)
@@ -152,11 +159,12 @@ def _prefill_kernel(
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     live = j <= _last_key_block(cached_len, i, block_q, block_k, groups,
-                                num_k)
+                                num_k, block)
     # every key of the block at or below the horizon of the block's FIRST
     # row, no padding row and no padding key: nothing to mask
     clear = jnp.logical_and(
-        (j + 1) * block_k - 1 <= cached_len + (i * block_q) // groups,
+        (j + 1) * block_k - 1 <= block_horizon(
+            cached_len + (i * block_q) // groups, block),
         jnp.logical_and((i + 1) * block_q <= rows,
                         (j + 1) * block_k <= keys))
     pl.when(jnp.logical_and(live, clear))(lambda: update(False))
@@ -178,6 +186,7 @@ def prefill_attention(
     scale=None,
     k_scales=None,
     v_scales=None,
+    block=1,
     interpret=None,
 ):
     """Causal attention of one sequence's new rows over its contiguous
@@ -194,6 +203,8 @@ def prefill_attention(
         scale: logit scale; defaults to ``1/sqrt(D)``.
         k_scales, v_scales: optional ``[Hkv, K]`` f32 absmax scales —
             passing them turns on fused int8 dequant (both or neither).
+        block: a row sees to the end of its block of this many positions
+            (counted from 0); 1 (the default) is plain causal.
         interpret: force pallas interpret mode; default: interpret
             everywhere except on a real TPU backend.
 
@@ -213,13 +224,15 @@ def prefill_attention(
         q, k, v, jnp.asarray(cached_len, jnp.int32).reshape(1), k_scales,
         v_scales, scale=float(scale) if scale is not None else (
             1.0 / math.sqrt(d)),
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        block=int(block))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret"))
+    jax.jit,
+    static_argnames=("scale", "block_q", "block_k", "interpret", "block"))
 def _blocked_call(q, k, v, cached_len, k_scales, v_scales, *, scale,
-                  block_q, block_k, interpret):
+                  block_q, block_k, interpret, block=1):
     """The call at its block sizes. Jitted, so that a program which makes
     it once a layer traces and lowers the kernel once, not once a layer
     (the engine's prefill programs lower in 0.9 s for 1.7; XLA inlines the
@@ -252,7 +265,8 @@ def _blocked_call(q, k, v, cached_len, k_scales, v_scales, *, scale,
 
     def key_index(h_i, i, j, cl_ref):
         return (h_i, jnp.minimum(j, _last_key_block(
-            cl_ref[0], i, block_q, block_k, groups, keys_p // block_k)), 0)
+            cl_ref[0], i, block_q, block_k, groups, keys_p // block_k,
+            block)), 0)
 
     in_specs = [pl.BlockSpec((1, block_q, d), q_index),
                 pl.BlockSpec((1, block_k, d), key_index),
@@ -264,6 +278,7 @@ def _blocked_call(q, k, v, cached_len, k_scales, v_scales, *, scale,
         _prefill_kernel, scale=scale, block_q=block_q, block_k=block_k,
         groups=groups, rows=rows, keys=keys, keys_p=keys_p,
         fill=mask_fill_value(jnp.float32), has_scales=has_scales,
+        block=block,
     )
     scratch = [pltpu.VMEM(s, jnp.float32) for s in (
         (block_q, 128), (block_q, 128), (block_q, d))]

@@ -32,3 +32,9 @@ from .ouro import (  # noqa: F401
     OuroForCausalLM,
     OuroModel,
 )
+from .sdar import (  # noqa: F401
+    SDARConfig,
+    SDARDecoderLayer,
+    SDARForCausalLM,
+    SDARModel,
+)

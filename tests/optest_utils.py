@@ -332,6 +332,8 @@ OVERRIDES = {
     "gqa_flash_attention": lambda: (
         [_f((1, 4, 2, 8)), _f((1, 4, 1, 8)), _f((1, 4, 1, 8))],
         {"causal": True}),
+    "block_causal_attention": lambda: (
+        [_f((1, 4, 2, 8)), _f((1, 4, 1, 8)), _f((1, 4, 1, 8)), 2], {}),
     "flash_attn_unpadded_op": lambda: (
         [_f((6, 2, 8)), _f((6, 2, 8)), _f((6, 2, 8)),
          np.array([0, 3, 6], np.int32), np.array([0, 3, 6], np.int32),
@@ -455,6 +457,8 @@ OVERRIDES = {
     "moe_apply_dropless": lambda: (
         [_f((6, 4)), _f((6, 3)), _f((3, 4, 8)), _f((3, 1, 8)),
          _f((3, 8, 4)), _f((3, 1, 4)), jax.nn.gelu, 2], {}),
+    "moe_routed_experts": lambda: (
+        [_f((6, 4)), _f((4, 3)), _f((3, 4, 8)), _f((3, 4, 4)), 2], {}),
     "fused_ec_moe_op": lambda: (
         [_f((2, 3, 4)), _f((2, 3, 3)), _f((3, 4, 8)), _f((3, 1, 8)),
          _f((3, 8, 4)), _f((3, 1, 4)), "gelu", 3], {}),

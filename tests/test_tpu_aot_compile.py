@@ -601,3 +601,97 @@ def test_the_looped_decode_kernels_line_is_what_the_benchmark_looks_for(
     for ln in lines:
         assert _decode_kernel_pattern(8).search(ln), ln
         assert re.search(r"%paged_attention(\.\d+)? = f32\[8,16,8,128\]", ln)
+
+
+# -- a block-diffusion model's engine programs: block, commit, prefill -------
+#
+# SDAR-30B-A3B (text/models/sdar.py) at its widths: 32 query heads on 4 kv
+# heads of 128 (8 a kernel row group, 4 rows a slot: 32 rows), 16 slots,
+# page 16, and a routed-expert layer whose grouped products run inside the
+# program. Two layers (the commit pass runs no experts in its last) and 16
+# of the 128 experts held, an expert-parallel rank's share: the grouped
+# kernel's blocks are an expert's whatever the count. The table is 3072
+# positions a slot so that a K pool is the served cell's 100.7 MB (6 layers
+# x 1024): a smaller pool XLA takes into VMEM whole (the note above
+# ENGINE_LAYERS).
+
+SDAR_PROGRAMS = ("block_b4", "commit_b4", "prefill_b512")
+
+
+@pytest.fixture(scope="module")
+def sdar_engine_programs(one_chip):
+    from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
+    from paddle_tpu.text.models import SDARConfig, SDARForCausalLM
+
+    made = {}
+
+    def text_of(program):
+        if program not in made:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                if "engine" not in made:
+                    model = SDARForCausalLM(SDARConfig(
+                        vocab_size=1024, num_hidden_layers=2,
+                        max_position_embeddings=3072, expert_range=(0, 16),
+                        expert_dtype="bfloat16", remasking="sequential",
+                    )).astype("bfloat16")
+                    model.eval()
+                    made["engine"] = DecodeEngine(model, EngineConfig(
+                        num_slots=16, max_length=3072, page_size=16,
+                        kv_dtype="bf16", prompt_buckets=(128, 256, 512)))
+                eng = made["engine"]
+                assert eng.stats()["attn_kernel"] == "pallas" and eng._donate
+                made[program] = eng._jitted(program).lower(*jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        np.shape(a), a.dtype, sharding=one_chip),
+                    eng._example_args(program))).compile().as_text()
+        return made[program], made["engine"].kv.shape
+
+    return text_of
+
+
+def _metric_pattern(name, **values):
+    import json
+    import os
+    import string
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "metrics", name + ".json")) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    return re.compile(string.Template(pattern).substitute(values))
+
+
+@pytest.mark.parametrize("program", SDAR_PROGRAMS)
+def test_block_engine_program_keeps_the_pool_and_runs_its_kernels(
+        sdar_engine_programs, program):
+    """Every array of the pool's shape keeps the row-major layout, nothing
+    as large as a layer's pool is copied or re-laid, and the passes call the
+    paged kernel at 4 rows a slot and the grouped kernel, each on the ONE
+    line the benchmark's block readers look for (a prefill's grouped calls
+    have more rows and are left out of them)."""
+    text, pool_shape = sdar_engine_programs(program)
+    dims = ",".join(str(n) for n in pool_shape)
+    assert set(re.findall(rf"\w+\[{dims}\]\{{([\d,]*)", text)) == {
+        "4,3,2,1,0"}
+    big = int(np.prod(pool_shape[1:]))
+    moved = [ln for ln in _pool_sized_traffic(text, big)
+             if any(len(d.split(",")) > 3 and _elements(f"x[{d}]") >= big
+                    for d in re.findall(r"\w+\[([\d,]*)\]", ln))]
+    assert moved == []
+    lines = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    attn = [ln for ln in lines if "attention" in ln]
+    gmm = [ln for ln in lines if "grouped_matmul_fwd" in ln]
+    paged = _metric_pattern("block_attn_roofline", num_slots=16)
+    routed = _metric_pattern("moe_gmm_hbm_roofline", rows=16 * 4 * 8)
+    if program == "prefill_b512":
+        assert all("prefill_attention" in ln for ln in attn) and len(attn) == 2
+        assert len(gmm) == 4 and not any(routed.search(ln) for ln in gmm)
+        return
+    assert all(re.search(r"paged_attention(\.\d+)? = f32\[16,4,32,128\]", ln)
+               and paged.search(ln) for ln in attn)
+    assert all(routed.search(ln) for ln in gmm)
+    # the commit pass runs neither the attention nor the experts of its
+    # last layer: nothing reads that layer's output
+    assert (len(attn), len(gmm)) == ((2, 4) if program == "block_b4"
+                                     else (1, 2))
