@@ -473,26 +473,83 @@ def _replicate_out(x):
     return x if m is None else _mesh.sharding_constraint(x, _mesh.P(), m)
 
 
+def _bisect_max(holds, n, bits):
+    """The largest uint32 ``c`` [n, 1] below ``2**bits`` for which
+    ``holds(c)`` (a row-wise predicate that holds up to some value and not
+    above it), set bit by bit from the top: ``bits`` masked reductions.
+    0 where it never holds."""
+    def step(i, c):
+        cand = c | jnp.left_shift(jnp.uint32(1),
+                                  (bits - 1 - i).astype(jnp.uint32))
+        return jnp.where(holds(cand), cand, c)
+    return jax.lax.fori_loop(0, bits, step, jnp.zeros((n, 1), jnp.uint32))
+
+
+def _kth_largest(x, k):
+    """[N, 1]: the ``k``-th largest of each row of ``x`` [N, V] f32, ties
+    counted (``jnp.sort(x)[:, ::-1][:, k - 1]``), ``k`` [N] in [1, V]."""
+    # float32 -> uint32 in the float order, -inf first: the answer is the
+    # largest key that k keys reach. The order is finer than jnp.sort's
+    # (-0.0 below 0.0), so the k-th falls in the sort's class of equals
+    # and ``x < kth`` reads the same
+    flip = jnp.int32(-2**31)
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    u = jax.lax.bitcast_convert_type(
+        jnp.where(b < 0, b ^ 0x7FFFFFFF, b) ^ flip, jnp.uint32)
+    ku = _bisect_max(lambda c: jnp.sum(u >= c, axis=-1, keepdims=True,
+                                       dtype=jnp.int32) >= k[:, None],
+                     x.shape[0], 32)
+    kb = jax.lax.bitcast_convert_type(ku, jnp.int32) ^ flip
+    return jax.lax.bitcast_convert_type(
+        jnp.where(kb < 0, kb ^ 0x7FFFFFFF, kb), jnp.float32)
+
+
+def _nucleus_floor(probs, top_p):
+    """[N, 1]: the largest ``t`` for which the tokens of ``probs`` [N, V]
+    at ``t`` or above hold ``top_p`` [N] of the row's mass, which is the
+    smallest probability of the nucleus; 0 (every token) where even the
+    whole row's float sum falls short of ``top_p``."""
+    # probabilities in [0, 1]: their bit patterns order as the values, and
+    # lie below 2.0's (2**30)
+    pu = jax.lax.bitcast_convert_type(probs, jnp.uint32)
+    return jax.lax.bitcast_convert_type(_bisect_max(
+        lambda c: jnp.sum(jnp.where(pu >= c, probs, 0.0), axis=-1,
+                          keepdims=True) >= top_p[:, None],
+        probs.shape[0], 30), jnp.float32)
+
+
+def _filter_logits(logits, temperature, top_k, top_p):
+    """The temperature-scaled logits [N, V] with every token outside a
+    row's top-k and top-p set to -inf; top_k <= 0 and top_p >= 1.0
+    disable their filters. Both cut-offs are exact thresholds found by
+    bisection, with no sort of the vocabulary, each skipped where no row
+    asks for it. The nucleus is every token at or above the smallest
+    probability a sort's rule keeps (a token is kept while the mass before
+    it is below top_p); the sort sums in another order, which can move
+    one token at the nucleus's exact edge."""
+    n, v = logits.shape
+    x = logits / temperature[:, None]
+    kth = jax.lax.cond(
+        jnp.any(top_k > 0), lambda x: _kth_largest(x, jnp.clip(top_k, 1, v)),
+        lambda x: jnp.full((n, 1), -jnp.inf, x.dtype), x)
+    x = jnp.where((top_k[:, None] > 0) & (x < kth), -jnp.inf, x)
+    probs = jax.nn.softmax(x, axis=-1)
+    thr = jax.lax.cond(
+        jnp.any(top_p < 1.0), lambda p: _nucleus_floor(p, top_p),
+        lambda p: jnp.zeros((n, 1), p.dtype), probs)
+    return jnp.where((top_p[:, None] < 1.0) & (probs < thr), -jnp.inf, x)
+
+
 def _sample_tokens(logits, keys, temperature, top_k, top_p, greedy,
                    exact_argmax=None):
     """On-device sampling for N rows: logits [N, V] f32, keys [N] typed,
     temperature/top_p f32 [N], top_k i32 [N], greedy bool [N]. Per-row
     keys keep every request's sample stream independent of co-scheduling.
-    top_k <= 0 and top_p >= 1.0 disable their filters. ``exact_argmax``
-    [N] i32, when given, replaces the local argmax for greedy rows — the
-    quantized logit wire passes the verify exchange's exact winner here
-    so greedy output never sees quantization (docs/SERVING.md §5)."""
-    v = logits.shape[-1]
-    x = logits / temperature[:, None]
-    sorted_x = jnp.sort(x, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(
-        sorted_x, (jnp.clip(top_k, 1, v) - 1)[:, None], axis=-1)
-    x = jnp.where((top_k[:, None] > 0) & (x < kth), -jnp.inf, x)
-    probs = jax.nn.softmax(x, axis=-1)
-    sp = jnp.sort(probs, axis=-1)[:, ::-1]
-    keep = (jnp.cumsum(sp, axis=-1) - sp) < top_p[:, None]
-    thr = jnp.min(jnp.where(keep, sp, jnp.inf), axis=-1, keepdims=True)
-    x = jnp.where((top_p[:, None] < 1.0) & (probs < thr), -jnp.inf, x)
+    The filters are ``_filter_logits``'s. ``exact_argmax`` [N] i32, when
+    given, replaces the local argmax for greedy rows — the quantized logit
+    wire passes the verify exchange's exact winner here so greedy output
+    never sees quantization (docs/SERVING.md §5)."""
+    x = _filter_logits(logits, temperature, top_k, top_p)
     sampled = jax.vmap(lambda xr, kr: jax.random.categorical(kr, xr))(x, keys)
     arg = (jnp.argmax(logits, axis=-1) if exact_argmax is None
            else exact_argmax)

@@ -566,15 +566,17 @@ def looped_engine_programs(one_chip):
 @pytest.mark.parametrize("program", LOOPED_PROGRAMS)
 def test_looped_engine_program_keeps_the_pool_in_one_layout_in_its_carry(
         looped_engine_programs, program):
-    """The loops are one ``while``; every array of the pool's shape, the
-    loop's carry among them, keeps the row-major layout it arrives in; the
-    kernel is inside; and nothing as large as ONE entry's pool is copied,
-    sliced or re-laid. (An entry's pool, 8.4 M elements, is smaller than an
-    MLP matrix here, 11.5 M: the weights' prefetches into VMEM, rank 2, are
-    not the pool's traffic.)"""
+    """The loops are one ``while`` with the pool in its carry (the
+    sampler's bisections are loops of their own, over [rows, 1]); every
+    array of the pool's shape, the loop's carry among them, keeps the
+    row-major layout it arrives in; the kernel is inside; and nothing as
+    large as ONE entry's pool is copied, sliced or re-laid. (An entry's
+    pool, 8.4 M elements, is smaller than an MLP matrix here, 11.5 M: the
+    weights' prefetches into VMEM, rank 2, are not the pool's traffic.)"""
     text, pool_shape = looped_engine_programs(program)
-    assert len(re.findall(r" while\(", text)) == 1
     dims = ",".join(str(n) for n in pool_shape)
+    assert len([ln for ln in text.splitlines() if " while(" in ln
+                and f"[{dims}]" in ln]) == 1
     assert set(re.findall(rf"\w+\[{dims}\]\{{([\d,]*)", text)) == {
         "4,3,2,1,0"}
     assert _kernels_in(text) == {_ENGINE_KERNEL[program.split("_")[0]]}
