@@ -510,12 +510,14 @@ def _nucleus_floor(probs, top_p):
     smallest probability of the nucleus; 0 (every token) where even the
     whole row's float sum falls short of ``top_p``."""
     # probabilities in [0, 1]: their bit patterns order as the values, and
-    # lie below 2.0's (2**30)
-    pu = jax.lax.bitcast_convert_type(probs, jnp.uint32)
-    return jax.lax.bitcast_convert_type(_bisect_max(
-        lambda c: jnp.sum(jnp.where(pu >= c, probs, 0.0), axis=-1,
-                          keepdims=True) >= top_p[:, None],
-        probs.shape[0], 30), jnp.float32)
+    # lie below 2.0's (2**30). A step compares the values with the
+    # candidate's float, so the loop carries and reads the one array
+    def holds(c):
+        t = jax.lax.bitcast_convert_type(c, jnp.float32)
+        return jnp.sum(jnp.where(probs >= t, probs, 0.0), axis=-1,
+                       keepdims=True) >= top_p[:, None]
+    return jax.lax.bitcast_convert_type(
+        _bisect_max(holds, probs.shape[0], 30), jnp.float32)
 
 
 def _filter_logits(logits, temperature, top_k, top_p):
@@ -2407,6 +2409,11 @@ class DecodeEngine:
         Returns the tokens [...] and the logits a program hands back,
         both replicated."""
         s_logits, exact_arg, wired = self._wire_logits(logits)
+        if wired is None:
+            # mp-vocab-sharded logits are gathered once, here, as the
+            # returned logits are anyway: the sampler's bisection steps
+            # then reduce each row on one shard, with no collective a step
+            s_logits = logits = _replicate_out(logits)
         n = int(np.prod(keys.shape))
         per = n // temp.shape[0]
         rep = (lambda a: a) if per == 1 else (
@@ -2416,8 +2423,7 @@ class DecodeEngine:
             rep(top_p), rep(greedy),
             exact_argmax=None if exact_arg is None else exact_arg.reshape(n)
         ).reshape(keys.shape)
-        return _replicate_out(tokens), (
-            _replicate_out(logits) if wired is None else wired)
+        return _replicate_out(tokens), logits if wired is None else wired
 
     def _build_prefill(self, tb: int):
         def body(pool, ids, cached_len, true_len, row, key, temp, top_k,

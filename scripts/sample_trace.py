@@ -42,7 +42,7 @@ CASES = {
 }
 
 
-def child(tree, cases, calls, out, cpu):
+def child(tree, cases, calls, out):
     """Times ``tree``'s sampler at ``cases``; writes rows to ``out``."""
     sys.path.insert(0, tree)
     sys.path.insert(1, os.path.join(ROOT, "benchmark"))
@@ -54,7 +54,7 @@ def child(tree, cases, calls, out, cpu):
     from paddle_tpu.inference.engine import _sample_tokens
 
     dev = jax.devices()[0]
-    if dev.platform != "tpu" and not cpu:
+    if dev.platform != "tpu":
         sys.exit("sample_trace.py measures a TPU")
     fn = jax.jit(_sample_tokens)
     rows = []
@@ -71,15 +71,14 @@ def child(tree, cases, calls, out, cpu):
         tokens = np.asarray(fn(*args))  # compiles, warms
         row = {"case": name, "rows": n, "vocab": v, "top_k": k,
                "tokens": tokens.tolist()}
-        if not cpu:
-            with tempfile.TemporaryDirectory(dir=os.path.dirname(out)) as d:
-                with jax.profiler.trace(d):
-                    for _ in range(calls):
-                        fn(*args).block_until_ready()
-                red = tr.reduce_trace(tr.find_xplane(d))
-            row["device_ms_a_call"] = 1e3 * red.busy_s / calls
-            row["top_ops_ms_a_call"] = [
-                (op, 1e3 * s / calls) for op, s in red.top_ops(4)]
+        with tempfile.TemporaryDirectory(dir=os.path.dirname(out)) as d:
+            with jax.profiler.trace(d):
+                for _ in range(calls):
+                    fn(*args).block_until_ready()
+            red = tr.reduce_trace(tr.find_xplane(d))
+        row["device_ms_a_call"] = 1e3 * red.busy_s / calls
+        row["top_ops_ms_a_call"] = [
+            (op, 1e3 * s / calls) for op, s in red.top_ops(4)]
         rows.append(row)
         print(json.dumps({k_: v_ for k_, v_ in row.items()
                           if k_ != "tokens"}), flush=True)
@@ -95,15 +94,12 @@ def main():
     ap.add_argument("--cases", default=",".join(CASES))
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "sample_trace.json"))
-    ap.add_argument("--rehearse-on-cpu", action="store_true",
-                    help="tokens compared, nothing timed")
     ap.add_argument("--child", help=argparse.SUPPRESS)
     args = ap.parse_args()
     cases = args.cases.split(",")
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     if args.child:
-        return child(args.child, cases, args.calls, args.out,
-                     args.rehearse_on_cpu)
+        return child(args.child, cases, args.calls, args.out)
     trees = {"change": ROOT}
     if args.parent:
         trees["parent"] = os.path.abspath(args.parent)
@@ -112,8 +108,7 @@ def main():
         part = f"{args.out}.{tag}"
         cmd = [sys.executable, os.path.abspath(__file__), "--child", tree,
                "--cases", args.cases, "--calls", str(args.calls),
-               "--out", part] + (["--rehearse-on-cpu"]
-                                 if args.rehearse_on_cpu else [])
+               "--out", part]
         print(f"# {tag}: {tree}", flush=True)
         subprocess.run(cmd, check=True)
         with open(part) as f:
@@ -130,9 +125,8 @@ def main():
                     for t in ("parent", "change"))
             row["tokens_equal"] = sum(x == y for x, y in zip(a, b))
             row["tokens"] = len(a)
-            if "parent_device_ms_a_call" in row:
-                row["speedup"] = (row["parent_device_ms_a_call"]
-                                  / row["change_device_ms_a_call"])
+            row["speedup"] = (row["parent_device_ms_a_call"]
+                              / row["change_device_ms_a_call"])
         rows.append(row)
         print(json.dumps(row), flush=True)
     with open(args.out, "w") as f:

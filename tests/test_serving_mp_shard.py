@@ -175,6 +175,39 @@ def test_logit_wire_config_resolution(model64, monkeypatch):
         DecodeEngine(model64, EngineConfig(**CFG, logit_wire="fp8"))
 
 
+def test_mp2_sampler_bisects_with_no_collective_a_step(model64):
+    """The vocab-sharded logits are gathered once before ``sample``: its
+    top-k and top-p bisections (a ``while`` each) reduce every row on one
+    shard, with no all-reduce in their loops (a sort paid the same one
+    gather)."""
+    import re
+
+    eng = DecodeEngine(model64, EngineConfig(**CFG, mesh=_mp_mesh(2)))
+    with eng._mesh_ctx():
+        text = eng._jitted("decode").lower(
+            *eng._example_args("decode")).compile().as_text()
+    bodies = {}
+    for comp in re.split(r"\n(?=\S)", text):
+        name = re.match(r"(?:ENTRY )?%?([\w.\-]+)", comp)
+        bodies[name.group(1) if name else ""] = comp
+
+    def reached(name, seen):
+        if name in bodies and name not in seen:
+            seen.add(name)
+            for callee in re.findall(r"%([\w.\-]+)", bodies[name]):
+                if callee in bodies:
+                    reached(callee, seen)
+        return seen
+
+    loops = re.findall(r" while\([^\n]*body=%?([\w.\-]+)", text)
+    assert len(loops) >= 2  # the two bisections, at the least
+    coll = re.compile(r" (all-reduce|all-gather|all-to-all|reduce-scatter|"
+                      r"collective-permute)(-start)?\(")
+    inside = [c for b in loops for c in reached(b, set())
+              if coll.search(bodies[c])]
+    assert re.search(coll, text) and inside == []
+
+
 @pytest.mark.slow
 def test_mp2_int8_logit_wire_bit_equal(model64, monkeypatch, tmp_path):
     """ISSUE 13: int8 absmax logit recombination + exact-argmax verify

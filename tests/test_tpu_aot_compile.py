@@ -566,8 +566,9 @@ def looped_engine_programs(one_chip):
 @pytest.mark.parametrize("program", LOOPED_PROGRAMS)
 def test_looped_engine_program_keeps_the_pool_in_one_layout_in_its_carry(
         looped_engine_programs, program):
-    """The loops are one ``while`` with the pool in its carry (the
-    sampler's bisections are loops of their own, over [rows, 1]); every
+    """The loops are one ``while`` with the pool in its carry, beside the
+    sampler's two bisections (top-k's and top-p's), whose carry holds a
+    uint32 threshold [rows, 1] and nothing of the pool's shape; every
     array of the pool's shape, the loop's carry among them, keeps the
     row-major layout it arrives in; the kernel is inside; and nothing as
     large as ONE entry's pool is copied, sliced or re-laid. (An entry's
@@ -575,8 +576,11 @@ def test_looped_engine_program_keeps_the_pool_in_one_layout_in_its_carry(
     weights' prefetches into VMEM, rank 2, are not the pool's traffic.)"""
     text, pool_shape = looped_engine_programs(program)
     dims = ",".join(str(n) for n in pool_shape)
-    assert len([ln for ln in text.splitlines() if " while(" in ln
-                and f"[{dims}]" in ln]) == 1
+    whiles = [ln for ln in text.splitlines() if " while(" in ln]
+    pooled = [ln for ln in whiles if f"[{dims}]" in ln]
+    assert len(pooled) == 1 and len(whiles) == 3, whiles
+    rows = 8 if program == "decode" else 1
+    assert all(f"u32[{rows},1]" in ln for ln in whiles if ln not in pooled)
     assert set(re.findall(rf"\w+\[{dims}\]\{{([\d,]*)", text)) == {
         "4,3,2,1,0"}
     assert _kernels_in(text) == {_ENGINE_KERNEL[program.split("_")[0]]}
