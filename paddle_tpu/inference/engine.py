@@ -1151,7 +1151,8 @@ class DecodeEngine:
         b, name = self._block, f"block_b{self._block}"
         with _obs.span("eng_block_pass", live=len(active),
                        rows=len(active) * b) as sp:
-            host = self._block_inputs(active)
+            with _obs.span("eng_block_prep"):
+                host = self._block_inputs(active)
             t0 = time.perf_counter()
             with _obs.span("eng_block_upload"):
                 dev = [jnp.asarray(a) for a in host]
@@ -1162,33 +1163,34 @@ class DecodeEngine:
                 new_tokens, new_masked, touched = (np.asarray(a) for a in out)
             _obs.observe("serving_decode_step_seconds",
                          time.perf_counter() - t0)
-            self._last_logits = logits
-            self.decode_steps += 1
-            self.slot_steps += len(active)
-            self.rows_computed += len(active) * b
-            self.experts_touched += int(touched)
-            self.experts_capacity += self.adapter.num_layers * int(
-                self.adapter.num_experts)
-            live = self.kv_pages_live
-            self._count_kv_pages(host[2], b)
-            final = 0
-            for slot, req in active:
-                blk = req.block
-                now = blk.masked & ~new_masked[slot]
-                for i in np.flatnonzero(now):
-                    req.unmask_pass[blk.start + int(i)] = blk.passes
-                blk.tokens = np.where(now, new_tokens[slot], blk.tokens)
-                blk.masked = new_masked[slot].copy()
-                blk.passes += 1
-                if req.decode_t0 is None:
-                    req.decode_t0 = t0
-                req.decode_steps_n += 1
-                final += self._emit_final(req)
-            _obs.inc("serving_tokens_total", final)
-            if sp:
-                sp.attrs.update(final=final, experts_touched=int(touched),
-                                kv_pages=self.kv_pages_live - live)
-            self._update_gauges()
+            with _obs.span("eng_block_append"):
+                self._last_logits = logits
+                self.decode_steps += 1
+                self.slot_steps += len(active)
+                self.rows_computed += len(active) * b
+                self.experts_touched += int(touched)
+                self.experts_capacity += self.adapter.num_layers * int(
+                    self.adapter.num_experts)
+                live = self.kv_pages_live
+                self._count_kv_pages(host[2], b)
+                final = 0
+                for slot, req in active:
+                    blk = req.block
+                    now = blk.masked & ~new_masked[slot]
+                    for i in np.flatnonzero(now):
+                        req.unmask_pass[blk.start + int(i)] = blk.passes
+                    blk.tokens = np.where(now, new_tokens[slot], blk.tokens)
+                    blk.masked = new_masked[slot].copy()
+                    blk.passes += 1
+                    if req.decode_t0 is None:
+                        req.decode_t0 = t0
+                    req.decode_steps_n += 1
+                    final += self._emit_final(req)
+                _obs.inc("serving_tokens_total", final)
+                if sp:
+                    sp.attrs.update(final=final, experts_touched=int(touched),
+                                    kv_pages=self.kv_pages_live - live)
+                self._update_gauges()
 
     def _emit_final(self, req: Request) -> int:
         """Emit, in order, the tokens of the request's block that are final
@@ -1214,19 +1216,23 @@ class DecodeEngine:
         along with zeroed table rows (their writes land on the trash page)
         at position 0."""
         s, b = self.config.num_slots, self._block
-        tokens = np.zeros((s, b), np.int32)
-        positions = np.zeros(s, np.int32)
-        tables = np.zeros_like(self._tables)
-        for slot, req in group:
-            tokens[slot] = req.block.tokens
-            positions[slot] = req.block.start
-            tables[slot] = self._tables[slot]
         with _obs.span("eng_block_commit", slots=len(group),
                        rows=len(group) * b) as sp:
-            touched, _ = self._run(
-                f"commit_b{b}", self._state_vals(epoch), self.kv,
-                *[jnp.asarray(a) for a in (tokens, positions, tables)])
-            touched = int(np.asarray(touched))  # waits for the pass
+            with _obs.span("eng_commit_prep"):
+                tokens = np.zeros((s, b), np.int32)
+                positions = np.zeros(s, np.int32)
+                tables = np.zeros_like(self._tables)
+                for slot, req in group:
+                    tokens[slot] = req.block.tokens
+                    positions[slot] = req.block.start
+                    tables[slot] = self._tables[slot]
+            with _obs.span("eng_commit_upload"):
+                dev = [jnp.asarray(a) for a in (tokens, positions, tables)]
+            with _obs.span("eng_commit_dispatch"):
+                touched, _ = self._run(
+                    f"commit_b{b}", self._state_vals(epoch), self.kv, *dev)
+            with _obs.span("eng_commit_readback"):
+                touched = int(np.asarray(touched))  # waits for the pass
             self.commit_passes += 1
             self.rows_computed += len(group) * b
             self.experts_touched += touched
@@ -2173,11 +2179,16 @@ class DecodeEngine:
             spec_wasted_tokens=wasted, queue_seconds=queue_s)
 
     def _update_gauges(self):
-        used = sum(len(r.prompt) + len(r.tokens)
-                   for r in self._running.values())
+        """The peaks that ``stats()`` reports, and, where telemetry is on,
+        the four gauges (their arguments cost a scan of the running
+        requests and of the pool's refcounts, so only then)."""
         in_use = self._num_pages - 1 - self.pool.available()
         self.peak_pages_in_use = max(self.peak_pages_in_use, in_use)
         self.peak_running = max(self.peak_running, len(self._running))
+        if not _obs.enabled():
+            return
+        used = sum(len(r.prompt) + len(r.tokens)
+                   for r in self._running.values())
         _obs.set_gauge("serving_batch_occupancy",
                        len(self._running) / float(self.config.num_slots))
         _obs.set_gauge("serving_kv_cache_utilization",

@@ -582,27 +582,49 @@ SPANS = {
         "gauges"),
     "eng_block_pass": (
         "paddle_tpu/inference/engine.py",
-        "A block-diffusion engine's block pass, child of eng_step: inputs, "
-        "upload, the compiled pass over every slot's block, read-back and "
-        "emission (attrs: live slots, rows = live x block_length, final = "
+        "A block-diffusion engine's block pass, child of eng_step: prep, "
+        "upload, dispatch, read-back and append, its five children "
+        "(attrs: live slots, rows = live x block_length, final = "
         "tokens emitted, experts_touched in this pass, kv_pages its "
         "attention read)"),
+    "eng_block_prep": (
+        "paddle_tpu/inference/engine.py",
+        "Block pass, host: the ten per-slot numpy arrays of one pass"),
     "eng_block_upload": (
         "paddle_tpu/inference/engine.py",
-        "Block pass, host: the per-slot arrays to the device"),
+        "Block pass, host: those arrays to the device (ten jnp.asarray)"),
     "eng_block_dispatch": (
         "paddle_tpu/inference/engine.py",
         "Block pass, host: the call that enqueues the compiled block pass"),
     "eng_block_readback": (
         "paddle_tpu/inference/engine.py",
-        "Block pass: the blocking read of the blocks' tokens and masks, "
-        "i.e. the wait for the device"),
+        "Block pass: the blocking read of the blocks' tokens and masks and "
+        "of the experts touched, i.e. the wait for the device"),
+    "eng_block_append": (
+        "paddle_tpu/inference/engine.py",
+        "Block pass, host: counters, page counts, the unmasked positions "
+        "taken into each block, final tokens emitted, requests finished, "
+        "gauges"),
     "eng_block_commit": (
         "paddle_tpu/inference/engine.py",
         "A block-diffusion engine's commit pass, child of eng_step: every "
         "finished block's final tokens through the layers, their keys and "
         "values written, at the start of a round (attrs: slots, rows, "
-        "experts_touched, kv_pages); waits for the device"),
+        "experts_touched, kv_pages); its four children, prep to read-back"),
+    "eng_commit_prep": (
+        "paddle_tpu/inference/engine.py",
+        "Commit pass, host: the tokens, positions and tables arrays"),
+    "eng_commit_upload": (
+        "paddle_tpu/inference/engine.py",
+        "Commit pass, host: those arrays to the device (three jnp.asarray)"),
+    "eng_commit_dispatch": (
+        "paddle_tpu/inference/engine.py",
+        "Commit pass, host: the call that enqueues the compiled commit "
+        "pass"),
+    "eng_commit_readback": (
+        "paddle_tpu/inference/engine.py",
+        "Commit pass: the blocking read of the experts touched, i.e. the "
+        "wait for the device"),
     "eng_verify_prep": (
         "paddle_tpu/inference/engine.py",
         "Speculative verify step, host: as eng_decode_prep, k+1 tokens a "

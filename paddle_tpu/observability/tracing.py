@@ -21,7 +21,9 @@ and the training side emits single-span trees per compile miss,
 checkpoint commit, reshard, pipeline-schedule build and gradient-exchange
 build. What the host does INSIDE a step is a tree a step: ``eng_step``
 over ``eng_admit`` (``eng_prefill_*``) and ``eng_decode_*`` /
-``eng_verify_*`` in ``DecodeEngine.step``, ``train_step`` over
+``eng_verify_*`` in ``DecodeEngine.step`` (a block-diffusion engine's:
+``eng_block_commit`` over ``eng_commit_*``, ``eng_block_pass`` over
+``eng_block_*``), ``train_step`` over
 ``train_gather`` / ``train_dispatch`` / ``train_writeback`` in
 ``TrainStep`` — all through the same three entry points:
 

@@ -112,6 +112,39 @@ def test_nobody_traces_and_the_hot_paths_leave_the_buffer_empty(
     assert tracing.recorded() == []
 
 
+def test_the_gauges_scan_nothing_unless_telemetry_is_on(engine, tmp_path,
+                                                       monkeypatch):
+    """The four gauges' arguments cost a scan of the running requests and
+    of the pool's refcounts: only where telemetry is on. The peaks that
+    ``stats()`` reports are kept either way."""
+    from paddle_tpu import observability as _obs
+
+    scans = []
+    real = engine.pool.shared_pages
+    monkeypatch.setattr(engine.pool, "shared_pages",
+                        lambda: scans.append(1) or real())
+    engine.peak_running = engine.peak_pages_in_use = 0
+    engine.submit(_prompt(5, 3), max_new_tokens=3)
+    while engine.step():
+        pass
+    assert scans == []
+    assert engine.stats()["peak_running"] == 1
+    assert engine.stats()["peak_pages_in_use"] >= 1
+    monkeypatch.setenv("PADDLE_TPU_TELEMETRY_DIR", str(tmp_path))
+    _obs.reset()
+    try:
+        engine.submit(_prompt(5, 4), max_new_tokens=3)
+        while engine.step():
+            pass
+        assert scans
+        gauges = _obs.snapshot()["metrics"]
+        assert gauges["serving_kv_pages_shared"]["type"] == "gauge"
+        assert gauges["serving_batch_occupancy"]["values"]
+    finally:
+        _obs.reset()
+        tracing._buffer.clear()
+
+
 def test_active_follows_the_profiler_and_the_telemetry_directory(
         tmp_path, monkeypatch):
     assert not tracing.active()

@@ -10,16 +10,21 @@ CPU runs; a prompt whose tail opens the first block, the mask token's id as
 a prompt token, a shared prefix. (3) The expert layer of one rank: the
 parts that all expert ranges give add up to the uncut reference layer. (4)
 Both kernels' block horizon against a plain mask at 8 kv groups. (5) What a
-block engine refuses.
+block engine refuses. (6) The block engine's span tree while a profiler
+records (docs/OBSERVABILITY.md section 8), nothing while nobody traces, and
+the program's spans mapped onto their profiler events through the
+benchmark tracer's one clock anchor.
 
 Tolerances: the program runs in float32 and the reference in float32 at
 ``highest`` precision, so they differ by summation order alone, ~1e-6 on
 logits of size ~1: 1e-4 leaves that a hundredfold and is passed by a wide
 margin by the same program in bfloat16 (test_bfloat16_program_is_outside).
 """
+import glob
 import importlib.util
 import os
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +36,7 @@ from paddle_tpu.framework.op import raw
 from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
 from paddle_tpu.incubate.moe import count_experts, routed_experts
 from paddle_tpu.nn import functional as F
+from paddle_tpu.observability import tracing
 from paddle_tpu.ops.pallas.paged_attention import paged_attention
 from paddle_tpu.ops.pallas.prefill_attention import prefill_attention
 
@@ -379,3 +385,166 @@ def test_a_block_engine_refuses_what_it_cannot_serve(made):
         from paddle_tpu.text.models import SDARConfig
 
         SDARConfig(remasking="random")
+
+
+# -- (6) the block engine's span tree -----------------------------------------
+
+BLOCK_PARTS = ["eng_block_prep", "eng_block_upload", "eng_block_dispatch",
+               "eng_block_readback", "eng_block_append"]
+COMMIT_PARTS = ["eng_commit_prep", "eng_commit_upload", "eng_commit_dispatch",
+                "eng_commit_readback"]
+
+
+@pytest.fixture
+def _nobody_traces(monkeypatch):
+    monkeypatch.delenv("PADDLE_TPU_TELEMETRY_DIR", raising=False)
+    tracing._buffer.clear()
+    yield
+    tracing._buffer.clear()
+
+
+def _submit_two(eng):
+    """One greedy and one sampled request, 9 tokens each: three rounds of
+    four passes, two of them opened by a commit."""
+    return [eng.submit(PROMPTS[0], max_new_tokens=9),
+            eng.submit(PROMPTS[1], max_new_tokens=9, seed=3,
+                       temperature=0.8, top_p=0.95)]
+
+
+def _host_events(trace_dir):
+    """{name: [(start_ns, end_ns)]} of the host planes' events, oldest
+    first, in the one ``.xplane.pb`` under ``trace_dir``."""
+    files = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(files) == 1
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return {k: sorted(v) for k, v in out.items()}
+
+
+def _parts_in_order(rows, parent, names):
+    """``parent``'s children are ``names`` in order, each starting after the
+    one before it ends, all inside the parent."""
+    kids = sorted((r for r in rows if r.parent_id == parent.span_id),
+                  key=lambda r: r.t0)
+    assert [k.name for k in kids] == names
+    t = parent.t0
+    for k in kids:
+        assert t <= k.t0 <= k.t1 <= parent.t1
+        assert k.trace_id == parent.trace_id
+        t = k.t1
+
+
+def test_each_block_step_is_one_tree_with_every_part_of_its_passes(
+        made, tmp_path, _nobody_traces):
+    model, _, _ = made()
+    eng = _engine(model)
+    _submit_two(eng)
+    with jax.profiler.trace(str(tmp_path)):
+        t_from = time.perf_counter()
+        steps = 0
+        while eng.step():
+            steps += 1
+        t_to = time.perf_counter()
+    rows = tracing.recorded(t_from, t_to)
+    roots = [r for r in rows if r.name == "eng_step"]
+    assert len(roots) == steps and steps >= 12
+    assert all(r.parent_id is None for r in roots)
+    assert len({r.trace_id for r in roots}) == len(roots)
+    by_id = {r.span_id: r for r in rows}
+    # every span of the run is one step's: its chain of parents ends there
+    for r in rows:
+        while r.parent_id is not None:
+            r = by_id[r.parent_id]
+        assert r.name == "eng_step"
+    passes = [r for r in rows if r.name == "eng_block_pass"]
+    commits = [r for r in rows if r.name == "eng_block_commit"]
+    assert len(passes) == steps and len(commits) == 2
+    for root in roots:
+        kids = [k.name for k in sorted(
+            (r for r in rows if r.parent_id == root.span_id),
+            key=lambda r: r.t0)]
+        # a round's start: the commit, the admissions, then the pass
+        assert kids[-1] == "eng_block_pass"
+        assert set(kids[:-1]) <= {"eng_block_commit", "eng_admit"}
+        assert kids.count("eng_block_commit") <= 1
+        if "eng_block_commit" in kids:
+            assert kids[0] == "eng_block_commit"
+    for sp in passes:
+        _parts_in_order(rows, sp, BLOCK_PARTS)
+        assert {"live", "rows", "final", "experts_touched",
+                "kv_pages"} <= set(sp.attrs)
+    for sp in commits:
+        _parts_in_order(rows, sp, COMMIT_PARTS)
+        assert {"slots", "rows", "experts_touched",
+                "kv_pages"} <= set(sp.attrs)
+    # the same names lie on the profiler's host plane, as many of each
+    host = _host_events(str(tmp_path))
+    for name in ["eng_step", "eng_block_pass", "eng_block_commit",
+                 *BLOCK_PARTS, *COMMIT_PARTS]:
+        assert len(host.get(name, ())) == sum(r.name == name for r in rows), \
+            name
+
+
+def test_nobody_traces_a_block_engine_and_its_tokens_are_the_same(
+        made, tmp_path, _nobody_traces):
+    model, _, _ = made()
+    served = []
+    for traced in (True, False):
+        eng = _engine(model)
+        rids = _submit_two(eng)
+        if traced:
+            with jax.profiler.trace(str(tmp_path)):
+                eng.run()
+            assert tracing.recorded()
+            tracing._buffer.clear()
+        else:
+            assert not tracing.active()
+            eng.run()
+            assert tracing.recorded() == []
+        served.append([eng.result(r).tolist() for r in rids])
+    assert served[0] == served[1]
+
+
+def test_program_spans_map_onto_their_profiler_events_by_the_tracers_anchor(
+        made, tmp_path, _nobody_traces):
+    """The benchmark's readers lay the program's buffer (``perf_counter``)
+    on the device's events (the profiler's clock) through ONE anchor:
+    ``harness.Tracer.t_start``, read just after the window's annotation
+    opens. Every program span of the window, mapped so, lies within 0.5 ms
+    of its own event on the host plane, at its start and at its end."""
+    if BENCH not in sys.path:
+        sys.path.append(BENCH)
+    harness = importlib.import_module("harness")
+    trace_reduce = importlib.import_module("trace_reduce")
+    model, _, _ = made()
+    eng = _engine(model)
+    _submit_two(eng)
+    for _ in range(4):  # the first round: its programs compile here
+        eng.step()
+    tracer = harness.Tracer(True, str(tmp_path / "trace"))
+    tracer.start()
+    for _ in range(2):  # the next round's start (its commit) and a pass
+        eng.step()
+    tracer.stop()
+    host = _host_events(tracer.dir)
+    (w0, _), = host[trace_reduce.WINDOW_SPAN]
+    rows = [r for r in tracing.recorded(tracer.t_start, tracer.t_stop)
+            if r.name.startswith("eng_")]
+    names = {r.name for r in rows}
+    assert {"eng_step", "eng_block_commit", *BLOCK_PARTS,
+            *COMMIT_PARTS} <= names
+    worst = 0.0
+    for name in names:
+        mine = sorted((r.t0, r.t1) for r in rows if r.name == name)
+        theirs = host[name]
+        assert len(mine) == len(theirs), name
+        for (t0, t1), (s, e) in zip(mine, theirs):
+            for t, ns in ((t0, s), (t1, e)):
+                worst = max(worst, abs((t - tracer.t_start) - (ns - w0) / 1e9))
+    assert worst < 0.5e-3
