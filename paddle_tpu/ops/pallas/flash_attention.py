@@ -22,8 +22,13 @@ pl.when).
 Backward is the standard recompute-based flash backward: the forward also
 emits the per-row logsumexp (LSE); backward recomputes P = exp(S - LSE)
 blockwise (no O(T^2) HBM tensor is ever materialized) and accumulates
-dQ in one kernel (grid over K blocks innermost) and dK/dV in a second
-kernel (grid over Q blocks innermost), all in fp32 VMEM scratch.
+dQ in one kernel (`flash_attention_bwd_dq`, grid over K blocks innermost)
+and dK/dV in a second (`flash_attention_bwd_dkv`, grid over Q blocks
+innermost), all in fp32 VMEM scratch. When one backward key block covers
+every key (Tk <= block_k) and no bias gradient is wanted, each dK/dV tile
+already holds a q block's whole dS, so a single kernel
+(`flash_attention_bwd_fused`) writes dQ = dS·K beside dK/dV and the dQ
+kernel, which would recompute P and dS on the same tile, is not launched.
 
 Supported: causal (incl. tq != tk, bottom-right aligned), additive bias /
 boolean mask broadcastable over batch and head, GQA/MQA (num_kv_heads
@@ -47,6 +52,10 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
+# The fused backward's step at 1024 x 1024 tiles and head_dim 64 holds
+# 16.1 MiB of scoped VMEM (compiled for a v5e), just over Mosaic's default
+# limit of 16 MiB; a v5e core has 128 MiB.
+FUSED_BWD_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _env_block(name, default):
@@ -374,14 +383,16 @@ def _dq_kernel(
 
 def _dkv_kernel(
     *refs, scale, causal, tq, tk, block_q, block_k, num_q_blocks, has_bias,
+    has_dq,
 ):
-    if has_bias:
-        (q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_scr, dv_scr) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_scr, dv_scr) = refs
-        bias_ref = None
+    q_ref, k_ref, v_ref = refs[:3]
+    i = 3
+    bias_ref = refs[i] if has_bias else None
+    i += int(has_bias)
+    do_ref, lse_ref, delta_ref, dk_ref, dv_ref = refs[i:i + 5]
+    i += 5
+    dq_ref = refs[i] if has_dq else None
+    dk_scr, dv_scr = refs[-2:]
     ki = pl.program_id(1)
     qj = pl.program_id(2)
 
@@ -402,12 +413,27 @@ def _dkv_kernel(
             q_ref[0], k_ref[0], v_ref[0], do,
             lse_ref[0], delta_ref[0], bias_ref, mask, scale,
         )
+        if dq_ref is not None:
+            # the one key block is every key: this tile's dS·K is the whole
+            # dQ of its q block, as the dQ kernel would accumulate it.
+            # Before dV and dK: the step then holds 16.1 MiB of VMEM, not
+            # the 17.9 it takes when dQ comes last
+            dq_ref[0] = (jax.lax.dot_general(
+                ds, k_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale).astype(dq_ref.dtype)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
+
+    if dq_ref is not None:
+        # causal with tq > tk: a q block that sees no key has a zero dQ
+        @pl.when(jnp.logical_not(run))
+        def _dead_rows():
+            dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
     @pl.when(qj == num_q_blocks - 1)
     def _emit():
@@ -458,69 +484,76 @@ def _fa_backward(q, k, v, bias, o, lse, do, causal, scale, n_heads,
     # gradient (mask-derived biases never do).
     want_dbias = has_bias and bias_grad
 
-    # ---- dQ: grid (bh, q blocks, k blocks innermost)
-    kv_index, bias_index = _make_index_maps(
-        causal, tq, tk, nq, nk, block_q, block_k, n_heads, n_kv_heads,
-        bias_b, bias_h, bias_tq, bias_tk,
-    )
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    in_specs = [
-        q_spec,
-        pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
-    ]
-    args = [q, k, v]
-    if has_bias:
-        in_specs.append(
-            pl.BlockSpec(_bias_block(block_q, block_k, bias_tq, bias_tk),
-                         bias_index)
+    # one key block holds every key: the dK/dV kernel's tile is all a q
+    # block's dQ needs, so dQ comes out of that kernel and its own pass
+    # (which would recompute P and dS on the same tile) is not launched.
+    # A trained bias keeps the dQ kernel: it writes the dS tensor dbias sums.
+    fuse_dq = nk == 1 and not want_dbias
+    dbias = None
+    if not fuse_dq:
+        # ---- dQ: grid (bh, q blocks, k blocks innermost)
+        kv_index, bias_index = _make_index_maps(
+            causal, tq, tk, nq, nk, block_q, block_k, n_heads, n_kv_heads,
+            bias_b, bias_h, bias_tq, bias_tk,
         )
-        args.append(bias)
-    in_specs += [q_spec, row_spec, row_spec]
-    args += [do, lse, delta]
+        q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+        row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+        in_specs = [
+            q_spec,
+            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, d), kv_index),
+        ]
+        args = [q, k, v]
+        if has_bias:
+            in_specs.append(
+                pl.BlockSpec(_bias_block(block_q, block_k, bias_tq, bias_tk),
+                             bias_index)
+            )
+            args.append(bias)
+        in_specs += [q_spec, row_spec, row_spec]
+        args += [do, lse, delta]
 
-    out_shape = [jax.ShapeDtypeStruct((bh, tqp, d), q.dtype)]
-    out_specs = [q_spec]
-    if want_dbias:
-        out_shape.append(jax.ShapeDtypeStruct((bh, tqp, tkp), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b, i, j))
-        )
+        out_shape = [jax.ShapeDtypeStruct((bh, tqp, d), q.dtype)]
+        out_specs = [q_spec]
+        if want_dbias:
+            out_shape.append(
+                jax.ShapeDtypeStruct((bh, tqp, tkp), jnp.float32))
+            out_specs.append(
+                pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b, i, j))
+            )
 
-    dq_kernel = functools.partial(
-        _dq_kernel, scale=scale, causal=causal, tq=tq, tk=tk,
-        block_q=block_q, block_k=block_k, num_k_blocks=nk, has_bias=has_bias,
-        has_dbias=want_dbias,
-    )
-    dq_out = pl.pallas_call(
-        dq_kernel,
-        out_shape=out_shape,
-        grid=(bh, nq, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[_scratch((block_q, d))],
-        interpret=interpret,
-        name="flash_attention_bwd_dq",
-    )(*args)
-    if want_dbias:
-        dq, ds_full = dq_out
-        dbias = ds_full[:, :tq, :tk].reshape(
-            bh // n_heads, n_heads, tq, tk
+        dq_kernel = functools.partial(
+            _dq_kernel, scale=scale, causal=causal, tq=tq, tk=tk,
+            block_q=block_q, block_k=block_k, num_k_blocks=nk,
+            has_bias=has_bias, has_dbias=want_dbias,
         )
-        if bias_b == 1:
-            dbias = dbias.sum(0, keepdims=True)
-        if bias_h == 1:
-            dbias = dbias.sum(1, keepdims=True)
-        if bias_tq == 1:
-            dbias = dbias.sum(2, keepdims=True)
-        if bias_tk == 1:
-            dbias = dbias.sum(3, keepdims=True)
-        dbias = dbias.reshape(bias_b * bias_h, bias_tq, bias_tk)
-    else:
-        (dq,) = dq_out
-        dbias = None
-    dq = dq[:, :tq]
+        dq_out = pl.pallas_call(
+            dq_kernel,
+            out_shape=out_shape,
+            grid=(bh, nq, nk),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[_scratch((block_q, d))],
+            interpret=interpret,
+            name="flash_attention_bwd_dq",
+        )(*args)
+        if want_dbias:
+            dq, ds_full = dq_out
+            dbias = ds_full[:, :tq, :tk].reshape(
+                bh // n_heads, n_heads, tq, tk
+            )
+            if bias_b == 1:
+                dbias = dbias.sum(0, keepdims=True)
+            if bias_h == 1:
+                dbias = dbias.sum(1, keepdims=True)
+            if bias_tq == 1:
+                dbias = dbias.sum(2, keepdims=True)
+            if bias_tk == 1:
+                dbias = dbias.sum(3, keepdims=True)
+            dbias = dbias.reshape(bias_b * bias_h, bias_tq, bias_tk)
+        else:
+            (dq,) = dq_out
+        dq = dq[:, :tq]
 
     # ---- dK/dV: grid (bh over *q heads*, k blocks, q blocks innermost);
     # GQA: per-q-head partials are group-summed after the kernel.
@@ -564,23 +597,40 @@ def _fa_backward(q, k, v, bias, o, lse, do, causal, scale, n_heads,
     args2 += [do, lse, delta]
 
     kv_out_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
+    out_shape2 = [
+        jax.ShapeDtypeStruct((bh, tkp, d), jnp.float32),
+        jax.ShapeDtypeStruct((bh, tkp, d), jnp.float32),
+    ]
+    out_specs2 = [kv_out_spec, kv_out_spec]
+    if fuse_dq:
+        # each step writes its own q block: not q_index2, whose causal
+        # clamp maps the dead leading blocks onto the first live one
+        out_shape2.append(jax.ShapeDtypeStruct((bh, tqp, d), q.dtype))
+        out_specs2.append(
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)))
     dkv_kernel = functools.partial(
         _dkv_kernel, scale=scale, causal=causal, tq=tq, tk=tk,
         block_q=block_q, block_k=block_k, num_q_blocks=nq, has_bias=has_bias,
+        has_dq=fuse_dq,
     )
-    dk, dv = pl.pallas_call(
+    dkv_out = pl.pallas_call(
         dkv_kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tkp, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tkp, d), jnp.float32),
-        ],
+        out_shape=out_shape2,
         grid=(bh, nk, nq),
         in_specs=in_specs2,
-        out_specs=[kv_out_spec, kv_out_spec],
+        out_specs=out_specs2,
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
+        name="flash_attention_bwd_fused" if fuse_dq
+        else "flash_attention_bwd_dkv",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_BWD_VMEM_LIMIT) if fuse_dq else None,
     )(*args2)
+    if fuse_dq:
+        dk, dv, dq = dkv_out
+        dq = dq[:, :tq]
+    else:
+        dk, dv = dkv_out
     dk, dv = dk[:, :tk], dv[:, :tk]
     group = n_heads // n_kv_heads
     if group > 1:

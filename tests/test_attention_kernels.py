@@ -243,6 +243,115 @@ def test_flash_attention_long_seq_grads():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
 
 
+# one backward key block covers every key: dQ comes out of the dK/dV kernel
+# (``flash_attention_bwd_fused``); a key block below Tk keeps two kernels
+FUSED_BWD_CASES = {
+    # tq, tk, heads, kv heads, causal, (B,1,1,Tk) keep-mask, bwd q block
+    "noncausal": (100, 100, 2, 2, False, False, None),
+    "causal": (100, 100, 2, 2, True, False, None),
+    # 56 leading rows see no key; q block 0 (rows 0-31) has none at all
+    "causal_tq_gt_tk": (96, 40, 2, 2, True, False, 32),
+    "gqa": (64, 64, 4, 2, True, False, None),
+    "padding_mask": (64, 64, 2, 2, False, True, None),
+}
+
+
+def _bwd_grads(q, k, v, cot, causal, keep):
+    return jax.grad(
+        lambda q_, k_, v_: (flash_attention(q_, k_, v_, causal=causal,
+                                            mask=keep) * cot).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _bwd_kernels(fn, *args):
+    """The backward kernels named in ``fn``'s program lowered for a TPU."""
+    import re
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    return set(re.findall(r"flash_attention_bwd_(?:fused|dq|dkv)", text))
+
+
+@pytest.mark.parametrize("case", list(FUSED_BWD_CASES))
+def test_flash_backward_fused_matches_two_kernels(monkeypatch, case):
+    tq, tk, h, hkv, causal, masked, block_q = FUSED_BWD_CASES[case]
+    if block_q is not None:
+        monkeypatch.setenv("PADDLE_TPU_FLASH_BWD_BLOCK_Q", str(block_q))
+    rng = np.random.default_rng(30)
+    q = jnp.asarray(rng.standard_normal((2, tq, h, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, tk, hkv, 16)), jnp.float32)
+            for _ in range(2))
+    cot = jnp.asarray(rng.standard_normal((2, tq, h, 16)), jnp.float32)
+    keep = None
+    if masked:
+        keep = np.ones((2, 1, 1, tk), bool)
+        keep[1, :, :, tk - 16:] = False  # the second row's padded tail
+        keep = jnp.asarray(keep)
+
+    fused = _bwd_grads(q, k, v, cot, causal, keep)
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BWD_BLOCK_K", "16")  # 16 < tk
+    two = _bwd_grads(q, k, v, cot, causal, keep)
+    for a, b in zip(fused, two):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+    # the dense reference: GQA's heads repeated, and for tq > tk only the
+    # rows that see a key (bottom-right aligned, they are the last tk)
+    dead = max(tq - tk, 0) if causal else 0
+    rep = h // hkv
+    ref = jax.grad(
+        lambda q_, k_, v_: (_sdpa_reference(
+            q_, jnp.repeat(k_, rep, 2), jnp.repeat(v_, rep, 2), keep, 0.0,
+            causal, None) * cot[:, dead:]).sum(),
+        argnums=(0, 1, 2))(q[:, dead:], k, v)
+    dq, dk, dv = fused
+    np.testing.assert_array_equal(np.asarray(dq)[:, :dead], 0.0)
+    for a, b in zip((dq[:, dead:], dk, dv), ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_flash_backward_trained_bias_keeps_two_kernels():
+    """dbias sums the dS tensor the dQ kernel writes: a trained bias keeps
+    that kernel even when one key block covers every key."""
+    q, k, v = _rand(1, 32, 2, 16, seed=31)
+    bias = jnp.asarray(
+        np.random.default_rng(32).standard_normal((1, 2, 32, 32)), jnp.float32)
+
+    def grads(q_, k_, bias_):
+        return jax.grad(
+            lambda a, b, c: (flash_attention(a, b, v, causal=True, bias=c)
+                             ** 2).mean(), argnums=(0, 1, 2))(q_, k_, bias_)
+
+    assert _bwd_kernels(grads, q, k, bias) == {
+        "flash_attention_bwd_dq", "flash_attention_bwd_dkv"}
+    g_pl = grads(q, k, bias)
+    g_ref = jax.grad(
+        lambda a, b, c: (_sdpa_reference(a, b, v, c, 0.0, True, None)
+                         ** 2).mean(), argnums=(0, 1, 2))(q, k, bias)
+    for a, b in zip(g_pl, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("t, kernels", [
+    (64, {"flash_attention_bwd_fused"}),
+    (72, {"flash_attention_bwd_dq", "flash_attention_bwd_dkv"}),
+], ids=["t_le_block_k", "t_gt_block_k"])
+def test_flash_backward_kernels_in_lowered_program(monkeypatch, t, kernels):
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BWD_BLOCK_K", "64")
+    x = jax.ShapeDtypeStruct((1, t, 2, 64), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: flash_attention(*a, causal=True)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    assert _bwd_kernels(fwd_bwd, x, x, x) == kernels
+
+
 def test_sdpa_routes_to_flash_kernel(monkeypatch):
     """The public functional uses the Pallas kernel when mask/dropout allow.
 

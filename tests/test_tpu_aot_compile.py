@@ -229,8 +229,10 @@ KERNEL_NAMES = {
     "paged_attention": "paged",
     "prefill_attention": "prefill",
     "flash_attention_fwd": "flash",
-    "flash_attention_bwd_dq": "flash",
-    "flash_attention_bwd_dkv": "flash",
+    "flash_attention_bwd_fused": "flash",
+    # a key length over one backward block keeps dQ in a kernel of its own
+    "flash_attention_bwd_dq": "flash_gpt",
+    "flash_attention_bwd_dkv": "flash_gpt",
     "grouped_matmul_fwd": "gmm",
     "grouped_matmul_drhs": "gmm",
 }
@@ -238,14 +240,15 @@ KERNEL_NAMES = {
 
 @pytest.fixture(scope="module")
 def named_programs(one_chip):
-    """The compiled text of one forward+backward flash program, one paged
-    decode program, one bucket-1024 prefill call and one forward+backward
-    grouped matmul, compiled when the first case asks."""
+    """The compiled text of a forward+backward flash program at the ERNIE
+    and at the GPT shape, one paged decode program, one bucket-1024 prefill
+    call and one forward+backward grouped matmul, compiled when the first
+    case asks."""
     texts = {}
 
-    def flash(q, k, v):
+    def flash(q, k, v, causal=False):
         return jax.grad(
-            lambda *a: flash_mod.flash_attention(*a)
+            lambda *a: flash_mod.flash_attention(*a, causal=causal)
             .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     def gmm(lhs, rhs, sizes):
@@ -254,8 +257,10 @@ def named_programs(one_chip):
             .astype(jnp.float32).sum(), argnums=(0, 1))(lhs, rhs)
 
     qkv = (FLASH_SHAPES["ernie_b32_t1024_h12_d64"], jnp.bfloat16)
+    gpt = (FLASH_SHAPES["gpt1p3b_b4_t2048_h16_d128"], jnp.bfloat16)
     programs = {
         "flash": (flash, (qkv, qkv, qkv)),
+        "flash_gpt": (functools.partial(flash, causal=True), (gpt, gpt, gpt)),
         "paged": (_paged, _paged_shapes("bf16", *PAGED_CALLS["decode"])),
         "prefill": (_prefill, _prefill_shapes("bf16", 1024)),
         "gmm": (gmm, (((8192, 2048), jnp.bfloat16),
