@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import math
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
@@ -108,6 +109,41 @@ def _program_kind(name: str) -> Tuple[str, int]:
     "block_b4" and ``("commit", 4)`` of "commit_b4"."""
     kind, _, size = name.partition("_")
     return kind, int(size[1:] or 0)
+
+
+def _pack_fields(layout, arrays) -> np.ndarray:
+    """Host: ``arrays`` (arrays or scalars, one a field of ``layout``, a
+    tuple of ``(shape, dtype)``) laid end to end in ONE int32 buffer, so
+    that a pass's inputs reach the device in one transfer: int32 as it is,
+    float32 and uint32 (threefry key data) bit for bit, bool as 0/1."""
+    buf = np.empty(sum(math.prod(shape) for shape, _ in layout), np.int32)
+    o = 0
+    for (shape, dt), a in zip(layout, arrays, strict=True):
+        a = np.asarray(a, dt)
+        if a.shape != shape:
+            raise ValueError(f"packed field {shape} {dt} given {a.shape}")
+        n = a.size
+        buf[o:o + n] = (a if dt == np.bool_ else a.view(np.int32)).reshape(n)
+        o += n
+    return buf
+
+
+def _unpack_fields(layout, packed):
+    """Device (traced): the fields of ``layout`` cut back out of the one
+    int32 array ``_pack_fields`` made, each in its own dtype and shape, bit for
+    bit: static slices, a bit-cast for float32 and uint32, ``!= 0`` for
+    bool."""
+    out, o = [], 0
+    for shape, dt in layout:
+        n = math.prod(shape)
+        seg = packed[o:o + n]
+        o += n
+        if dt == np.bool_:
+            seg = seg != 0
+        elif dt != np.int32:
+            seg = jax.lax.bitcast_convert_type(seg, dt)
+        out.append(seg.reshape(shape))
+    return out
 
 
 def pow2_bucket(n: int, lo: int = 16, hi: Optional[int] = None) -> int:
@@ -788,6 +824,10 @@ class DecodeEngine:
         #: the experts held, passes x layers x experts a layer
         self.commit_passes = 0
         self.rows_computed = 0
+        #: host-to-device transfers made by passes and prefills: ONE
+        #: packed int32 array each (``_pack_fields``)
+        self.host_uploads = 0
+        self._layouts: Dict[str, tuple] = {}
         self.experts_touched = 0
         self.experts_capacity = 0
         self._t_decode_ema = None
@@ -1041,13 +1081,14 @@ class DecodeEngine:
             epoch = self._epoch
         with _obs.span("eng_decode_prep"):
             active, host = self._decode_inputs(epoch)
+            packed = self._pack("decode", host)
         warm = "decode" in self._compiled
         t0 = time.perf_counter()
-        with _obs.span("eng_decode_upload"):
-            dev = [jnp.asarray(a) for a in host]
+        with _obs.span("eng_decode_upload") as sp:
+            dev = self._upload(packed, sp)
         with _obs.span("eng_decode_dispatch"):
             nxt, logits = self._run(
-                "decode", self._state_vals(epoch), self.kv, *dev)
+                "decode", self._state_vals(epoch), self.kv, dev)
         with _obs.span("eng_decode_readback"):
             # the per-token host transfer, [S] int32: waits for the step
             nxt_host = np.asarray(nxt)
@@ -1153,12 +1194,13 @@ class DecodeEngine:
                        rows=len(active) * b) as sp:
             with _obs.span("eng_block_prep"):
                 host = self._block_inputs(active)
+                packed = self._pack(name, host)
             t0 = time.perf_counter()
-            with _obs.span("eng_block_upload"):
-                dev = [jnp.asarray(a) for a in host]
+            with _obs.span("eng_block_upload") as up:
+                dev = self._upload(packed, up)
             with _obs.span("eng_block_dispatch"):
                 out, logits = self._run(
-                    name, self._state_vals(epoch), self.kv, *dev)
+                    name, self._state_vals(epoch), self.kv, dev)
             with _obs.span("eng_block_readback"):
                 new_tokens, new_masked, touched = (np.asarray(a) for a in out)
             _obs.observe("serving_decode_step_seconds",
@@ -1216,6 +1258,7 @@ class DecodeEngine:
         along with zeroed table rows (their writes land on the trash page)
         at position 0."""
         s, b = self.config.num_slots, self._block
+        name = f"commit_b{b}"
         with _obs.span("eng_block_commit", slots=len(group),
                        rows=len(group) * b) as sp:
             with _obs.span("eng_commit_prep"):
@@ -1226,11 +1269,12 @@ class DecodeEngine:
                     tokens[slot] = req.block.tokens
                     positions[slot] = req.block.start
                     tables[slot] = self._tables[slot]
-            with _obs.span("eng_commit_upload"):
-                dev = [jnp.asarray(a) for a in (tokens, positions, tables)]
+                packed = self._pack(name, (tokens, positions, tables))
+            with _obs.span("eng_commit_upload") as up:
+                dev = self._upload(packed, up)
             with _obs.span("eng_commit_dispatch"):
                 touched, _ = self._run(
-                    f"commit_b{b}", self._state_vals(epoch), self.kv, *dev)
+                    name, self._state_vals(epoch), self.kv, dev)
             with _obs.span("eng_commit_readback"):
                 touched = int(np.asarray(touched))  # waits for the pass
             self.commit_passes += 1
@@ -1319,7 +1363,7 @@ class DecodeEngine:
             epoch = self._epoch
         if self._acct is not None:
             self._acct_tick(time.perf_counter())
-        s, k1 = cfg.num_slots, k + 1
+        s, k1, name = cfg.num_slots, k + 1, f"verify_k{k}"
         with _obs.span("eng_verify_prep"):
             tokens = np.zeros((s, k1), np.int32)
             positions = np.zeros(s, np.int32)
@@ -1337,15 +1381,16 @@ class DecodeEngine:
                 temp[slot], top_k[slot], top_p[slot], greedy[slot] = (
                     t_, k_, p_, g_)
                 keys[slot] = req.key_np
-        warm = f"verify_k{k}" in self._compiled
-        t0 = time.perf_counter()
-        with _obs.span("eng_verify_upload"):
-            dev = [jnp.asarray(a) for a in (
+            packed = self._pack(name, (
                 tokens, positions, self._tables, keys, temp, top_k, top_p,
-                greedy)]
+                greedy))
+        warm = name in self._compiled
+        t0 = time.perf_counter()
+        with _obs.span("eng_verify_upload") as sp:
+            dev = self._upload(packed, sp)
         with _obs.span("eng_verify_dispatch"):
             targets, logits = self._run(
-                f"verify_k{k}", self._state_vals(epoch), self.kv, *dev)
+                name, self._state_vals(epoch), self.kv, dev)
         with _obs.span("eng_verify_readback"):
             targets_host = np.asarray(targets)  # [S, k+1] int32
         dt = time.perf_counter() - t0
@@ -1512,10 +1557,16 @@ class DecodeEngine:
 
     def _example_args(self, name: str) -> tuple:
         """The arguments of program ``name`` ("decode", "verify_k4",
-        "prefill_b512"): the live state and pool, then synthetic inputs
-        with all-zero page tables, so every KV write lands on the inert
-        trash page. What ``warmup`` runs, and what a compile for a
-        described chip takes its shapes from."""
+        "prefill_b512"): the live state and pool, then its synthetic
+        inputs packed (``_example_inputs``). What ``warmup`` runs, and
+        what a compile for a described chip takes its shapes from."""
+        return (self._state_vals(), self.kv,
+                self._pack(name, self._example_inputs(name)))
+
+    def _example_inputs(self, name: str) -> tuple:
+        """Program ``name``'s synthetic inputs, field by field, with
+        all-zero page tables, so every KV write lands on the inert trash
+        page."""
         s, key = self.config.num_slots, self._zero_key
         one, zero, yes = np.float32(1.0), np.int32(0), np.asarray(True)
         kind, n = _program_kind(name)
@@ -1535,7 +1586,7 @@ class DecodeEngine:
                       np.array(np.broadcast_to(key, (s,) + key.shape)),
                       np.full(s, one), np.full(s, zero), np.full(s, one),
                       np.full(s, yes))
-        return (self._state_vals(), self.kv) + tuple(inputs)
+        return tuple(inputs)
 
     def stats(self) -> dict:
         return {
@@ -1571,6 +1622,7 @@ class DecodeEngine:
             "cache_layers": self.cache_layers,
             "block_length": self._block,
             "commit_passes": self.commit_passes,
+            "host_uploads": self.host_uploads,
             "rows_computed": self.rows_computed,
             "experts_touched": self.experts_touched,
             "experts_capacity": self.experts_capacity,
@@ -2018,20 +2070,18 @@ class DecodeEngine:
             return self._prefill_blocks(req, slot, row, cached_len)
         t0 = int(req.prompt.shape[0])
         rid = req.req_id
-        with _obs.span("eng_prefill_prep", rid=rid):
+        with _obs.span("eng_prefill_prep", rid=rid) as sp:
             tail = req.prompt[cached_len:]
             tb = self._bucket_for(len(tail))
             ids = np.zeros((1, tb), np.int32)
             ids[0, :len(tail)] = tail
-            t_, k_, p_, g_ = req.params.fields()
-            tp0 = time.perf_counter()  # the uploads count as prefill
-            args = (jnp.asarray(ids), np.int32(cached_len), np.int32(t0),
-                    jnp.asarray(row), jnp.asarray(req.key_np),
-                    np.float32(t_), np.int32(k_), np.float32(p_),
-                    np.asarray(g_))
+            tp0 = time.perf_counter()  # the upload counts as prefill
+            name = f"prefill_b{tb}"
+            dev = self._upload(self._pack(name, (
+                ids, cached_len, t0, row, req.key_np,
+                *req.params.fields())), sp)
         with _obs.span("eng_prefill_dispatch", rid=rid, bucket=int(tb)):
-            nxt, _ = self._run(
-                f"prefill_b{tb}", self._state_vals(), self.kv, *args)
+            nxt, _ = self._run(name, self._state_vals(), self.kv, dev)
         with _obs.span("eng_prefill_readback", rid=rid):
             token = int(nxt)  # waits for the prefill
         now = time.perf_counter()
@@ -2067,18 +2117,17 @@ class DecodeEngine:
         rid = req.req_id
         tp0 = time.perf_counter()
         if start > cached_len:
-            with _obs.span("eng_prefill_prep", rid=rid):
+            with _obs.span("eng_prefill_prep", rid=rid) as sp:
                 tail = req.prompt[cached_len:start]
                 tb = self._bucket_for(len(tail))
                 ids = np.zeros((1, tb), np.int32)
                 ids[0, :len(tail)] = tail
-                args = (jnp.asarray(ids), np.int32(cached_len),
-                        np.int32(start), jnp.asarray(row),
-                        jnp.asarray(req.key_np), np.float32(1.0),
-                        np.int32(0), np.float32(1.0), np.asarray(True))
+                name = f"prefill_b{tb}"
+                dev = self._upload(self._pack(name, (
+                    ids, cached_len, start, row, req.key_np, 1.0, 0, 1.0,
+                    True)), sp)
             with _obs.span("eng_prefill_dispatch", rid=rid, bucket=int(tb)):
-                nxt, _ = self._run(
-                    f"prefill_b{tb}", self._state_vals(), self.kv, *args)
+                nxt, _ = self._run(name, self._state_vals(), self.kv, dev)
             with _obs.span("eng_prefill_readback", rid=rid):
                 jax.block_until_ready(nxt)  # its token is not used
             self.rows_computed += start - cached_len
@@ -2239,8 +2288,49 @@ class DecodeEngine:
                 else self._build_prefill(n))
         return fn
 
+    def _layout(self, name: str) -> tuple:
+        """The fields of program ``name``'s one packed input, ``(shape,
+        dtype)`` each in the order its body takes them: fixed by the
+        program's kind and the engine's shapes (slots, the table's width,
+        the bucket, ``k + 1``, the block length)."""
+        lay = self._layouts.get(name)
+        if lay is None:
+            kind, n = _program_kind(name)
+            s, mp = self.config.num_slots, self._mp
+            i32, f32, u32, yes = (np.dtype(t) for t in (
+                np.int32, np.float32, np.uint32, np.bool_))
+            key = self._zero_key.shape
+            if kind == "prefill":  # ids, cached_len, true_len, row, key
+                lay = (((1, n), i32), ((), i32), ((), i32), ((mp,), i32),
+                       (key, u32), ((), f32), ((), i32), ((), f32), ((), yes))
+            elif kind == "commit":  # tokens, positions, tables
+                lay = (((s, n), i32), ((s,), i32), ((s, mp), i32))
+            else:  # tokens [, masked], positions, tables, keys, sampling
+                lay = ((((s,), i32),) if kind == "decode"
+                       else (((s, n + 1), i32),) if kind == "verify"
+                       else (((s, n), i32), ((s, n), yes)))
+                lay += (((s,), i32), ((s, mp), i32), ((s,) + key, u32),
+                        ((s,), f32), ((s,), i32), ((s,), f32), ((s,), yes))
+                if kind == "block":
+                    lay += (((s,), i32),)  # how many to unmask
+            self._layouts[name] = lay
+        return lay
+
+    def _pack(self, name: str, arrays) -> np.ndarray:
+        """Program ``name``'s host inputs as its one packed int32 array."""
+        return _pack_fields(self._layout(name), arrays)
+
+    def _upload(self, packed: np.ndarray, sp=None):
+        """ONE host-to-device transfer of a pass's packed inputs, counted
+        in ``host_uploads`` and on its span ``sp`` (attrs ``arrays``,
+        ``bytes``)."""
+        self.host_uploads += 1
+        if sp:
+            sp.attrs.update(arrays=1, bytes=int(packed.nbytes))
+        return jnp.asarray(packed)
+
     def _run(self, name: str, *args):
-        """Run program ``name`` on ``(state values, pool, *inputs)``, keep
+        """Run program ``name`` on ``(state values, pool, packed)``, keep
         the pool it returns and hand back ``(tokens, logits)``; a
         program's first run is timed and counted as its compile."""
         fn = self._jitted(name)
@@ -2313,8 +2403,10 @@ class DecodeEngine:
     # swapped into the live tensors around the traced body and restored —
     # the jit.TracedLayer idiom), so parameters stay jit arguments rather
     # than baked-in constants, and the paged KV pool flows through as ONE
-    # donated pytree input/output. Page tables arrive as plain int32
-    # arguments.
+    # donated pytree input/output. A pass's host inputs (tokens, positions,
+    # page tables, keys, sampling fields) arrive as ONE int32 array, moved
+    # to the device in one transfer and cut back into its fields inside the
+    # program (``_layout``, ``_pack_fields``, ``_unpack_fields``).
 
     def _wire_logits(self, logits):
         """Route mp-vocab-sharded logits [..., V] through the quantized
@@ -2341,19 +2433,21 @@ class DecodeEngine:
             exact = None
         return wl, exact, wl
 
-    def _program(self, body):
-        """Jit ``body(pool, *inputs) -> (pool, tokens, logits)`` as one of
-        the engine's programs (the header above): the state swap, the
-        returned pool's pin and the pool's donation, once for all three."""
-        state = self._state
+    def _program(self, body, name: str):
+        """Jit ``body(pool, *inputs) -> (pool, tokens, logits)`` as program
+        ``name`` (the header above): the state swap, the returned pool's
+        pin and the pool's donation, once for all of them, and the inputs
+        cut out of the one packed array they arrive in (``_layout``)."""
+        state, layout = self._state, self._layout(name)
 
-        def pure(state_vals, pool, *inputs):
+        def pure(state_vals, pool, packed):
             originals = [t._value for t in state]
             try:
                 for t_, v_ in zip(state, state_vals):
                     t_._value = v_
                 with no_grad():
-                    pool, tokens, logits = body(pool, *inputs)
+                    pool, tokens, logits = body(
+                        pool, *_unpack_fields(layout, packed))
             finally:
                 for t_, v_ in zip(state, originals):
                     t_._value = v_
@@ -2463,7 +2557,7 @@ class DecodeEngine:
                     top_p[None], greedy[None])
             return pool, nxt[0], logits[0]
 
-        return self._program(body)
+        return self._program(body, f"prefill_b{tb}")
 
     def _build_decode(self):
         def body(pool, tokens, positions, tables, keys, *sampling):
@@ -2481,7 +2575,7 @@ class DecodeEngine:
                     positions + 1)
                 return pool, *self._sample(logits, step_keys, *sampling)
 
-        return self._program(body)
+        return self._program(body, "decode")
 
     def _build_verify(self, k1: int):
         """The speculative companion of the decode program: k1 = k + 1
@@ -2503,7 +2597,7 @@ class DecodeEngine:
                     jax.random.wrap_key_data(keys, impl=_KEY_IMPL), pos2 + 1)
                 return pool, *self._sample(logits, step_keys, *sampling)
 
-        return self._program(body)
+        return self._program(body, f"verify_k{k1 - 1}")
 
     def _block_forward(self, pool, ids, positions, tables, b, read):
         """The forward of the block and commit passes: every slot's ``b``
@@ -2556,7 +2650,7 @@ class DecodeEngine:
                                          rule, thr)
             return pool, (tokens, masked, touched), logits
 
-        return self._program(body)
+        return self._program(body, f"block_b{b}")
 
     def _build_commit(self, b: int):
         """The commit pass of a block-diffusion model: each listed slot's
@@ -2571,4 +2665,4 @@ class DecodeEngine:
             touched = sum(counts[:-1], jnp.int32(0))
             return pool, touched, jnp.zeros((1,), jnp.float32)
 
-        return self._program(body)
+        return self._program(body, f"commit_b{b}")

@@ -553,8 +553,10 @@ SPANS = {
         "start less submit; request_trace_id where a router gave one)"),
     "eng_prefill_prep": (
         "paddle_tpu/inference/engine.py",
-        "Prefill, host: bucket choice, padded ids, argument uploads "
-        "(attrs: rid; child of eng_admit, a root under prefill_export)"),
+        "Prefill, host: bucket choice, padded ids, the inputs packed and "
+        "moved to the device in one transfer (attrs: rid; arrays, the "
+        "transfers made, 1, and bytes; child of eng_admit, a root under "
+        "prefill_export)"),
     "eng_prefill_dispatch": (
         "paddle_tpu/inference/engine.py",
         "Prefill, host: the call that enqueues the compiled prefill "
@@ -565,10 +567,12 @@ SPANS = {
         "for the device (attrs: rid)"),
     "eng_decode_prep": (
         "paddle_tpu/inference/engine.py",
-        "Decode, host: the eight per-slot numpy arrays of one step"),
+        "Decode, host: the eight per-slot numpy arrays of one step, "
+        "packed into one int32 array"),
     "eng_decode_upload": (
         "paddle_tpu/inference/engine.py",
-        "Decode, host: those arrays to the device (eight jnp.asarray)"),
+        "Decode, host: that array to the device, one transfer (attrs: "
+        "arrays, the transfers made, 1, and bytes)"),
     "eng_decode_dispatch": (
         "paddle_tpu/inference/engine.py",
         "Decode, host: the call that enqueues the compiled decode step"),
@@ -589,10 +593,12 @@ SPANS = {
         "attention read)"),
     "eng_block_prep": (
         "paddle_tpu/inference/engine.py",
-        "Block pass, host: the ten per-slot numpy arrays of one pass"),
+        "Block pass, host: the ten per-slot numpy arrays of one pass, "
+        "packed into one int32 array"),
     "eng_block_upload": (
         "paddle_tpu/inference/engine.py",
-        "Block pass, host: those arrays to the device (ten jnp.asarray)"),
+        "Block pass, host: that array to the device, one transfer (attrs: "
+        "arrays, 1, and bytes)"),
     "eng_block_dispatch": (
         "paddle_tpu/inference/engine.py",
         "Block pass, host: the call that enqueues the compiled block pass"),
@@ -613,10 +619,12 @@ SPANS = {
         "experts_touched, kv_pages); its four children, prep to read-back"),
     "eng_commit_prep": (
         "paddle_tpu/inference/engine.py",
-        "Commit pass, host: the tokens, positions and tables arrays"),
+        "Commit pass, host: the tokens, positions and tables arrays, "
+        "packed into one int32 array"),
     "eng_commit_upload": (
         "paddle_tpu/inference/engine.py",
-        "Commit pass, host: those arrays to the device (three jnp.asarray)"),
+        "Commit pass, host: that array to the device, one transfer (attrs: "
+        "arrays, 1, and bytes)"),
     "eng_commit_dispatch": (
         "paddle_tpu/inference/engine.py",
         "Commit pass, host: the call that enqueues the compiled commit "
@@ -631,7 +639,8 @@ SPANS = {
         "slot"),
     "eng_verify_upload": (
         "paddle_tpu/inference/engine.py",
-        "Speculative verify step, host: argument uploads"),
+        "Speculative verify step, host: its packed inputs to the device, "
+        "one transfer (attrs: arrays, 1, and bytes)"),
     "eng_verify_dispatch": (
         "paddle_tpu/inference/engine.py",
         "Speculative verify step, host: the enqueueing call"),

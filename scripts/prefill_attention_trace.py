@@ -68,9 +68,10 @@ def set_variant(variant, saved):
     KVPool.attend_block, pf._block_sizes = saved
     kind, _, blocks = variant.partition(":")
     if kind == "paged":
-        KVPool.attend_block = lambda self, q, layer, row, cached_len, kernel: (
+        KVPool.attend_block = (
+            lambda self, q, layer, row, cached_len, kernel, block=1:
             self.attend(q, layer, row[None], jnp.reshape(cached_len, (1,)),
-                        kernel))
+                        kernel, block))
     elif blocks:
         bq, bk = (int(n) for n in blocks.split("x"))
         pf._block_sizes = lambda rows, keys: (
@@ -84,15 +85,16 @@ def prefill_args(eng, bucket, cached):
     ``cached`` cached ones, in pages 1, 2, ... of the pool."""
     import numpy as np
 
-    args = list(eng._example_args(f"prefill_b{bucket}"))
+    name = f"prefill_b{bucket}"
+    inputs = list(eng._example_inputs(name))
     page = eng.config.page_size
-    row = np.zeros_like(args[5])
+    row = np.zeros_like(inputs[3])
     used = -(-(cached + bucket) // page)
     row[:used] = 1 + np.arange(used)
     ids = np.random.default_rng(bucket + cached).integers(
         1, 60, (1, bucket)).astype(np.int32)
-    args[2:6] = [ids, np.int32(cached), np.int32(cached + bucket), row]
-    return args
+    inputs[:4] = [ids, np.int32(cached), np.int32(cached + bucket), row]
+    return eng._state_vals(), eng.kv, eng._pack(name, inputs)
 
 
 def main():

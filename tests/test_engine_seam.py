@@ -287,6 +287,11 @@ def test_pool_is_one_donated_argument_of_a_program(toy, kv_dtype, leaves):
     for i in range(leaves):
         arg = re.search(rf"%arg{state + i}: [^%]*", main).group(0)
         assert f"tf.aliasing_output = {i} : i32" in arg
+    # after the pool, the pass's host inputs as ONE packed int32 array
+    assert len(args) == 3 and args[2].dtype == np.int32 and args[2].ndim == 1
+    last = re.search(rf"%arg{state + leaves}: tensor<(\d+)xi32>", main)
+    assert last and int(last.group(1)) == args[2].size
+    assert f"%arg{state + leaves + 1}:" not in main
 
 
 # -- (4) the two depths: a model without loops compiles no loop ---------------
